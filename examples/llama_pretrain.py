@@ -25,6 +25,7 @@ import numpy as np
 
 
 def main():
+    paddle.utils.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--hidden", type=int, default=2048)
     ap.add_argument("--intermediate", type=int, default=8192)

@@ -3,9 +3,8 @@
 Flow: build a Llama -> greedy generate (ONE compiled dispatch for
 prefill + the whole decode scan) -> LLMPredictor session (block decode,
 K tokens per dispatch) -> save/load the serving artifact -> weight-only
-int8.  Runs in seconds on CPU with the tiny config; on a TPU chip the
-same code serves the 1.1B bench config at the BASELINE.md decode
-numbers (int8 ~1.6-2.4x bf16 at batch 1).
+int8.  Runs in seconds on CPU with the tiny config; `chip_smoke.py`
+serves the 1.1B configuration through `ServingEngine` on a TPU chip.
 
 Run: python examples/llama_serve.py
 """
@@ -25,6 +24,7 @@ from paddle_tpu.quantization import weight_only_quantize
 
 
 def main():
+    paddle.utils.enable_compile_cache()
     paddle.seed(0)
     cfg = models.tiny_llama_config()
     net = models.LlamaForCausalLM(cfg)
@@ -56,7 +56,7 @@ def main():
     print("artifact:", again[0], "(deterministic)")
 
     # 4) weight-only int8: halve the weight stream (decode is
-    #    weight-streaming bound — BASELINE.md roofline)
+    #    weight-streaming bound)
     qnet = weight_only_quantize(net, inplace=False,
                                 skip=lambda name, l: name == "lm_head")
     qpred = LLMPredictor(qnet, batch=2, prompt_len=8, max_cache_len=32,

@@ -16,6 +16,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def main():
+    paddle.utils.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--data-file", type=str, default=None)
     ap.add_argument("--arch", type=str, default="resnet18")

@@ -43,11 +43,8 @@ def _annotate(param, spec):
     mesh = get_global_mesh()
     if mesh is not None and MODEL_AXIS in mesh.axis_names and \
             not isinstance(param._value, jax.core.Tracer):
-        try:
-            param._value = jax.device_put(param._value,
-                                          NamedSharding(mesh, spec))
-        except Exception:
-            pass  # single-device mesh or placement unavailable eagerly
+        param._value = jax.device_put(param._value,
+                                      NamedSharding(mesh, spec))
     return param
 
 

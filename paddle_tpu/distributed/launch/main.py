@@ -76,7 +76,13 @@ def _run_in_process(args):
 
 def _spawn_workers(args):
     """Reference collective controller: Popen one proc per local rank, tee
-    logs, propagate first failure (kill the rest)."""
+    logs, propagate first failure (kill the rest).
+
+    Nothing here gives a worker its own chip: on a TPU host every worker
+    would ask for all local chips and all but one fail or hang (a chip
+    belongs to one process).  Until a per-rank device assignment exists
+    the launcher is for CPU workers; one process drives the four chips
+    of a host through a mesh instead (``chip_smoke.py`` step 5)."""
     os.makedirs(args.log_dir, exist_ok=True)
     procs = []
     logs = []

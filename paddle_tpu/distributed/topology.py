@@ -46,13 +46,12 @@ def get_global_mesh() -> Optional[Mesh]:
 
 
 def pvary(x, axes):
-    """Mark x as varying over manual mesh axes (pcast on new jax, pvary on
-    old); idempotent — already-varying values pass through.  Shared by the
-    shard_map-based engines (pipeline, ring attention)."""
+    """Mark x as varying over manual mesh axes; idempotent —
+    already-varying values pass through (``pcast`` itself refuses
+    varying -> varying).  Shared by the shard_map-based engines
+    (pipeline, ring attention)."""
     try:
         return jax.lax.pcast(x, axes, to="varying")
-    except (AttributeError, TypeError):
-        return jax.lax.pvary(x, axes)
     except ValueError as e:
         if "from=varying" in str(e):
             return x

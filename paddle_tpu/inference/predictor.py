@@ -8,7 +8,6 @@ one fused XLA executable call, no Python op dispatch.
 
 from __future__ import annotations
 
-import os
 import pickle
 import threading
 from typing import Dict, List, Optional
@@ -24,7 +23,8 @@ class Config:
     Knobs with real effect on this backend:
 
     - ``set_compilation_cache_dir`` — persistent XLA executable cache
-      (≙ serialized TRT engines).
+      (≙ serialized TRT engines); ignored when the environment sets
+      ``JAX_COMPILATION_CACHE_DIR``.
     - ``enable_memory_optim`` — donate input device buffers to the
       executable so XLA reuses them for outputs (≙ memory-reuse passes).
     - ``set_tpu_device_id`` / ``set_device_id`` — place weights and run
@@ -193,11 +193,10 @@ class Predictor:
             if prefix is None:
                 raise ValueError("Config has no model path")
             if config._effective_cache_dir():
-                os.makedirs(config._effective_cache_dir(), exist_ok=True)
-                jax.config.update("jax_compilation_cache_dir",
-                                  config._effective_cache_dir())
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.0)
+                # the environment's JAX_COMPILATION_CACHE_DIR wins when
+                # set (utils/compile_cache.py)
+                from ..utils.compile_cache import enable_compile_cache
+                enable_compile_cache(config._effective_cache_dir())
             from jax import export as jax_export
             with open(prefix + ".ptpu_model", "rb") as f:
                 self._exported = jax_export.deserialize(f.read())

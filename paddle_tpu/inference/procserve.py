@@ -502,6 +502,9 @@ class EngineProcess:
         if self.dryrun:
             return
         env = dict(os.environ)
+        # replica children are CPU-only by construction: the supervisor
+        # may hold the chip, and a chip belongs to one process — a
+        # multi-process chip path does not exist yet
         env["JAX_PLATFORMS"] = "cpu"
         env.setdefault("XLA_FLAGS",
                        "--xla_force_host_platform_device_count=1")
@@ -675,6 +678,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     kw = json.loads(args.kwargs)
     fault_spec = kw.pop("fault_spec", None)
     if fault_spec:

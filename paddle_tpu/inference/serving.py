@@ -1535,12 +1535,16 @@ class ServingEngine:
         # unchanged, which is what keeps a sharded engine scheduling-
         # identical (and, with per-request keyed PRNG, token-exact) to
         # single-chip.  Sharding is pjit annotations ONLY (no
-        # shard_map — unavailable in this environment, see the
-        # pre-existing F-cluster) so no new sync reason exists.  A
-        # geometry that cannot split whole kv-heads (hkv % n_shards
-        # != 0, or a 1-wide model axis) falls back to the exact
-        # single-chip engine and says so once on the route counter
-        # (decision="xla", reason="mesh_geom").
+        # shard_map), so no new sync reason exists.  A geometry that
+        # cannot split whole kv-heads (hkv % n_shards != 0, or a
+        # 1-wide model axis) is the exact single-chip engine on the
+        # mesh's FIRST device — not on the process default, and not
+        # replicated: a multi-device program could take no Pallas
+        # kernel (ops/pallas/_common.pallas_enabled) — and says so
+        # once on the route counter (decision="xla",
+        # reason="mesh_geom").  The committed weights and arenas pull
+        # every uncommitted host push (tables, carries, sampling
+        # planes) onto the same device(s) at dispatch.
         self._shard = None
         self.shard_group = None
         if mesh is not None:
@@ -1554,14 +1558,16 @@ class ServingEngine:
             devs = [int(dv.id) for dv in mesh.devices.flat]
             tp_ok = n_sh > 1 and hkv % n_sh == 0
             if tp_ok:
+                rep = NamedSharding(mesh, _P())
                 kv_ns = NamedSharding(mesh, _P(None, None, "model"))
                 self._shard = ArenaSharding(kv=kv_ns, n_shards=n_sh)
-                rep = NamedSharding(mesh, _P())
-                self._arenas = [jax.device_put(a, kv_ns)
-                                for a in self._arenas]
-                self._pb = [jax.device_put(v, rep) for v in self._pb]
             else:
+                kv_ns = rep = jax.sharding.SingleDeviceSharding(
+                    mesh.devices.flat[0])
                 _decode_attn.count_shard_route(hkv, n_sh, False)
+            self._arenas = [jax.device_put(a, kv_ns)
+                            for a in self._arenas]
+            self._pb = [jax.device_put(v, rep) for v in self._pb]
             self.shard_group = {
                 "n_shards": n_sh if tp_ok else 1,
                 "requested": n_sh,

@@ -44,16 +44,12 @@ _worker_info = threading.local()
 
 
 def _fork_is_safe():
-    """True while every JAX backend initialized in this process is the CPU
-    one — forking with libtpu/grpc threads live can deadlock the child."""
-    try:
-        from jax._src import xla_bridge
-        backends = getattr(xla_bridge, "_backends", None)
-        if backends is None:  # private API moved: assume unsafe
-            return False
-        return all(name == "cpu" for name in backends)
-    except Exception:
-        return False
+    """True while this process runs on the CPU backend — forking with
+    libtpu's threads live can deadlock the child, and a child that needs
+    the chip its parent holds fails or hangs.  Asking for the backend
+    initializes it, which a DataLoader's first batch would do anyway."""
+    import jax
+    return jax.default_backend() == "cpu"
 
 
 def get_worker_info():

@@ -25,6 +25,7 @@ from ..core import tape as _tape
 from ..core.tensor import Tensor
 from ..observability import metrics as _obs
 from ..observability.spans import span as _span
+from ..ops.pallas._common import partitioned_scope
 from ..optimizer import SGD, Adam, AdamW, Momentum
 from ..optimizer.optimizer import Optimizer
 
@@ -459,8 +460,13 @@ class TrainStep:
                     a._value if isinstance(a, Tensor) else a
                     for a in aux), new_b)
 
-            (loss, (aux, new_b)), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(list(p_values))
+            # over a mesh of several devices the step is a GSPMD-
+            # partitioned program, into which no Pallas kernel may be
+            # traced (ops/pallas/_common.pallas_enabled); the scope
+            # spans the backward trace too (custom_vjp bwd rules)
+            with partitioned_scope(mesh is not None and mesh.size > 1):
+                (loss, (aux, new_b)), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(list(p_values))
             if grad_pins is not None:
                 # ZeRO stage-2/3 (os_g / p_g_os): pin each gradient to its
                 # optimizer-state sharding so XLA reduce-scatters the grad

@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import on_tpu, pallas_enabled
+from ._common import (on_tpu, pallas_enabled, partitioned_scope,
+                      refused_for_partitioning)
 
 # The closed label vocabulary of the ``pallas.decode_attention.route``
 # counter's ``reason`` axis (graftlint DECODE_ROUTE_REASONS; the
@@ -60,10 +61,10 @@ from ._common import on_tpu, pallas_enabled
 DECODE_ROUTE_REASONS = (
     "ok", "paged_ok", "paged_int8_ok", "paged_multi_ok",
     "paged_multi_int8_ok", "sharded_ok", "mesh_geom",
-    "flag_disabled", "pallas_unavailable", "unpacked_cache",
-    "dtype_mismatch", "scales_mismatch", "geometry", "int8_geom",
-    "group_too_wide", "seq_align", "paged_block_len", "query_rows",
-    "vmem_budget",
+    "flag_disabled", "pallas_unavailable", "gspmd_partitioned",
+    "unpacked_cache", "dtype_mismatch", "scales_mismatch", "geometry",
+    "int8_geom", "int8_scale_lanes", "group_too_wide", "seq_align",
+    "paged_block_len", "paged_dma_sems", "query_rows", "vmem_budget",
 )
 
 
@@ -96,7 +97,10 @@ def shard_dispatch_scope(n_shards: int):
     prev = _SHARD_N
     _SHARD_N = int(n_shards)
     try:
-        yield
+        # a sharded serving program is GSPMD-partitioned: no kernel of
+        # any kind may be traced into it (``_common.pallas_enabled``)
+        with partitioned_scope(_SHARD_N > 1):
+            yield
     finally:
         _SHARD_N = prev
 
@@ -124,7 +128,42 @@ _LANES = 128
 DEFAULT_CHUNK = 256            # cache slots per DMA chunk
 _NEG_INF = -1e30
 _GPAD = 8                      # q rows per head block (sublane unit)
+# What the gate admits is what the compiler is told: ``_VMEM_BUDGET``
+# bounds the buffers the kernels STAGE (K/V landing buffers, scale
+# planes, the logits scratch), and every decode ``pallas_call`` sets
+# Mosaic's scoped-VMEM limit to ``_VMEM_LIMIT`` so that the softmax's
+# temporaries — a handful of logits-sized values the estimate does not
+# itemise — have room on top of a full budget whatever the compiler's
+# default is.  On the v5e the bf16 kernel compiles and agrees at the
+# budget's edge (5376 staged rows of 512 lanes) with this limit; 5120
+# rows also fit the default limit (chip run, PR 22).
 _VMEM_BUDGET = 12 << 20
+_VMEM_LIMIT = 32 << 20
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+
+
+# The paged kernels hold one DMA semaphore per table block per staged
+# operand, and semaphores live in the core's 2 KiB "sflag" memory, 4
+# bytes each, next to 292 bytes the program keeps for itself ("Ran out
+# of memory in memory space sflag. Used 2.1K of 2.0K sflag", v5e /
+# jax 0.9.0: two operands compile at 208 blocks and fail at 224).
+_SFLAG_BYTES = 2048
+_SFLAG_RESERVED = 292
+
+
+def _paged_table_rule(arena, tables, kv_scales):
+    """(ok, reason) for what a paged kernel needs of the block table:
+    ``paged_block_len`` — the staged chunk unit is a whole block, so
+    ``block_len`` must sit on the 8-row sublane tile (bf16 and f32
+    arenas compile at 8, 16 and 32 on the v5e); ``paged_dma_sems`` —
+    the table is no wider than the semaphore memory allows, 219 blocks
+    for a float cache (3504 tokens at the default block length 16)."""
+    if arena.shape[1] % 8:
+        return False, "paged_block_len"
+    n_ops = 2 if kv_scales is None else 4
+    if n_ops * tables.shape[1] * 4 + _SFLAG_RESERVED > _SFLAG_BYTES:
+        return False, "paged_dma_sems"
+    return True, None
 
 
 def packed_ok(num_kv_heads: int, head_dim: int) -> bool:
@@ -233,13 +272,16 @@ def _gate_shared(q4, cache, s, align_ok, align_reason, q_rows=_GPAD,
     pairs of ``_MIXED_DTYPE_ALLOWLIST`` (every other q/cache dtype mix
     rejects as ``dtype_mismatch``; an int8 pairing that fails the
     packed-geometry check rejects as ``int8_geom`` so the route
-    counter separates it from bf16 ``geometry``).  Returns
+    counter separates it from bf16 ``geometry``, and one whose scale
+    planes Mosaic cannot DMA rejects as ``int8_scale_lanes``).  Returns
     (use_pallas, reason-or-None); the caller maps None to its accept
     reason."""
     from ...core.flags import flag
     if not flag("use_decode_attention_kernel"):
         return False, "flag_disabled"
     if not pallas_enabled():
+        if refused_for_partitioning():
+            return False, "gspmd_partitioned"
         return False, "pallas_unavailable"
     if cache.ndim != 3:
         return False, "unpacked_cache"
@@ -260,6 +302,15 @@ def _gate_shared(q4, cache, s, align_ok, align_reason, q_rows=_GPAD,
     w = cache.shape[2]
     if not packed_ok(hkv, d) or w != hkv * d:
         return False, "int8_geom" if int8_pair else "geometry"
+    if int8_pair and hkv % _LANES:
+        # the [NB+1, L, H_kv] f32 scale planes are staged by DMA, one
+        # [L, H_kv] slab per block, and Mosaic slices an HBM plane only
+        # in whole 128-lane tiles ("Slice shape along dimension 2 must
+        # be aligned to tiling (128), but is 8", v5e / jax 0.9.0, at
+        # block lengths 16 and 32 alike).  No served model has 128 KV
+        # heads, so on the chip the int8 cache reads through
+        # ``paged_dequant_view`` until the scale planes change shape.
+        return False, "int8_scale_lanes"
     if g > _GPAD:        # q_cat blocks hold at most 8 query heads/KV head
         return False, "group_too_wide"
     if not align_ok:
@@ -328,19 +379,17 @@ def should_use_pallas(q4, cache) -> bool:
 def _route_decision_paged(q4, arena, tables, kv_scales=None):
     """(use_pallas, reason) for the PAGED decode-attention gate: the
     shared gate (``_gate_shared``) evaluated on the arena geometry,
-    with the paged-only sublane rule in place of ``seq_align`` — the
-    staged chunk unit is a whole block, so ``block_len`` must sit on
-    the (8, 128) sublane tile (``paged_block_len``).  Accepts route as
+    with the paged-only table rule (``_paged_table_rule``) in place of
+    ``seq_align``.  Accepts route as
     ``paged_ok`` so the route counter separates paged-kernel traffic
     from dense ``ok`` — or as ``paged_int8_ok`` when the caller passes
     the quantized cache's scale arenas (``kv_scales``), the explicitly
     allowlisted (float q, int8 cache + scales) pairing that runs the
     dequant-in-kernel variant."""
-    blk_len = arena.shape[1]
-    s = tables.shape[1] * blk_len      # staged dense rows
-    use, reason = _gate_shared(q4, arena, s, blk_len % 8 == 0,
-                               "paged_block_len",
-                               has_scales=kv_scales is not None)
+    s = tables.shape[1] * arena.shape[1]      # staged dense rows
+    use, reason = _gate_shared(
+        q4, arena, s, *_paged_table_rule(arena, tables, kv_scales),
+        has_scales=kv_scales is not None)
     if reason is not None:
         return use, reason
     return use, ("paged_int8_ok" if kv_scales is not None
@@ -361,7 +410,7 @@ _QROWS_MAX = 4 * _GPAD      # per-head q-row cap of the K-wide kernel
 def _route_decision_paged_multi(q5, arena, tables, kv_scales=None):
     """(use_pallas, reason) for the K-WIDE paged verify gate
     (``decode_attention_paged_multi``): the shared gate evaluated on
-    the arena geometry with the paged sublane rule, plus the verify
+    the arena geometry with the paged table rule, plus the verify
     kernel's own row budget — the block-diagonal q staging packs
     ``g * C`` query rows per head (C speculative positions x G grouped
     query heads), rounded up to the sublane unit; wider than
@@ -374,11 +423,10 @@ def _route_decision_paged_multi(q5, arena, tables, kv_scales=None):
     qr = -(-(g * cq) // _GPAD) * _GPAD
     if qr > _QROWS_MAX:
         return False, "query_rows"
-    blk_len = arena.shape[1]
-    s = tables.shape[1] * blk_len      # staged dense rows
-    use, reason = _gate_shared(q5[:, 0], arena, s, blk_len % 8 == 0,
-                               "paged_block_len", q_rows=qr,
-                               has_scales=kv_scales is not None)
+    s = tables.shape[1] * arena.shape[1]      # staged dense rows
+    use, reason = _gate_shared(
+        q5[:, 0], arena, s, *_paged_table_rule(arena, tables, kv_scales),
+        q_rows=qr, has_scales=kv_scales is not None)
     if reason is not None:
         return use, reason
     return use, ("paged_multi_int8_ok" if kv_scales is not None
@@ -936,8 +984,8 @@ def _decode_attention_pallas(q4, k_cache, v_cache, lens, chunk=None):
         in_specs=[
             pl.BlockSpec((1, ng, hp * _GPAD, gw),
                          lambda bi, lens_p: (bi, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, hkv, g, d),
                                lambda bi, lens_p: (bi, 0, 0, 0)),
@@ -953,6 +1001,7 @@ def _decode_attention_pallas(q4, k_cache, v_cache, lens, chunk=None):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q4.dtype),
+        compiler_params=_COMPILER_PARAMS,
         interpret=not on_tpu(),
     )(lens.astype(jnp.int32), qcat, k_cache, v_cache)
 
@@ -999,7 +1048,7 @@ def _paged_dispatch(kernel, qcat, operands, tables, lens, *, b, hkv, d,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, ng, q_rows, gw),
                                lambda bi, lens_p, tbl_p: (bi, 0, 0, 0))]
-        + [pl.BlockSpec(memory_space=pltpu.ANY) for _ in operands],
+        + [pl.BlockSpec(memory_space=pl.ANY) for _ in operands],
         out_specs=pl.BlockSpec((1, hkv, out_rows, d),
                                lambda bi, lens_p, tbl_p: (bi, 0, 0, 0)),
         scratch_shapes=land
@@ -1011,6 +1060,7 @@ def _paged_dispatch(kernel, qcat, operands, tables, lens, *, b, hkv, d,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, out_rows, d),
                                        qcat.dtype),
+        compiler_params=_COMPILER_PARAMS,
         interpret=not on_tpu(),
     )(lens.astype(jnp.int32), tables.astype(jnp.int32), qcat,
       *operands)

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._common import on_tpu, pallas_enabled
+from ._common import on_tpu, pallas_enabled, refused_for_partitioning
 
 BLOCK_M = 256
 BLOCK_N = 256
@@ -43,6 +43,7 @@ QMM_ROUTE_REASONS = (
     "int4_ok",
     "flag_disabled",
     "pallas_unavailable",
+    "gspmd_partitioned",
     "bad_rank",
     "k_mismatch",
     "geometry",
@@ -82,6 +83,8 @@ def _qmm_route_reason(x, qweight, bits=8, max_m=None, require_flag=True):
     if require_flag and not flag("use_int8_matmul_kernel"):
         return "flag_disabled"
     if not pallas_enabled():
+        if refused_for_partitioning():
+            return "gspmd_partitioned"
         return "pallas_unavailable"
     if x.ndim < 2 or qweight.ndim != 2:
         return "bad_rank"

@@ -75,14 +75,32 @@ def test_predictor_errors(saved_model):
         inference.create_predictor(inference.Config())
 
 
-def test_compilation_cache_dir(saved_model, tmp_path):
+@pytest.fixture(autouse=True)
+def _keep_suite_compile_cache():
+    """A Predictor with a configured cache directory moves JAX's
+    persistent cache there (``utils/compile_cache.py``); put the suite's
+    own directory back so the modules that follow keep their warm
+    cache."""
+    import jax
+    before = (jax.config.jax_compilation_cache_dir,
+              jax.config.jax_persistent_cache_min_compile_time_secs)
+    yield
+    jax.config.update("jax_compilation_cache_dir", before[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      before[1])
+
+
+def test_compilation_cache_dir(saved_model, tmp_path, monkeypatch):
+    import jax
     prefix, x, expected = saved_model
     cache = str(tmp_path / "xla_cache")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     config = inference.Config(prefix)
     config.set_compilation_cache_dir(cache)
     predictor = inference.create_predictor(config)
     outs = predictor.run([x])
     np.testing.assert_allclose(outs[0], expected, rtol=1e-5, atol=1e-5)
+    assert jax.config.jax_compilation_cache_dir == cache
     assert os.path.isdir(cache)
 
 

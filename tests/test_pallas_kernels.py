@@ -1163,10 +1163,24 @@ def test_decode_gate_mixed_dtype_rejects_and_int8_allowlisted(monkeypatch):
     q/cache dtypes REJECT (``dtype_mismatch``) unless the pair is on
     the explicit allowlist — (bf16|f32 q, int8 cache) — AND the caller
     carries the scale arenas; an allowlisted pairing that fails the
-    packed-geometry check rejects as ``int8_geom``."""
+    packed-geometry check rejects as ``int8_geom``.  The chip's own
+    rule (PR 22): scale planes narrower than a 128-lane tile cannot be
+    DMA'd, so only ``H_kv % 128 == 0`` routes — every served head
+    count rejects as ``int8_scale_lanes``."""
     from paddle_tpu.ops.pallas import decode_attention as da
     monkeypatch.setattr(da, "pallas_enabled", lambda: True)
-    b, hkv, g, blk_len, nb, mb, d = 2, 2, 2, 8, 8, 3, 64
+    for hkv_served in (2, 8):
+        q4 = jnp.zeros((2, hkv_served, 2, 64), jnp.bfloat16)
+        arena = jnp.zeros((9, 32, hkv_served * 64), jnp.int8)
+        planes = (jnp.ones((9, 32, hkv_served), jnp.float32),) * 2
+        tbl = jnp.zeros((2, 3), jnp.int32)
+        use, reason = da._route_decision_paged(q4, arena, tbl, planes)
+        assert not use and reason == "int8_scale_lanes"
+        use, reason = da._route_decision_paged_multi(
+            jnp.zeros((2, 3, hkv_served, 2, 64), jnp.bfloat16), arena,
+            tbl, planes)
+        assert not use and reason == "int8_scale_lanes"
+    b, hkv, g, blk_len, nb, mb, d = 2, 128, 2, 8, 8, 3, 64
     w = hkv * d
     tables = jnp.asarray(np.arange(nb)[:b * mb].reshape(b, mb), jnp.int32)
     sshape = (nb + 1, blk_len, hkv)
@@ -1268,3 +1282,22 @@ def test_decode_attention_paged_multi_int8_kernel_parity():
                            lens, (ks, vs)).reshape(b, cq, hkv, g, d)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
+
+
+def test_paged_gate_table_width_rule(monkeypatch):
+    """The v5e's semaphore memory bounds the block table (PR 22): two
+    staged operands compile at 208 blocks and fail at 224, so the gate
+    admits 219 and rejects 220 under ``paged_dma_sems`` — for the
+    K-wide verify kernel alike."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "pallas_enabled", lambda: True)
+    q4 = jnp.zeros((2, 2, 2, 64), jnp.bfloat16)
+    q5 = jnp.zeros((2, 3, 2, 2, 64), jnp.bfloat16)
+    arena = jnp.zeros((9, 8, 128), jnp.bfloat16)
+    for width, want in ((219, True), (220, False)):
+        tables = jnp.zeros((2, width), jnp.int32)
+        use, reason = da._route_decision_paged(q4, arena, tables)
+        assert use is want
+        assert reason == ("paged_ok" if want else "paged_dma_sems")
+        use, reason = da._route_decision_paged_multi(q5, arena, tables)
+        assert reason == ("paged_multi_ok" if want else "paged_dma_sems")

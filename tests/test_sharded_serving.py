@@ -201,6 +201,22 @@ def test_mesh_geometry_fallback(net2):
     assert eng1._shard is None and not eng1.shard_group["sharded"]
 
 
+def test_one_wide_mesh_places_engine_on_its_device(net2):
+    """A one-chip replica built on any device but the first keeps its
+    weights and arenas THERE, also after a drain (the donated round
+    trip).  (``chip_smoke.multichip_serving`` checks on real chips that
+    such replicas serve what the default-device engine serves.)"""
+    _, net = net2
+    dev = jax.devices()[3]
+    eng = _mk(net, mesh=build_mesh(mp=1, devices=[dev]))
+    assert eng.shard_group["devices"] == [dev.id]
+    req = eng.submit(np.arange(1, 8, dtype=np.int32), max_new_tokens=3)
+    eng.run()
+    assert req.output.shape == (3,)
+    assert all(a.devices() == {dev} for a in eng._arenas)
+    assert all(v.devices() == {dev} for v in eng._pb)
+
+
 def test_mesh_needs_model_axis(net2):
     _, net = net2
     bad = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",))
