@@ -813,6 +813,37 @@ class _ServingInstruments:
         return counter.total() - self._base.get(counter.name, 0)
 
 
+class _Phase(_span):
+    """One phase of a step, delimited ONCE: a span on the profiler's
+    clock whose two boundaries are also the two reads of the engine's
+    clock that the step's accumulators (``_disp_s``, ``_overlap_s``,
+    ``_stall_s``, the host remainder) are charged from.  ``seconds``
+    is the phase's duration once it has closed; ``stop()`` closes it
+    from inside the ``with`` suite and returns that."""
+
+    __slots__ = ("_clock", "_t0", "seconds")
+
+    def __init__(self, clock, name: str, **attrs):
+        super().__init__(name, **attrs)
+        self._clock = clock
+        self.seconds = None
+
+    def __enter__(self):
+        super().__enter__()
+        self._t0 = self._clock()
+        return self
+
+    def stop(self) -> float:
+        if self.seconds is None:
+            self.seconds = self._clock() - self._t0
+            super().__exit__(None, None, None)
+        return self.seconds
+
+    def __exit__(self, *exc):
+        self.stop()
+        return False
+
+
 def _call_quiet(fn, *args):
     """Invoke a compiled serving program with the donation warning
     suppressed for THIS call only: cache donation is a no-op (with a
@@ -1950,6 +1981,9 @@ class ServingEngine:
             return None
         return self._pool.alloc(n)
 
+    def _phase(self, name: str, **attrs) -> _Phase:
+        return _Phase(self._clock, name, **attrs)
+
     # -- dispatch-ahead pipeline (plan / harvest) --
     def _charge_overlap(self, dt: float):
         """Account time spent blocking on a PREVIOUS iteration's
@@ -2007,7 +2041,7 @@ class ServingEngine:
         return None
 
     # graftlint: plan-phase
-    def _harvest_next(self, out: List[Request]):
+    def _harvest_next(self, out: List[Request], reason: str):
         """Force the OLDEST pending dispatch's outputs to host and
         absorb them — the finish-bitmap poll site: the materialized
         ``done`` carry says which riders finished on device (EOS or
@@ -2015,30 +2049,34 @@ class ServingEngine:
         Harvest order is FIFO, so host truth (tokens, remaining, lens
         mirrors) is fresh up to the popped dispatch.  The wait charges
         to serving.step.overlap_seconds, never to host_seconds — this
-        is the slice the pipeline hides under device time."""
+        is the slice the pipeline hides under device time.  ``reason``
+        (``deferred``: the pipeline's own overlap point; else the
+        forced sync's reason) labels the ``serving.harvest`` span."""
         if not self._pend_q:
             return
-        p = self._pend_q.popleft()
-        self._m.async_depth.set(len(self._pend_q))
-        t0 = self._clock()
-        toks = np.asarray(p.toks_d)
-        tok = np.array(p.tok_d)       # np.array: writable host copies
-        lens = np.array(p.lens_d)
-        done = np.array(p.done_d)     # the finish bitmap
-        self._charge_overlap(self._clock() - t0)
-        toks = self._checked_harvest(toks)
-        n_before = len(out)
-        self._absorb_block(p, toks, tok, lens, done, out)
-        if self.async_depth == 1 and len(out) > n_before:
-            # the PR-10 contract at depth 1: deferral is legal ONLY
-            # when no rider can finish inside the block (EOS syncs,
-            # budget syncs) — a finish here means the defer predicate
-            # regressed, and silent off-schedule retirement is worse
-            # than a loud failure
-            raise RuntimeError(
-                "deferred harvest produced a finish at async_depth=1 "
-                "— the defer predicate (_block_sync_reason) is broken")
-        self._reconcile_host_tier()
+        with _span("serving.harvest", reason=reason):
+            p = self._pend_q.popleft()
+            self._m.async_depth.set(len(self._pend_q))
+            with self._phase("serving.harvest.wait") as wait:
+                toks = np.asarray(p.toks_d)
+                tok = np.array(p.tok_d)   # np.array: writable host copies
+                lens = np.array(p.lens_d)
+                done = np.array(p.done_d)     # the finish bitmap
+                self._charge_overlap(wait.stop())
+            toks = self._checked_harvest(toks)
+            n_before = len(out)
+            self._absorb_block(p, toks, tok, lens, done, out)
+            if self.async_depth == 1 and len(out) > n_before:
+                # the PR-10 contract at depth 1: deferral is legal
+                # ONLY when no rider can finish inside the block (EOS
+                # syncs, budget syncs) — a finish here means the defer
+                # predicate regressed, and silent off-schedule
+                # retirement is worse than a loud failure
+                raise RuntimeError(
+                    "deferred harvest produced a finish at "
+                    "async_depth=1 — the defer predicate "
+                    "(_block_sync_reason) is broken")
+            self._reconcile_host_tier()
 
     def _flush_async(self, reason: str,
                      out: Optional[List[Request]] = None):
@@ -2059,7 +2097,7 @@ class ServingEngine:
         self._m.async_syncs.inc(reason=reason)
         sink = out if out is not None else self._flush_finishes
         while self._pend_q:
-            self._harvest_next(sink)
+            self._harvest_next(sink, reason)
 
     def _reconcile_host_tier(self):
         """Materialize every demote parcel enqueued during plan (the
@@ -3902,108 +3940,107 @@ class ServingEngine:
             # becomes host truth THIS step (EOS check, decode-mix
             # entry, the slot's tok/lens carries) — the pipeline syncs
             self._flush_async("chunk_final", out)
-        flags, samp = self._build_samp([req])
-        lora_on, lora_planes = self._build_lora([req])
-        lora_args = (lora_planes,) if lora_on else ()
-        t0 = self._clock()
         with _span("serving.prefill", request=req.request_id,
                    slot=req.slot, start=start):
-            outp = _call_quiet(
-                self._chunk_fn(flags, lora_on), self._pb,
-                jnp.asarray(req.chunk_ids[None, start:start + c]),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(req.seq_len, jnp.int32),
-                jnp.asarray(self._tables[req.slot][None, :]), samp,
-                *lora_args, *self._arenas)
-            self._arenas = list(outp[1:])
-            # a non-final chunk's sampled token is meaningless (the
-            # engine never advances decode state from it): the
-            # dispatch-ahead engine leaves it un-forced, so the chunk
-            # computes under the NEXT iterations' host work; the
-            # final chunk's token is host truth and materializes here
-            tok0 = (int(np.asarray(outp[0])[0])
-                    if is_final or not self.async_dispatch else None)
-        self._m.prefill_chunks.inc()
-        dt = self._clock() - t0
-        self._m.chunk_latency.observe(dt)
-        self._disp_s += dt
-        self._count_kv_sweep([min(start + c, req.seq_len) - 1])
-        self._count_weight_sweep(1)
-        # goodput: the dispatch computed chunk_len positions for this
-        # row — valid prompt positions split first-time-useful vs
-        # cache-known recompute (the [gp_recompute_from, _to) span set
-        # at admission), the grid tail past seq_len is pad
-        valid = min(start + c, req.seq_len) - start
-        rc = max(0, (min(start + valid, req.gp_recompute_to)
-                     - max(start, req.gp_recompute_from)))
-        self._ledger(valid - rc, tenant=req.tenant,
-                     recompute_cache=rc, pad=c - valid)
-        self._fr.emit("prefill_chunk", req.request_id, self._step_idx,
-                      start=start, tokens=valid)
-        req.pf_pos = start + c
-        if self._radix is not None:
-            full = min(req.pf_pos, req.seq_len) // self.block_len
-            if full > req.registered:
-                # token runs + block spans go into the tree as soon as
-                # the blocks are fully written (first writer wins; the
-                # request's pin keeps them alive until release, after
-                # which they park tree-held in the reclaimable LRU)
-                self._radix.insert(req.prompt, req.blocks, full,
-                                   start_block=req.registered)
-                req.registered = full
-        elif self.enable_prefix_cache:
-            full = min(req.pf_pos, req.seq_len) // self.block_len
-            while req.registered < min(full, len(req.digests)):
-                i = req.registered
-                self._pool.register(req.blocks[i], req.digests[i])
-                req.registered = i + 1
-        if req.pf_pos < req.seq_len:
-            return                        # more chunks to go
-        # final chunk: tok0 is the request's first generated token
-        self._prefilling.popleft()
-        self._m.prefills.inc()
-        self._m.tokens_emitted.inc()
-        t = self._clock()
-        req.first_token_time = t
-        if req.ttft is not None:
-            self._m.ttft.observe(req.ttft)
-        req.tokens.append(tok0)
-        req.remaining = req.max_new_tokens - 1
-        self._count_sample_route([(req, 1)])
-        slot = req.slot
-        if (self.cfg.eos_token_id is not None and
-                tok0 == self.cfg.eos_token_id) or req.remaining == 0:
-            # finished at the first token: never enters the decode mix
-            self._slots[slot] = None
-            self._done[slot] = True
-            self._release_blocks(req)
-            self._finish(req, t, out)
-            return
-        if req.sampling is not None and \
-                req.sampling.mask_processor is not None and \
-                self._mask_dead_end(req):
-            self._slots[slot] = None
-            self._done[slot] = True
-            self._release_blocks(req)
-            self._finish(req, t, out)
-            return
-        if self.role == "prefill":
-            # the disaggregation point (ROADMAP item 2): a prefill-
-            # role replica never decodes in place — gather the
-            # request's KV parcel at exact at-rest bytes and stage it
-            # for router pickup; the chosen decode replica resumes
-            # token-exact through the unchanged migrate_in/_try_resume
-            # path (tok0 travels in the parcel's tok carry)
-            self._handoff_out(req, tok0, slot)
-            return
-        req.state = "decode"
-        self._tok[slot] = tok0
-        self._lens[slot] = req.seq_len
-        # spec-mode rows never ride the plain decode block: their row
-        # stays done=True there (frozen lens, trash-routed writes, pad
-        # emits) and all progress happens in the verify dispatch, which
-        # reads its own host-side truth (req.tokens / self._lens)
-        self._done[slot] = req.spec_k is not None
+            flags, samp = self._build_samp([req])
+            lora_on, lora_planes = self._build_lora([req])
+            lora_args = (lora_planes,) if lora_on else ()
+            with self._phase("serving.prefill.dispatch") as ph:
+                outp = _call_quiet(
+                    self._chunk_fn(flags, lora_on), self._pb,
+                    jnp.asarray(req.chunk_ids[None, start:start + c]),
+                    jnp.asarray(start, jnp.int32),
+                    jnp.asarray(req.seq_len, jnp.int32),
+                    jnp.asarray(self._tables[req.slot][None, :]), samp,
+                    *lora_args, *self._arenas)
+                self._arenas = list(outp[1:])
+                # a non-final chunk's sampled token is meaningless (the
+                # engine never advances decode state from it): the
+                # dispatch-ahead engine leaves it un-forced, so the chunk
+                # computes under the NEXT iterations' host work; the
+                # final chunk's token is host truth and materializes here
+                tok0 = (int(np.asarray(outp[0])[0])
+                        if is_final or not self.async_dispatch else None)
+            self._m.prefill_chunks.inc()
+            self._m.chunk_latency.observe(ph.seconds)
+            self._disp_s += ph.seconds
+            self._count_kv_sweep([min(start + c, req.seq_len) - 1])
+            self._count_weight_sweep(1)
+            # goodput: the dispatch computed chunk_len positions for this
+            # row — valid prompt positions split first-time-useful vs
+            # cache-known recompute (the [gp_recompute_from, _to) span set
+            # at admission), the grid tail past seq_len is pad
+            valid = min(start + c, req.seq_len) - start
+            rc = max(0, (min(start + valid, req.gp_recompute_to)
+                         - max(start, req.gp_recompute_from)))
+            self._ledger(valid - rc, tenant=req.tenant,
+                         recompute_cache=rc, pad=c - valid)
+            self._fr.emit("prefill_chunk", req.request_id, self._step_idx,
+                          start=start, tokens=valid)
+            req.pf_pos = start + c
+            if self._radix is not None:
+                full = min(req.pf_pos, req.seq_len) // self.block_len
+                if full > req.registered:
+                    # token runs + block spans go into the tree as soon as
+                    # the blocks are fully written (first writer wins; the
+                    # request's pin keeps them alive until release, after
+                    # which they park tree-held in the reclaimable LRU)
+                    self._radix.insert(req.prompt, req.blocks, full,
+                                       start_block=req.registered)
+                    req.registered = full
+            elif self.enable_prefix_cache:
+                full = min(req.pf_pos, req.seq_len) // self.block_len
+                while req.registered < min(full, len(req.digests)):
+                    i = req.registered
+                    self._pool.register(req.blocks[i], req.digests[i])
+                    req.registered = i + 1
+            if req.pf_pos < req.seq_len:
+                return                        # more chunks to go
+            # final chunk: tok0 is the request's first generated token
+            self._prefilling.popleft()
+            self._m.prefills.inc()
+            self._m.tokens_emitted.inc()
+            t = self._clock()
+            req.first_token_time = t
+            if req.ttft is not None:
+                self._m.ttft.observe(req.ttft)
+            req.tokens.append(tok0)
+            req.remaining = req.max_new_tokens - 1
+            self._count_sample_route([(req, 1)])
+            slot = req.slot
+            if (self.cfg.eos_token_id is not None and
+                    tok0 == self.cfg.eos_token_id) or req.remaining == 0:
+                # finished at the first token: never enters the decode mix
+                self._slots[slot] = None
+                self._done[slot] = True
+                self._release_blocks(req)
+                self._finish(req, t, out)
+                return
+            if req.sampling is not None and \
+                    req.sampling.mask_processor is not None and \
+                    self._mask_dead_end(req):
+                self._slots[slot] = None
+                self._done[slot] = True
+                self._release_blocks(req)
+                self._finish(req, t, out)
+                return
+            if self.role == "prefill":
+                # the disaggregation point (ROADMAP item 2): a prefill-
+                # role replica never decodes in place — gather the
+                # request's KV parcel at exact at-rest bytes and stage it
+                # for router pickup; the chosen decode replica resumes
+                # token-exact through the unchanged migrate_in/_try_resume
+                # path (tok0 travels in the parcel's tok carry)
+                self._handoff_out(req, tok0, slot)
+                return
+            req.state = "decode"
+            self._tok[slot] = tok0
+            self._lens[slot] = req.seq_len
+            # spec-mode rows never ride the plain decode block: their row
+            # stays done=True there (frozen lens, trash-routed writes, pad
+            # emits) and all progress happens in the verify dispatch, which
+            # reads its own host-side truth (req.tokens / self._lens)
+            self._done[slot] = req.spec_k is not None
 
     def _handoff_out(self, req: Request, tok0: int, slot: int):
         """Chunk-final handoff swap-out (prefill-role engines only):
@@ -4224,8 +4261,8 @@ class ServingEngine:
         flags, samp = self._build_samp(riding)
         lora_on, lora_planes = self._build_lora(riding)
         lora_args = (lora_planes,) if lora_on else ()
-        t0 = self._clock()
-        with _span("serving.spec_verify", width=width, active=len(spec)):
+        with self._phase("serving.spec_verify", width=width,
+                         active=len(spec)) as ph:
             outp = _call_quiet(
                 self._verify_fn(width, flags, lora_on), self._pb,
                 jnp.asarray(toks),
@@ -4240,7 +4277,7 @@ class ServingEngine:
             else:
                 greedy = np.asarray(outp[0])            # [B, width]
                 self._arenas = list(outp[1:])
-        self._disp_s += self._clock() - t0
+        self._disp_s += ph.seconds
         self._m.spec_verifies.inc()
         # the K-wide kernel DMAs the STATIC width's frontier
         # (lens + cq - 1) for every spec row, however few positions
@@ -4315,26 +4352,34 @@ class ServingEngine:
         host_seconds`` — the pure host-scheduler slice the
         dispatch-ahead pipeline hides under device time.  Steps that
         dispatched nothing (idle admission polls) observe neither
-        host nor dispatch."""
+        host nor dispatch.
+
+        Each phase is delimited ONCE (``_phase``): the same boundaries
+        open and close a span, so under a profiler session the
+        iteration reads ``serving.step`` > ``serving.admit``,
+        ``serving.prefill`` (> ``.dispatch``), ``serving.spec_verify``,
+        ``serving.plan``, ``serving.decode_block``, ``serving.harvest``
+        (> ``.wait``) on the device trace's clock."""
         self._step_idx += 1
         self._disp_s = 0.0
         self._overlap_s = 0.0
         self._stall_s = 0.0
         self._in_step = True
-        t0 = self._clock()
-        try:
-            out = self._step_inner(now)
-            # reconcile any demote gathers this step enqueued so their
-            # wait is attributed HERE (and the device copies do not
-            # outlive the step)
-            self._reconcile_host_tier()
-        finally:
-            self._in_step = False
+        with self._phase("serving.step", step=self._step_idx,
+                         queued=len(self._queue)) as whole:
+            try:
+                out = self._step_inner(now)
+                # reconcile any demote gathers this step enqueued so
+                # their wait is attributed HERE (and the device copies
+                # do not outlive the step)
+                self._reconcile_host_tier()
+            finally:
+                self._in_step = False
         disp = self._disp_s
         if disp > 0.0:
             self._m.step_dispatch.observe(disp)
             self._m.step_host.observe(
-                max((self._clock() - t0) - disp - self._overlap_s
+                max(whole.seconds - disp - self._overlap_s
                     - self._stall_s, 0.0))
         return out
 
@@ -4356,50 +4401,51 @@ class ServingEngine:
         else:
             self._step_dt = 0.0
             self._last_now = None
-        if self._fault is not None:
-            # replica-fatal faults raise BEFORE any scheduling work
-            # mutates state: a killed/wedged replica did not run this
-            # step, and the router's failover recovers from the last
-            # consistent host truth
-            if self._fault.take_kill(self._step_idx):
-                raise ReplicaKilledError(
-                    f"injected replica kill at step {self._step_idx} "
-                    f"(latched until the injector's replica restart)")
-            if self._fault.take_permanent_stall():
-                raise EngineStalledError(
-                    f"injected permanent stall at step "
-                    f"{self._step_idx}: the dispatch will never "
-                    f"return (latched until the injector's replica "
-                    f"restart)")
-            stall = self._fault.take_stall()
-            if stall:
-                with _span("serving.fault.stall", seconds=stall):
-                    t0s = self._clock()
-                    time.sleep(stall)
-                    dt = self._clock() - t0s
-                # charge the injected sleep to its OWN histogram and
-                # carve it out of host_seconds: a fault-injection run
-                # must not pollute the host-scheduler baseline the
-                # dispatch-ahead pipeline is judged against
-                self._stall_s += dt
-                self._m.stall_seconds.observe(dt)
-            for rid in self._fault.take_forced_swaps():
-                for r in self._slots:
-                    if r is not None and r.request_id == rid \
-                            and r.state in ("prefill", "decode"):
-                        self._preempt(r, reason="forced",
-                                      out=finished)
-                        break
-            n_evict = self._fault.take_tier_evicts()
-            if n_evict:
-                applied = 0
-                for _ in range(n_evict):
-                    if not self._host_tier.evict_one():
-                        break
-                    applied += 1
-                self._fault.record_tier_evicts(applied)
-                self._update_host_gauge()
-        self._admit(t_now, finished)
+        with _span("serving.admit", queued=len(self._queue)):
+            if self._fault is not None:
+                # replica-fatal faults raise BEFORE any scheduling work
+                # mutates state: a killed/wedged replica did not run this
+                # step, and the router's failover recovers from the last
+                # consistent host truth
+                if self._fault.take_kill(self._step_idx):
+                    raise ReplicaKilledError(
+                        f"injected replica kill at step {self._step_idx} "
+                        f"(latched until the injector's replica restart)")
+                if self._fault.take_permanent_stall():
+                    raise EngineStalledError(
+                        f"injected permanent stall at step "
+                        f"{self._step_idx}: the dispatch will never "
+                        f"return (latched until the injector's replica "
+                        f"restart)")
+                stall = self._fault.take_stall()
+                if stall:
+                    with self._phase("serving.fault.stall",
+                                     seconds=stall) as ph:
+                        time.sleep(stall)
+                    dt = ph.seconds
+                    # charge the injected sleep to its OWN histogram and
+                    # carve it out of host_seconds: a fault-injection run
+                    # must not pollute the host-scheduler baseline the
+                    # dispatch-ahead pipeline is judged against
+                    self._stall_s += dt
+                    self._m.stall_seconds.observe(dt)
+                for rid in self._fault.take_forced_swaps():
+                    for r in self._slots:
+                        if r is not None and r.request_id == rid \
+                                and r.state in ("prefill", "decode"):
+                            self._preempt(r, reason="forced",
+                                          out=finished)
+                            break
+                n_evict = self._fault.take_tier_evicts()
+                if n_evict:
+                    applied = 0
+                    for _ in range(n_evict):
+                        if not self._host_tier.evict_one():
+                            break
+                        applied += 1
+                    self._fault.record_tier_evicts(applied)
+                    self._update_host_gauge()
+            self._admit(t_now, finished)
         self._prefill_chunk(finished)
         self._spec_fallback = set()
         self._spec_verify(finished)
@@ -4428,129 +4474,130 @@ class ServingEngine:
             self._m.slot_occupancy.set(
                 sum(r is not None for r in self._slots))
             return finished
-        # a full block only when no active request can finish inside it
-        # (a block never overshoots a budget or a block table); otherwise
-        # drop to exact iteration-level single steps.  Mask-constrained
-        # rows clamp the mix to single steps too: their bias plane is
-        # valid for exactly ONE emitted token — the host state machine
-        # must observe it before the next bias can be built.  The clamp
-        # prices ALL co-resident rows at one dispatch per token while a
-        # masked row is live (deliberate: masked workloads are latency-
-        # shaped and the alternative — freezing masked rows out of the
-        # n-step block via the done plane and feeding them a second
-        # 1-step dispatch per iteration — doubles dispatches and
-        # accounting paths for a mix this engine rarely sees)
-        pend = self._pend_q[-1] if self._pend_q else None
-        if pend is not None:
-            # structurally impossible either way (new decode entrants
-            # sync via chunk_final/resume, cancel and preempt flush) —
-            # a drift means the invariant broke and dispatching would
-            # corrupt carries: fail loudly.  At depth 1 the set must
-            # match EXACTLY (no rider can finish while deferred — the
-            # PR-10 contract); at depth >= 2 riders legally LEAVE a
-            # deferred set by finishing on device, so only growth is
-            # a breach.
-            if self.async_depth == 1:
-                if pend.active != active:
+        with _span("serving.plan", active=len(active),
+                   queued=len(self._queue)):
+            # a full block only when no active request can finish inside it
+            # (a block never overshoots a budget or a block table); otherwise
+            # drop to exact iteration-level single steps.  Mask-constrained
+            # rows clamp the mix to single steps too: their bias plane is
+            # valid for exactly ONE emitted token — the host state machine
+            # must observe it before the next bias can be built.  The clamp
+            # prices ALL co-resident rows at one dispatch per token while a
+            # masked row is live (deliberate: masked workloads are latency-
+            # shaped and the alternative — freezing masked rows out of the
+            # n-step block via the done plane and feeding them a second
+            # 1-step dispatch per iteration — doubles dispatches and
+            # accounting paths for a mix this engine rarely sees)
+            pend = self._pend_q[-1] if self._pend_q else None
+            if pend is not None:
+                # structurally impossible either way (new decode entrants
+                # sync via chunk_final/resume, cancel and preempt flush) —
+                # a drift means the invariant broke and dispatching would
+                # corrupt carries: fail loudly.  At depth 1 the set must
+                # match EXACTLY (no rider can finish while deferred — the
+                # PR-10 contract); at depth >= 2 riders legally LEAVE a
+                # deferred set by finishing on device, so only growth is
+                # a breach.
+                if self.async_depth == 1:
+                    if pend.active != active:
+                        raise RuntimeError(
+                            f"dispatch-ahead riding set drifted while a "
+                            f"harvest was deferred: pending {pend.active} "
+                            f"vs now {active}")
+                elif not set(active) <= set(pend.active):
                     raise RuntimeError(
-                        f"dispatch-ahead riding set drifted while a "
-                        f"harvest was deferred: pending {pend.active} "
-                        f"vs now {active}")
-            elif not set(active) <= set(pend.active):
-                raise RuntimeError(
-                    f"dispatch-ahead riding set grew while a harvest "
-                    f"was deferred: pending {pend.active} vs now "
-                    f"{active}")
-        # stale-truth correction: while harvests are deferred, each
-        # rider's host truth (remaining, len(tokens), lens mirror) is
-        # behind by exactly the steps still in flight (every rider
-        # rides every pending dispatch — it entered before the oldest
-        # and can only leave by finishing, which is discovered AT
-        # harvest)
-        lag = sum(p.n for p in self._pend_q)
-        min_budget = min(self._slots[i].remaining for i in active) - lag
-        masked = any(self._slots[i].sampling is not None and
-                     self._slots[i].sampling.mask_processor is not None
-                     for i in active)
-        n = 1 if (min_budget < self.steps_per_call or masked) \
-            else self.steps_per_call
-        # fused multi-iteration window (async_depth >= 2): when the
-        # next S iterations are PROVABLY eventless — nothing queued or
-        # swapped to admit, no chunk to ride, the dispatch itself
-        # deferrable (no mask/penalty/spec row) and budget headroom
-        # strictly beyond the whole window for every rider — dispatch
-        # S iterations as ONE fused scan program, amortizing the
-        # per-dispatch host cost the way decode_scan_body amortizes
-        # the per-token cost.  EOS inside the window is legal: the
-        # finish bitmap freezes the row in-trace and the harvest
-        # re-splits the window iteration by iteration.
-        iters = 1
-        fuse_cap = self.async_depth
-        if self._queue:
-            # a queued request normally blocks fusing outright (its
-            # admission is an event inside the window).  Arrival-aware
-            # sizing (PR 14's open follow-on): when every queued entry
-            # is a known FUTURE arrival and the trace drives step(now=)
-            # on a monotonic clock, the last observed per-step
-            # now-delta bounds the steps until the earliest arrival —
-            # fuse min(S, steps_until_arrival), so the window SHRINKS
-            # to close at the arrival step instead of degrading to
-            # unfused.  Already-arrived entries (or no step-rate
-            # estimate) keep the conservative outright block.
-            fuse_cap = 0
-            if self._step_dt > 0 and \
-                    all(r.arrival_time > t_now for r in self._queue):
-                nxt = min(r.arrival_time for r in self._queue)
-                until = int(-(-(nxt - t_now) // self._step_dt))
-                fuse_cap = min(self.async_depth, until)
-        if (self.async_depth > 1 and not masked
-                and not self._prefilling and not self._swapped
-                and fuse_cap > 1
-                and min_budget > self.async_depth * n
-                and self._block_sync_reason(n, active, lag) is None):
-            iters = fuse_cap
-        n_total = n * iters
-        active_set = set(active)
-        riding = [self._slots[i] if i in active_set else None
-                  for i in range(self.num_slots)]
-        flags, samp = self._build_samp(riding, pos_lag=lag)
-        # adapter ids are host-plan state pinned with the riding set
-        # (which cannot grow while a harvest is deferred), so the
-        # dispatch-ahead pipeline carries them one-step-stale for free
-        lora_on, lora_planes = self._build_lora(riding)
-        lora_args = (lora_planes,) if lora_on else ()
-        pre_lens = np.array(self._lens)
-        if pend is not None:
-            # every current rider rode every pending dispatch (subset
-            # check above), so its true pre-dispatch lens is the host
-            # mirror + the in-flight steps (rows an in-flight EOS
-            # already froze advance less — the harvest's sweep model
-            # clamps to their final lens)
-            pre_lens[active] += lag
-            # double-buffered carries: feed the newest in-flight
-            # dispatch's device outputs straight into this one — no
-            # host round-trip, no wait.  budget rides the same carry
-            # chain (the finish-bitmap protocol).
-            tok_in, lens_in, done_in, budget_in = \
-                pend.tok_d, pend.lens_d, pend.done_d, pend.budget_d
-        else:
-            budget = np.zeros((self.num_slots,), np.int32)
-            for i in active:
-                budget[i] = self._slots[i].remaining
-            tok_in = jnp.asarray(self._tok)
-            lens_in = jnp.asarray(self._lens)
-            done_in = jnp.asarray(self._done)
-            budget_in = jnp.asarray(budget)
-        t_blk = self._clock()
-        with _span("serving.decode_block", steps=n_total,
-                   active=len(active)):
+                        f"dispatch-ahead riding set grew while a harvest "
+                        f"was deferred: pending {pend.active} vs now "
+                        f"{active}")
+            # stale-truth correction: while harvests are deferred, each
+            # rider's host truth (remaining, len(tokens), lens mirror) is
+            # behind by exactly the steps still in flight (every rider
+            # rides every pending dispatch — it entered before the oldest
+            # and can only leave by finishing, which is discovered AT
+            # harvest)
+            lag = sum(p.n for p in self._pend_q)
+            min_budget = min(self._slots[i].remaining for i in active) - lag
+            masked = any(self._slots[i].sampling is not None and
+                         self._slots[i].sampling.mask_processor is not None
+                         for i in active)
+            n = 1 if (min_budget < self.steps_per_call or masked) \
+                else self.steps_per_call
+            # fused multi-iteration window (async_depth >= 2): when the
+            # next S iterations are PROVABLY eventless — nothing queued or
+            # swapped to admit, no chunk to ride, the dispatch itself
+            # deferrable (no mask/penalty/spec row) and budget headroom
+            # strictly beyond the whole window for every rider — dispatch
+            # S iterations as ONE fused scan program, amortizing the
+            # per-dispatch host cost the way decode_scan_body amortizes
+            # the per-token cost.  EOS inside the window is legal: the
+            # finish bitmap freezes the row in-trace and the harvest
+            # re-splits the window iteration by iteration.
+            iters = 1
+            fuse_cap = self.async_depth
+            if self._queue:
+                # a queued request normally blocks fusing outright (its
+                # admission is an event inside the window).  Arrival-aware
+                # sizing (PR 14's open follow-on): when every queued entry
+                # is a known FUTURE arrival and the trace drives step(now=)
+                # on a monotonic clock, the last observed per-step
+                # now-delta bounds the steps until the earliest arrival —
+                # fuse min(S, steps_until_arrival), so the window SHRINKS
+                # to close at the arrival step instead of degrading to
+                # unfused.  Already-arrived entries (or no step-rate
+                # estimate) keep the conservative outright block.
+                fuse_cap = 0
+                if self._step_dt > 0 and \
+                        all(r.arrival_time > t_now for r in self._queue):
+                    nxt = min(r.arrival_time for r in self._queue)
+                    until = int(-(-(nxt - t_now) // self._step_dt))
+                    fuse_cap = min(self.async_depth, until)
+            if (self.async_depth > 1 and not masked
+                    and not self._prefilling and not self._swapped
+                    and fuse_cap > 1
+                    and min_budget > self.async_depth * n
+                    and self._block_sync_reason(n, active, lag) is None):
+                iters = fuse_cap
+            n_total = n * iters
+            active_set = set(active)
+            riding = [self._slots[i] if i in active_set else None
+                      for i in range(self.num_slots)]
+            flags, samp = self._build_samp(riding, pos_lag=lag)
+            # adapter ids are host-plan state pinned with the riding set
+            # (which cannot grow while a harvest is deferred), so the
+            # dispatch-ahead pipeline carries them one-step-stale for free
+            lora_on, lora_planes = self._build_lora(riding)
+            lora_args = (lora_planes,) if lora_on else ()
+            pre_lens = np.array(self._lens)
+            if pend is not None:
+                # every current rider rode every pending dispatch (subset
+                # check above), so its true pre-dispatch lens is the host
+                # mirror + the in-flight steps (rows an in-flight EOS
+                # already froze advance less — the harvest's sweep model
+                # clamps to their final lens)
+                pre_lens[active] += lag
+                # double-buffered carries: feed the newest in-flight
+                # dispatch's device outputs straight into this one — no
+                # host round-trip, no wait.  budget rides the same carry
+                # chain (the finish-bitmap protocol).
+                tok_in, lens_in, done_in, budget_in = \
+                    pend.tok_d, pend.lens_d, pend.done_d, pend.budget_d
+            else:
+                budget = np.zeros((self.num_slots,), np.int32)
+                for i in active:
+                    budget[i] = self._slots[i].remaining
+                tok_in = jnp.asarray(self._tok)
+                lens_in = jnp.asarray(self._lens)
+                done_in = jnp.asarray(self._done)
+                budget_in = jnp.asarray(budget)
+            tables_in = jnp.asarray(self._decode_tables())
+        with self._phase("serving.decode_block", steps=n_total,
+                         active=len(active)) as ph:
             out = _call_quiet(
                 self._block_fn(n_total, flags, lora_on, iters=iters),
                 self._pb, tok_in, lens_in, done_in, budget_in, samp,
-                *lora_args, jnp.asarray(self._decode_tables()),
-                *self._arenas)
-        self._arenas = list(out[5:])
-        self._disp_s += self._clock() - t_blk
+                *lora_args, tables_in, *self._arenas)
+            self._arenas = list(out[5:])
+        self._disp_s += ph.seconds
         # plan-known accounting lands at DISPATCH (same step as the
         # lockstep engine); output-dependent accounting (KV sweep,
         # ledger, token streams, flight-recorder events) lands at
@@ -4576,7 +4623,7 @@ class ServingEngine:
         # only now, after this iteration's host work ran and its
         # dispatch was enqueued — harvest down to the configured depth
         while len(self._pend_q) > self.async_depth:
-            self._harvest_next(finished)
+            self._harvest_next(finished, "deferred")
             self._m.async_harvests.inc()
         # defer or sync the tail.  Riders a same-step harvest just
         # retired are skipped inside _block_sync_reason; the remaining
@@ -4596,21 +4643,22 @@ class ServingEngine:
             # older dispatches flush first, FIFO (their waits charge
             # to overlap — they did run under later host work) ...
             while len(self._pend_q) > 1:
-                self._harvest_next(finished)
+                self._harvest_next(finished, reason)
             self._pend_q.pop()
             self._m.async_depth.set(0)
-            t_mat = self._clock()
-            toks = np.asarray(new_pend.toks_d)          # [B, n]
-            tok = np.array(new_pend.tok_d)  # np.array: writable copies
-            lens = np.array(new_pend.lens_d)
-            done = np.array(new_pend.done_d)
-            # ... and the new dispatch's sync materialization is part
-            # of the dispatch, exactly the lockstep engine's
-            # attribution
-            self._disp_s += self._clock() - t_mat
-            toks = self._checked_harvest(toks)
-            self._absorb_block(new_pend, toks, tok, lens, done,
-                               finished)
+            with _span("serving.harvest", reason=reason):
+                with self._phase("serving.harvest.wait") as wait:
+                    toks = np.asarray(new_pend.toks_d)      # [B, n]
+                    tok = np.array(new_pend.tok_d)  # writable copies
+                    lens = np.array(new_pend.lens_d)
+                    done = np.array(new_pend.done_d)
+                # ... and the new dispatch's sync materialization is
+                # part of the dispatch, exactly the lockstep engine's
+                # attribution
+                self._disp_s += wait.seconds
+                toks = self._checked_harvest(toks)
+                self._absorb_block(new_pend, toks, tok, lens, done,
+                                   finished)
         return finished
 
     def _stall_diagnosis(self, wall_timeout_s: float) -> str:

@@ -10,9 +10,11 @@ Two cooperating layers (see the module docstrings for design notes):
   the Pallas decode-attention routing gate and the kernel tuner record
   into the default registry.
 - :mod:`~paddle_tpu.observability.spans` — ``span(name, **attrs)``
-  ranges over ``runtime.HostTracer`` and ``merge_chrome_traces`` to
-  stitch the host trace with the ``jax.profiler`` device dump into one
-  Perfetto-loadable file.
+  ranges, live under a ``jax.profiler`` session or the ``Profiler``:
+  written into the profiler's own trace (one clock with the device)
+  and into ``runtime.HostTracer``'s buffer (``recorded()``);
+  ``merge_chrome_traces`` writes host lanes into one Perfetto-loadable
+  file.
 - :mod:`~paddle_tpu.observability.flightrec` — the per-request
   ``FlightRecorder``: a bounded ring of structured lifecycle events
   the serving engine emits, with ``timeline()``/``explain()`` queries,
@@ -41,7 +43,8 @@ from .metrics import (  # noqa: F401
     diff_snapshots, get_registry,
 )
 from .spans import (  # noqa: F401
-    format_span_name, instant, merge_chrome_traces, parse_span_name, span,
+    format_span_name, instant, merge_chrome_traces, parse_span_name,
+    recorded, span,
 )
 from .flightrec import (  # noqa: F401
     EVENT_KINDS, FlightEvent, FlightRecord, FlightRecorder,
@@ -57,7 +60,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "DEFAULT_BUCKETS",
     "NAME_RE", "diff_snapshots", "get_registry",
     "span", "instant", "format_span_name", "parse_span_name",
-    "merge_chrome_traces",
+    "merge_chrome_traces", "recorded",
     "EVENT_KINDS", "FlightEvent", "FlightRecord", "FlightRecorder",
     "explain_events", "load_flight_record",
     "ALERT_KINDS", "SLOBurnRateMonitor", "StitchedEvent",

@@ -416,9 +416,7 @@ class StitchedRecord:
                          **e.attrs}})
         return out
 
-    def export_chrome_trace(self, out_path: str,
-                            device_trace_dir: Optional[str] = None
-                            ) -> dict:
+    def export_chrome_trace(self, out_path: str) -> dict:
         """One-call Perfetto export through the existing
         ``merge_chrome_traces`` writer (replica lanes via its
         ``extra=`` hook; a host pid-0 metadata line precedes replica
@@ -426,7 +424,6 @@ class StitchedRecord:
         "replica 0")."""
         from .spans import merge_chrome_traces
         return merge_chrome_traces(out_path, host=[],
-                                   device_trace_dir=device_trace_dir,
                                    extra=self.chrome_events())
 
 

@@ -11,7 +11,7 @@ a bounded ring buffer; ``timeline()`` filters one request's events,
 ``explain()`` renders them as one human-readable sentence, and
 ``chrome_events()`` re-encodes the ring as HostTracer-style event
 tuples (one lane per request) that ``merge_chrome_traces`` stitches
-into the same Perfetto file as the host spans and the device dump.
+into the same Perfetto file as the host spans.
 
 Design constraints (mirrors ``observability.metrics``):
 
@@ -214,16 +214,13 @@ class FlightRecorder:
             out.append((1, t, t, e.request, 0, name))
         return out
 
-    def export_chrome_trace(self, out_path: str, host=None,
-                            device_trace_dir: Optional[str] = None
-                            ) -> dict:
+    def export_chrome_trace(self, out_path: str, host=None) -> dict:
         """One-call Perfetto export: the flight-recorder lanes plus
-        optional host-tracer events (a list of event tuples) and the
-        jax.profiler device dump, through ``merge_chrome_traces``."""
+        optional host-tracer events (a list of event tuples), through
+        ``merge_chrome_traces``."""
         from .spans import merge_chrome_traces
         events = self.chrome_events() + list(host or [])
-        return merge_chrome_traces(out_path, host=events,
-                                   device_trace_dir=device_trace_dir)
+        return merge_chrome_traces(out_path, host=events)
 
 
 def events_from_record(record: dict) -> List[FlightEvent]:

@@ -1,53 +1,96 @@
-"""Structured spans over the host tracer + host/device trace merging.
+"""Structured spans on the profiler's clock, recorded in the host tracer.
 
-``span(name, **attrs)`` is the structured face of
-``runtime.HostTracer``: a range on the calling thread's lane whose
-attributes are encoded into the event name (the tracer's native event
-tuple has no args field — native C++ and Python fallback share the
-``(kind, t0, t1, tid, value, name)`` schema), using ``;k=v`` suffixes
-that ``parse_span_name`` and the chrome-trace merger decode back into
-Perfetto ``args``.  When the tracer is disabled ``__enter__`` is one
-attribute load + bool test — attrs are never formatted — so
+``span(name, **attrs)`` is *live* while a ``jax.profiler`` session is
+live (``TraceAnnotation.is_enabled()``: ``jax.profiler.start_trace``, a
+TensorBoard capture) or while ``runtime.HostTracer`` is enabled (the
+``paddle_tpu.profiler.Profiler`` path).  A live span does two things:
+
+- it opens a ``jax.profiler.TraceAnnotation(name, **attrs)``, so it sits
+  in the profiler's own ``.xplane.pb`` / Perfetto file on the host's
+  ``python`` line, on ONE clock with the device's ``XLA Ops`` by
+  construction, its attributes as event stats;
+- it is recorded in the ONE in-memory buffer, ``HostTracer``'s, on that
+  tracer's monotonic clock.  The tracer's native event tuple has no
+  args field (native C++ and Python fallback share the
+  ``(kind, t0, t1, tid, value, name)`` schema), so attributes are
+  encoded into the event name with ``;k=v`` suffixes; ``recorded()``
+  and the chrome-trace writer decode them back.
+
+Going live because of a profiler session alone starts a new trace
+generation and clears the buffer, exactly as ``Profiler`` does at a
+record window's start; when the session has ended, the next span or
+instant sees it and goes quiet.  When nothing is live ``__enter__`` is
+one attribute load and one ``is_enabled()`` -- attrs are never
+formatted, no object beyond the ``span`` itself is made -- so
 instrumented hot loops (the serving scheduler) pay nothing outside a
 profiling window.
 
-``merge_chrome_traces`` stitches the host chrome trace and the
-``jax.profiler`` device dump (the ``*.trace.json.gz`` files
-``DeviceSummaryView._load`` reads) into ONE Perfetto-loadable JSON:
-host lanes keep pid 0, device processes are re-numbered into a disjoint
-pid range, and metadata (process/thread names) is preserved.  The two
-clock domains are not re-aligned — Perfetto shows them as separate
-process groups, which is what correlating "queue stall here, device
-idle there" needs in practice.
+``merge_chrome_traces`` writes host lanes (the tracer's events, the
+flight recorder's per-request lanes, the fleet's per-replica lanes)
+into one Perfetto-loadable JSON.  The device's events are not pasted
+in: the profiler's own trace already holds them and the spans on one
+clock.
 """
 
 from __future__ import annotations
 
-import glob
-import gzip
 import json
-import os
-from typing import Optional
+import threading
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from .. import runtime as rt
 
 _ATTR_SEP = ";"
 
-# tracing-window generation: bumped by Profiler at every record-window
-# start (after HostTracer.clear()).  Ranges opened in an earlier window
-# no longer exist on the tracer, so a close crossing a window boundary
-# must become a no-op instead of popping an unrelated range.
+# tracing-window generation: bumped at every record-window start (after
+# HostTracer.clear()).  Ranges opened in an earlier window no longer
+# exist on the tracer, so a close crossing a window boundary must
+# become a no-op instead of popping an unrelated range.
 _trace_gen = 0
+# True while the tracer records on behalf of a jax.profiler session
+# alone (no Profiler enabled it): the one state ``_live`` has to undo
+_session_owned = False
+_window_mu = threading.Lock()
 
 
 def current_trace_generation() -> int:
     return _trace_gen
 
 
-def bump_trace_generation() -> int:
-    global _trace_gen
-    _trace_gen += 1
-    return _trace_gen
+def start_recording(for_session: bool = False):
+    """Start a record window: clear the buffer, invalidate the ranges
+    any previous window left open (their tracer stack entries did not
+    survive the clear) and enable the tracer.  ``Profiler`` calls it
+    at a window's start; ``_live`` calls it ``for_session`` when a
+    span finds a profiler session live (and undoes it when the session
+    has ended)."""
+    global _trace_gen, _session_owned
+    with _window_mu:
+        if for_session and rt.HostTracer.enabled:
+            return              # another thread's span went live first
+        rt.HostTracer.clear()
+        _trace_gen += 1
+        _session_owned = for_session
+        rt.HostTracer.enable()
+
+
+def _live() -> bool:
+    """Whether spans record right now; follows a ``jax.profiler``
+    session going live and ending."""
+    global _session_owned
+    if rt.HostTracer.enabled:
+        if _session_owned and not _Annotation.is_enabled():
+            with _window_mu:
+                if _session_owned:
+                    _session_owned = False
+                    rt.HostTracer.disable()
+            return rt.HostTracer.enabled
+        return True
+    if _Annotation.is_enabled():
+        start_recording(for_session=True)
+        return True
+    return False
 
 
 def _esc_attr(v) -> str:
@@ -92,35 +135,51 @@ class span:
     nesting distinct spans is (the tracer keeps a per-thread stack).
     """
 
-    __slots__ = ("_name", "_attrs", "_active", "_gen")
+    __slots__ = ("_name", "_attrs", "_ann", "_gen")
 
     def __init__(self, name: str, **attrs):
         self._name = name
         self._attrs = attrs
-        self._active = False
+        self._ann = None
         self._gen = 0
 
     def __enter__(self):
-        if rt.HostTracer.enabled:
-            self._active = True
+        if _live():
             self._gen = _trace_gen
+            self._ann = _Annotation(self._name, **self._attrs)
+            self._ann.__enter__()
             rt.HostTracer.begin(format_span_name(self._name, self._attrs))
         return self
 
     def __exit__(self, *exc):
-        if self._active:
-            self._active = False
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
             # a window boundary between enter and exit invalidated the
             # opened range — closing now would pop someone else's
             if self._gen == _trace_gen:
                 rt.HostTracer.end()
+            ann.__exit__(*exc)
         return False
 
 
 def instant(name: str, **attrs):
     """Zero-duration marker (request queued / finished) with attrs."""
-    if rt.HostTracer.enabled:
-        rt.HostTracer.instant(format_span_name(name, attrs))
+    if _live():
+        with _Annotation(name, **attrs):
+            rt.HostTracer.instant(format_span_name(name, attrs))
+
+
+def recorded() -> list:
+    """The closed spans of the current trace generation as
+    ``(name, t0_ns, t1_ns, thread, attrs)``, times on the tracer's
+    monotonic clock: what ``HostTracer.events()`` holds, decoded."""
+    out = []
+    for kind, t0, t1, tid, _value, raw in rt.HostTracer.events():
+        if kind == 0:
+            name, attrs = parse_span_name(raw)
+            out.append((name, t0, t1, tid, attrs))
+    return out
 
 
 def _host_events_as_chrome(events) -> list:
@@ -142,25 +201,18 @@ def _host_events_as_chrome(events) -> list:
     return out
 
 
-def merge_chrome_traces(out_path: str, host=None,
-                        device_trace_dir: Optional[str] = None,
-                        extra=None) -> dict:
-    """Write one chrome/Perfetto JSON combining host spans and the
-    jax.profiler device capture.
+def merge_chrome_traces(out_path: str, host=None, extra=None) -> dict:
+    """Write one chrome/Perfetto JSON of host lanes.
 
     ``host``: path to an exported host chrome trace, a list of
     HostTracer event tuples, or None (= the live tracer buffer).
-    ``device_trace_dir``: the ``Profiler.device_trace_dir`` /
-    ``jax.profiler.start_trace`` directory; None or a dir without
-    captures yields a host-only trace (still valid JSON).
     ``extra``: already-formed chrome event dicts appended verbatim —
     the hook fleet exports use to add one process lane per replica
     (their own pids + process_name metadata) without re-implementing
-    the writer; callers own pid disjointness from the device range
-    (>= 1000).
+    the writer.
 
-    Returns summary counts: ``{"host_events", "device_events",
-    "device_processes", "extra_events", "path"}``.
+    Returns summary counts: ``{"host_events", "extra_events",
+    "path"}``.
     """
     events = [{"ph": "M", "pid": 0, "name": "process_name",
                "args": {"name": "host (paddle_tpu.runtime.HostTracer)"}}]
@@ -187,32 +239,8 @@ def merge_chrome_traces(out_path: str, host=None,
             events.append(e)
             if e.get("ph") != "M":
                 n_extra += 1
-
-    n_dev = 0
-    pid_map = {}
-    if device_trace_dir:
-        # device pids are renumbered from 1000 upward per (file, pid) so
-        # multiple capture files cannot collide with each other or host
-        for path in sorted(glob.glob(os.path.join(
-                device_trace_dir, "**", "*.trace.json.gz"),
-                recursive=True)):
-            with gzip.open(path, "rt") as f:
-                raw = json.load(f).get("traceEvents", [])
-            for e in raw:
-                pid = e.get("pid")
-                if pid is None:
-                    continue
-                key = (path, pid)
-                if key not in pid_map:
-                    pid_map[key] = 1000 + len(pid_map)
-                e = dict(e)
-                e["pid"] = pid_map[key]
-                events.append(e)
-                if e.get("ph") != "M":
-                    n_dev += 1
     with open(out_path, "w") as f:
         json.dump({"traceEvents": events,
                    "displayTimeUnit": "ms"}, f)
-    return {"host_events": len(host_events), "device_events": n_dev,
-            "device_processes": len(pid_map), "extra_events": n_extra,
+    return {"host_events": len(host_events), "extra_events": n_extra,
             "path": out_path}

@@ -389,11 +389,7 @@ class Profiler:
 
     def _start_record(self):
         from ..observability import spans as _spans
-        rt.HostTracer.clear()
-        # invalidate ranges opened in any previous window: their tracer
-        # stack entries did not survive the clear/disable boundary
-        _spans.bump_trace_generation()
-        rt.HostTracer.enable()
+        _spans.start_recording()
         if not self.timer_only and any(
                 t in (ProfilerTarget.TPU, ProfilerTarget.GPU,
                       ProfilerTarget.CUSTOM_DEVICE) for t in self.targets):
@@ -440,13 +436,13 @@ class Profiler:
         return _obs.get_registry().snapshot()
 
     def export_merged_trace(self, path: str) -> dict:
-        """Stitch the recorded host events and the device capture (when
-        a device target completed a record window) into ONE
-        Perfetto-loadable chrome trace at ``path``."""
+        """Write the recorded host events, span attrs decoded into
+        Perfetto ``args``, as one chrome trace at ``path``.  With a
+        device target the spans are also in the profiler's own trace
+        under ``device_trace_dir``, on one clock with the device's
+        ``XLA Ops``: open that one to lay them over the device."""
         from ..observability.spans import merge_chrome_traces
-        return merge_chrome_traces(
-            path, host=self.events(),
-            device_trace_dir=self._device_trace_dir)
+        return merge_chrome_traces(path, host=self.events())
 
     def export_chrome_trace(self, path: str):
         rt.HostTracer.export_chrome_trace(path)

@@ -8,7 +8,6 @@ serving acceptance criteria (Prometheus export, merged trace, stats
 equality, decode-block timing) — tiny llama shapes, no Pallas compile;
 registry-only tests are pure Python."""
 
-import gzip
 import importlib.util
 import json
 import os
@@ -541,35 +540,44 @@ def test_serving_lifecycle_spans_recorded(served):
 
 
 def test_merged_chrome_trace(served, tmp_path):
-    # synthetic jax.profiler-style device capture (the *.trace.json.gz
-    # layout DeviceSummaryView._load reads)
-    dev = tmp_path / "plugins" / "profile" / "run1"
-    dev.mkdir(parents=True)
-    with gzip.open(dev / "m.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": [
-            {"ph": "M", "pid": 2, "name": "process_name",
-             "args": {"name": "/device:TPU:0"}},
-            {"ph": "X", "pid": 2, "tid": 1, "name": "fusion.1",
-             "ts": 10, "dur": 50.0},
-        ]}, f)
+    # the device's events are not pasted beside the host lanes on a
+    # clock of their own any more: under a profiler session a span sits
+    # in the profiler's OWN trace, on the host's line beside where the
+    # device's lines are, attrs as stats -- and in the one buffer
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    from paddle_tpu.observability import recorded
+    jax.profiler.start_trace(str(tmp_path / "xprof"))
+    try:
+        with span("serving.decode_block", steps=3, active=2):
+            pass
+        live = [(n, a) for n, _t0, _t1, _tid, a in recorded()
+                if n.startswith("serving.")]
+    finally:
+        jax.profiler.stop_trace()
+    xplane = glob.glob(str(tmp_path / "xprof" / "plugins" / "profile"
+                           / "*" / "*.xplane.pb"))[-1]
+    in_trace = [dict(e.stats)
+                for plane in ProfileData.from_file(xplane).planes
+                if plane.name == "/host:CPU"
+                for line in plane.lines for e in line.events
+                if e.name == "serving.decode_block"]
+    assert in_trace == [{"steps": 3, "active": 2}]
+    assert live == [("serving.decode_block",
+                     {"steps": "3", "active": "2"})]
     out = str(tmp_path / "merged.json")
-    info = merge_chrome_traces(out, host=served.host_events,
-                               device_trace_dir=str(tmp_path))
-    assert info["device_events"] == 1 and info["device_processes"] == 1
+    info = merge_chrome_traces(out, host=served.host_events)
+    assert set(info) == {"host_events", "extra_events", "path"}
     with open(out) as f:
         trace = json.load(f)
     evs = trace["traceEvents"]
-    host_names = {e["name"] for e in evs if e.get("pid") == 0}
+    assert {e["pid"] for e in evs} == {0}              # host lanes only
+    host_names = {e["name"] for e in evs}
     assert "serving.decode_block" in host_names        # attrs decoded
     blocks = [e for e in evs if e["name"] == "serving.decode_block"]
     assert all("steps" in e["args"] for e in blocks)
-    dev_evs = [e for e in evs if e.get("pid", 0) >= 1000
-               and e.get("ph") == "X"]
-    assert len(dev_evs) == 1 and dev_evs[0]["name"] == "fusion.1"
-    # host-only merge is still valid
-    info2 = merge_chrome_traces(str(tmp_path / "host_only.json"),
-                                host=served.host_events)
-    assert info2["device_events"] == 0
+    assert info["host_events"] == len(evs) - 1         # + process_name
     # file-path host input decodes span attrs too (same contract as
     # the event-tuple and live-tracer forms)
     hostf = tmp_path / "host.json"
