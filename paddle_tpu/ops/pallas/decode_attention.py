@@ -34,6 +34,20 @@ CONTIGUOUS in lanes (head h at lane offset h*D).  Then:
 - traffic is O(valid prefix): the chunk loop stops at ``lens[b]``
   (the reference mmha ``sequence_lengths`` contract), with one program
   per batch row (grid overhead O(B), not O(B*H*chunks)).
+
+Two bodies live here.  The STAGED one lands a row's whole valid prefix
+in VMEM and then computes on all of it: the dense ``_kernel``
+(``LLMPredictor``'s contiguous cache) and the two int8 paged kernels
+(``_paged_kernel_q``, ``_paged_multi_kernel_q``); its landing buffers
+grow with the cache (``_VMEM_BUDGET``, reason ``vmem_budget``) and the
+paged pair holds a DMA semaphore a table block (``_SFLAG_BYTES``,
+reason ``paged_dma_sems``).  The STREAMING one
+(``_paged_stream_kernel``: the float paged cache, decode and the
+K-wide verify alike) walks the prefix in double-buffered groups of
+blocks with an online softmax and prefetches across slots; what it
+stages does not grow with the table, and neither limit binds it
+(PR 29: 80% of the HBM roofline at the serving cell's geometry where
+the staged body read 44%, kernel alone on the v5e).
 """
 
 from __future__ import annotations
@@ -129,41 +143,88 @@ DEFAULT_CHUNK = 256            # cache slots per DMA chunk
 _NEG_INF = -1e30
 _GPAD = 8                      # q rows per head block (sublane unit)
 # What the gate admits is what the compiler is told: ``_VMEM_BUDGET``
-# bounds the buffers the kernels STAGE (K/V landing buffers, scale
-# planes, the logits scratch), and every decode ``pallas_call`` sets
-# Mosaic's scoped-VMEM limit to ``_VMEM_LIMIT`` so that the softmax's
-# temporaries — a handful of logits-sized values the estimate does not
-# itemise — have room on top of a full budget whatever the compiler's
-# default is.  On the v5e the bf16 kernel compiles and agrees at the
-# budget's edge (5376 staged rows of 512 lanes) with this limit; 5120
-# rows also fit the default limit (chip run, PR 22).
+# bounds the buffers a kernel STAGES (K/V landing buffers or stages,
+# scale planes, the logits scratch), and every decode ``pallas_call``
+# sets Mosaic's scoped-VMEM limit to ``_VMEM_LIMIT`` so that the
+# softmax's temporaries — a handful of logits-sized values the estimate
+# does not itemise — have room on top of a full budget whatever the
+# compiler's default is.  The budget BINDS the kernels that stage a
+# whole context: the dense ``_kernel`` and the int8 ``_q`` kernels (on
+# the v5e the bf16 ``_kernel`` compiles and agrees at the budget's
+# edge, 5376 staged rows of 512 lanes, with this limit; 5120 rows also
+# fit the default limit: chip run, PR 22).  The streaming float kernel
+# (``_paged_stream_kernel``) stages two stages of ``_STAGE_BYTES`` an
+# operand whatever the table's width, so it passes the same estimate
+# at any context.
 _VMEM_BUDGET = 12 << 20
 _VMEM_LIMIT = 32 << 20
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+# the streaming kernel carries its stage parity and its prefetch from
+# one program to the next: the grid must run in order
+_STREAM_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=_VMEM_LIMIT, dimension_semantics=("arbitrary",))
+
+# One stage of the streaming kernel: the bytes of K (and as many of V)
+# that are computed on while the next stage's DMAs land.  A fixed byte
+# size; the rows of a stage follow from what the code sees
+# (``_stage_blocks``): 8 blocks of 16 x 4096 B at 16 bf16 KV heads of
+# 128, 128 rows.
+_STAGE_BYTES = 512 << 10
 
 
-# The paged kernels hold one DMA semaphore per table block per staged
-# operand, and semaphores live in the core's 2 KiB "sflag" memory, 4
-# bytes each, next to 292 bytes the program keeps for itself ("Ran out
-# of memory in memory space sflag. Used 2.1K of 2.0K sflag", v5e /
-# jax 0.9.0: two operands compile at 208 blocks and fail at 224).
+def _stage_blocks(arena, tables):
+    """Blocks in one stage of ``_paged_stream_kernel``: as many whole
+    blocks as ``_STAGE_BYTES`` holds at this arena's width, item size
+    and block length, at least one, and no more than the table has."""
+    blk_bytes = (arena.shape[1] * arena.shape[2]
+                 * jnp.dtype(arena.dtype).itemsize)
+    return max(1, min(_STAGE_BYTES // blk_bytes, tables.shape[1]))
+
+
+# The STAGED paged kernels (the int8 ``_q`` pair) hold one DMA
+# semaphore per table block per staged operand, and semaphores live in
+# the core's 2 KiB "sflag" memory, 4 bytes each, next to 292 bytes the
+# program keeps for itself ("Ran out of memory in memory space sflag.
+# Used 2.1K of 2.0K sflag", v5e / jax 0.9.0: two operands compile at
+# 208 blocks and fail at 224).  The streaming float kernel holds one
+# per stage and operand, four in all, and is not bound by this.
 _SFLAG_BYTES = 2048
 _SFLAG_RESERVED = 292
 
 
 def _paged_table_rule(arena, tables, kv_scales):
     """(ok, reason) for what a paged kernel needs of the block table:
-    ``paged_block_len`` — the staged chunk unit is a whole block, so
+    ``paged_block_len`` — the staged unit is a whole block, so
     ``block_len`` must sit on the 8-row sublane tile (bf16 and f32
     arenas compile at 8, 16 and 32 on the v5e); ``paged_dma_sems`` —
-    the table is no wider than the semaphore memory allows, 219 blocks
-    for a float cache (3504 tokens at the default block length 16)."""
+    for the staged int8 kernels (``kv_scales`` given: four operands, a
+    semaphore a block each) the table is no wider than the semaphore
+    memory allows, 109 blocks."""
     if arena.shape[1] % 8:
         return False, "paged_block_len"
-    n_ops = 2 if kv_scales is None else 4
-    if n_ops * tables.shape[1] * 4 + _SFLAG_RESERVED > _SFLAG_BYTES:
+    if (kv_scales is not None
+            and 4 * tables.shape[1] * 4 + _SFLAG_RESERVED > _SFLAG_BYTES):
         return False, "paged_dma_sems"
     return True, None
+
+
+def _stream_rows(hkv, cq, g):
+    """Rows of the streaming kernel's q block and accumulator: every
+    query row of a slot (``cq`` positions of ``g`` query heads on each
+    of ``hkv`` KV heads), rounded up to the sublane unit."""
+    return -(-(hkv * cq * g) // _GPAD) * _GPAD
+
+
+def _paged_staging(hkv, cq, g, arena, tables, kv_scales):
+    """(s, acc_rows) of ``_gate_shared``'s estimate for a paged kernel:
+    the rows of K (and of V) it holds in VMEM and the rows of its
+    full-width accumulator — both stages and ``_stream_rows`` for the
+    streaming float kernel, the whole table's width and no accumulator
+    for the staged int8 kernels."""
+    if kv_scales is not None:
+        return tables.shape[1] * arena.shape[1], 0
+    return (2 * _stage_blocks(arena, tables) * arena.shape[1],
+            _stream_rows(hkv, cq, g))
 
 
 def packed_ok(num_kv_heads: int, head_dim: int) -> bool:
@@ -259,15 +320,20 @@ _MIXED_DTYPE_ALLOWLIST = frozenset({
 
 
 def _gate_shared(q4, cache, s, align_ok, align_reason, q_rows=_GPAD,
-                 has_scales=False):
+                 has_scales=False, acc_rows=0):
     """The gate checks common to the dense and paged dispatchers —
     ONE implementation so the two routes cannot silently diverge.
-    ``s`` is the staged dense-row count; ``align_ok``/``align_reason``
+    ``s`` is the count of rows staged in VMEM (the whole cache for the
+    dense and the int8 paged kernels, ``_paged_staging`` for the
+    streaming one); ``align_ok``/``align_reason``
     inject the path-specific sublane-tiling rule at its position in
     the check order; ``q_rows`` is the per-head q-row block the caller
     stages (``_GPAD`` for the single-token kernels, a multiple of it
     for the K-wide verify kernel) and scales the logits-scratch VMEM
-    estimate; ``has_scales`` says the caller carries the int8 cache's
+    estimate; ``acc_rows`` is the rows of the streaming kernel's
+    full-width q block and float32 accumulator (0 for the staged
+    kernels, which have neither); ``has_scales`` says the caller
+    carries the int8 cache's
     scale arenas — the requirement for the mixed (float q, int8 cache)
     pairs of ``_MIXED_DTYPE_ALLOWLIST`` (every other q/cache dtype mix
     rejects as ``dtype_mismatch``; an int8 pairing that fails the
@@ -321,6 +387,7 @@ def _gate_shared(q4, cache, s, align_ok, align_reason, q_rows=_GPAD,
     vmem = 2 * s * w * itemsize + lg_bytes
     if int8_pair:
         vmem += 2 * s * hkv * 4      # staged f32 scale planes
+    vmem += acc_rows * w * (4 + 2 * jnp.dtype(q4.dtype).itemsize)
     if vmem > _VMEM_BUDGET:
         return False, "vmem_budget"
     return True, None
@@ -386,10 +453,11 @@ def _route_decision_paged(q4, arena, tables, kv_scales=None):
     the quantized cache's scale arenas (``kv_scales``), the explicitly
     allowlisted (float q, int8 cache + scales) pairing that runs the
     dequant-in-kernel variant."""
-    s = tables.shape[1] * arena.shape[1]      # staged dense rows
+    s, acc_rows = _paged_staging(q4.shape[1], 1, q4.shape[2], arena,
+                                 tables, kv_scales)
     use, reason = _gate_shared(
         q4, arena, s, *_paged_table_rule(arena, tables, kv_scales),
-        has_scales=kv_scales is not None)
+        has_scales=kv_scales is not None, acc_rows=acc_rows)
     if reason is not None:
         return use, reason
     return use, ("paged_int8_ok" if kv_scales is not None
@@ -423,10 +491,10 @@ def _route_decision_paged_multi(q5, arena, tables, kv_scales=None):
     qr = -(-(g * cq) // _GPAD) * _GPAD
     if qr > _QROWS_MAX:
         return False, "query_rows"
-    s = tables.shape[1] * arena.shape[1]      # staged dense rows
+    s, acc_rows = _paged_staging(hkv, cq, g, arena, tables, kv_scales)
     use, reason = _gate_shared(
         q5[:, 0], arena, s, *_paged_table_rule(arena, tables, kv_scales),
-        q_rows=qr, has_scales=kv_scales is not None)
+        q_rows=qr, has_scales=kv_scales is not None, acc_rows=acc_rows)
     if reason is not None:
         return use, reason
     return use, ("paged_multi_int8_ok" if kv_scales is not None
@@ -537,95 +605,137 @@ def _kernel(lens_ref, qcat_ref, k_hbm, v_hbm, o_ref,
                            ).astype(out_dtype)
 
 
-def _paged_kernel(lens_ref, tbl_ref, qcat_ref, k_hbm, v_hbm, o_ref,
-                  kbuf, vbuf, lg_ref, ksem, vsem,
-                  *, block_len, n_blocks_max, scale, out_dtype, hkv, g, d,
-                  gw, hp, ng):
-    """Block-table variant of ``_kernel``: the c-th staged chunk DMAs
-    arena block ``tbl_ref[bi, c]`` (a [block_len, W] row of the shared
-    pool) instead of a slice of a per-sequence contiguous cache row —
-    the indirection is resolved at DMA-issue time from the scalar-
-    prefetched table, so traffic is still O(valid prefix) and the
-    compute phases see the same contiguous [rows, W] staging buffer.
+def _paged_stream_kernel(lens_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         kbuf, vbuf, m_ref, l_ref, acc_ref, stage_ref,
+                         ksem, vsem,
+                         *, block_len, bpg, n_blocks_max, cq, g, hkv, d,
+                         scale, out_dtype):
+    """Streaming paged decode attention over a FLOAT arena: one program
+    per slot, the slot's context walked in GROUPS of ``bpg`` blocks
+    (``rows = bpg * block_len`` staged rows, sized by ``_stage_blocks``)
+    through two stages of K and two of V used in turn, with an online
+    softmax across groups — running max ``m_ref``, running sum ``l_ref``
+    and the rescaled float32 accumulator ``acc_ref``, one division at
+    the end.  QK^T, the mask, ``exp`` and PV run on one group while the
+    next group's block DMAs (``tbl_ref[b, c]`` resolved at issue time
+    from the scalar-prefetched table) are in flight, and only over the
+    ``ceil(valid rows / rows)`` groups of the valid prefix: neither the
+    staged bytes nor the compute grow with the table's width.
 
-    Scratch-reuse invariant (same as ``_kernel``, stated in full
-    because it is load-bearing here too): VMEM scratch is SHARED across
-    the grid and the table-indirected DMAs refresh only blocks of the
-    valid prefix — ``vbuf`` is zeroed at program 0 ONLY, ``kbuf`` is
-    NEVER zeroed, so past this row's prefix both buffers hold the
-    previous program's blocks (or, at program 0, zeros/undefined).
-    Correctness rests on (a) the masked-logit flush: every logit at
-    row > length is set to -1e30 before exp, so stale K contributes
-    weight exp(-inf) = 0; (b) vbuf's one-time memset: a zero weight
-    never meets an undefined NaN bit pattern in V (0 * NaN = NaN;
-    stale-but-real V from earlier programs is finite and safe under
-    (a)).  Both depend on the grid executing SEQUENTIALLY (the
-    Pallas-TPU 'arbitrary' grid order) — declaring the batch dimension
-    'parallel' would race programs on the shared scratch and break the
-    invariant."""
+    The prefetch crosses the program boundary: the grid is sequential,
+    the table and ``lens`` are whole in SMEM, so while program ``bi``
+    computes its last group it issues group 0 of program ``bi + 1`` into
+    the other stage.  ``stage_ref[0]`` carries which stage holds the
+    next program's first group; program 0 primes the pipeline, the last
+    program prefetches nothing, and every copy started is waited for
+    inside the program that computes on it.  One DMA semaphore per
+    stage and operand: a group's copies all signal it, and the wait
+    walks the same blocks.
+
+    Layout: every query row of the slot sits on the sublane axis of ONE
+    block-diagonal q, ``q_ref[0]`` [P, W] (``_build_qall``): row
+    ``h * cq * g + c * g + gi`` holds position c of grouped query head
+    gi of KV head h in lanes [h*D, (h+1)*D) and zeros elsewhere, so one
+    full-width dot per group gives every head's logits [P, rows] with
+    no padding rows between heads, the softmax statistics are [P, 1],
+    and one dot gives PV [P, W], of which row r's own head's D lanes
+    are the answer (the rest, other heads' V under this head's weights,
+    is dropped at the end).  ``cq`` is 1 for decode and K+1 for the
+    speculative verifier; query c sees cache rows ``<= lens[b] + c``,
+    the causal frontier the sequential decode loop would have given it.
+
+    Stale-buffer invariant: a stage is refreshed only as far as the
+    group's valid blocks reach, so rows past the frontier of the last
+    group hold an EARLIER group's or an earlier SLOT's data (or, for K
+    before anything was staged, undefined bits).  Correctness rests on
+    (a) the masked-logit flush: every logit past its query's frontier
+    is set to -1e30 before ``exp``, so stale K weighs exp(-inf) = 0 (the
+    first group always holds row 0, so the running max is real from
+    then on); (b) both V stages are zeroed once at program 0, so a zero
+    weight never meets an undefined NaN bit pattern (0 * NaN = NaN;
+    stale-but-real V is finite and safe under (a)).  Both, and the
+    cross-program prefetch, depend on the grid executing SEQUENTIALLY
+    (``dimension_semantics=("arbitrary",)``): a 'parallel' batch
+    dimension would race programs on the shared stages."""
     bi = pl.program_id(0)
-    length = lens_ref[bi]                     # last valid slot index
-    n_blk = length // block_len + 1
-    rows = n_blocks_max * block_len
+    last_prog = pl.num_programs(0) - 1
+    rows = bpg * block_len
+    n_rows = q_ref.shape[1]                   # P
+    length = lens_ref[bi]                     # first query's global slot
+
+    def group_blocks(b, gi):
+        # how many of group gi's blocks hold rows slot b's queries see
+        n_blk = jnp.minimum((lens_ref[b] + cq - 1) // block_len + 1,
+                            n_blocks_max)
+        return jnp.clip(n_blk - gi * bpg, 0, bpg)
+
+    def for_group(b, gi, stage, count, act):
+        for c in range(bpg):                  # short unroll, guarded
+            @pl.when(c < count)
+            def _(c=c):
+                blk = tbl_ref[b, gi * bpg + c]
+                dst = pl.ds(c * block_len, block_len)
+                act(pltpu.make_async_copy(
+                    k_hbm.at[blk], kbuf.at[stage, dst, :], ksem.at[stage]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[blk], vbuf.at[stage, dst, :], vsem.at[stage]))
 
     @pl.when(bi == 0)
     def _():
         vbuf[...] = jnp.zeros_like(vbuf)
+        stage_ref[0] = 0
+        for_group(0, 0, 0, group_blocks(0, 0), lambda cp: cp.start())
 
-    for c in range(n_blocks_max):             # static unroll, guarded
-        @pl.when(c < n_blk)
-        def _(c=c):
-            pltpu.make_async_copy(
-                k_hbm.at[tbl_ref[bi, c]],
-                kbuf.at[pl.ds(c * block_len, block_len), :],
-                ksem.at[c]).start()
-            pltpu.make_async_copy(
-                v_hbm.at[tbl_ref[bi, c]],
-                vbuf.at[pl.ds(c * block_len, block_len), :],
-                vsem.at[c]).start()
+    first = stage_ref[0]
+    n_groups = jnp.minimum((length + cq - 1) // rows + 1,
+                           -(-n_blocks_max // bpg))
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    for c in range(n_blocks_max):
-        @pl.when(c < n_blk)
-        def _(c=c):
-            pltpu.make_async_copy(
-                k_hbm.at[tbl_ref[bi, c]],
-                kbuf.at[pl.ds(c * block_len, block_len), :],
-                ksem.at[c]).wait()
+    row = jax.lax.broadcasted_iota(jnp.int32, (n_rows, rows), 1)
+    frontier = length
+    if cq > 1:      # row r of a head's cq*g is position r // g
+        sub = jax.lax.broadcasted_iota(jnp.int32, (n_rows, rows), 0)
+        frontier = length + jax.lax.rem(sub, cq * g) // g
 
-    for p in range(ng):
-        lg_ref[p] = jax.lax.dot_general(
-            qcat_ref[0, p], kbuf[:, p * gw:(p + 1) * gw],
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [hp*8, rows]
+    def group(gi, carry):
+        stage = jax.lax.rem(first + gi, 2)
+        # the next group in flight before this one is waited for: this
+        # slot's, or past its last group the next slot's first
+        tail = gi == n_groups - 1
+        nxt_b = jnp.where(tail, jnp.minimum(bi + 1, last_prog), bi)
+        nxt_g = jnp.where(tail, 0, gi + 1)
+        nxt_n = jnp.where(tail & (bi == last_prog), 0,
+                          group_blocks(nxt_b, nxt_g))
+        for_group(nxt_b, nxt_g, 1 - stage, nxt_n, lambda cp: cp.start())
+        for_group(bi, gi, stage, group_blocks(bi, gi),
+                  lambda cp: cp.wait())
 
-    sub = jax.lax.broadcasted_iota(jnp.int32, (ng, hp * _GPAD, rows), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (ng, hp * _GPAD, rows), 2)
-    keep = (row <= length) & (jax.lax.rem(sub, _GPAD) < g)
-    lg = jnp.where(keep, lg_ref[...], _NEG_INF)
-    m = jnp.max(lg, axis=-1, keepdims=True)
-    p_ = jnp.exp(lg - m)
-    l = jnp.sum(p_, axis=-1, keepdims=True)    # [ng, hp*8, 1]
-    lg_ref[...] = p_
+        lg = jax.lax.dot_general(
+            q_ref[0], kbuf[stage], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # [P, rows]
+        lg = jnp.where(row + gi * rows <= frontier, lg, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(lg, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p_ = jnp.exp(lg - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p_, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p_.astype(vbuf.dtype), vbuf[stage], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [P, W]
+        m_ref[...] = m_new
+        return carry
 
-    for c in range(n_blocks_max):
-        @pl.when(c < n_blk)
-        def _(c=c):
-            pltpu.make_async_copy(
-                v_hbm.at[tbl_ref[bi, c]],
-                vbuf.at[pl.ds(c * block_len, block_len), :],
-                vsem.at[c]).wait()
+    jax.lax.fori_loop(0, n_groups, group, 0)
+    stage_ref[0] = jax.lax.rem(first + n_groups, 2)
 
-    for p in range(ng):
-        pv_w = jax.lax.dot_general(
-            lg_ref[p].astype(vbuf.dtype), vbuf[:, p * gw:(p + 1) * gw],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [hp*8, gw]
-        for j in range(hp):
-            h = p * hp + j
-            o_ref[0, h] = (pv_w[j * _GPAD:j * _GPAD + g,
-                                j * d:(j + 1) * d]
-                           / l[p, j * _GPAD:j * _GPAD + g]
-                           ).astype(out_dtype)
+    # row r keeps its own head's D lanes of the accumulator
+    head = jax.lax.broadcasted_iota(jnp.int32, (n_rows, d), 0) // (cq * g)
+    out = jnp.zeros((n_rows, d), jnp.float32)
+    for h in range(hkv):
+        out = jnp.where(head == h, acc_ref[:, h * d:(h + 1) * d], out)
+    o_ref[0] = (out / l_ref[...]).astype(out_dtype)
 
 
 def _paged_kernel_q(lens_ref, tbl_ref, qcat_ref, k_hbm, v_hbm,
@@ -634,7 +744,12 @@ def _paged_kernel_q(lens_ref, tbl_ref, qcat_ref, k_hbm, v_hbm,
                     ksem, vsem, kssem, vssem,
                     *, block_len, n_blocks_max, scale, out_dtype, hkv,
                     g, d, gw, hp, ng):
-    """INT8 variant of ``_paged_kernel`` — the whole point of the
+    """The paged kernel over the INT8 cache, one query position, on the
+    STAGED body (the block-table form of ``_kernel``: the slot's whole
+    valid prefix lands in VMEM, then one softmax over all of it; the
+    float cache reads through ``_paged_stream_kernel`` instead, and
+    this body cannot compile on the v5e, whose gate answers
+    ``int8_scale_lanes``) — the whole point of the
     quantized cache: each staged block DMAs int8 K/V codes PLUS the
     [L, H_kv] f32 scale plane, so HBM traffic per cache row drops from
     2 bytes/lane (bf16) to 1 byte/lane + 4/D scale bytes, while the
@@ -737,113 +852,21 @@ def _paged_kernel_q(lens_ref, tbl_ref, qcat_ref, k_hbm, v_hbm,
                            ).astype(out_dtype)
 
 
-def _paged_multi_kernel(lens_ref, tbl_ref, qcat_ref, k_hbm, v_hbm, o_ref,
-                        kbuf, vbuf, lg_ref, ksem, vsem,
-                        *, block_len, n_blocks_max, cq, qr, scale,
-                        out_dtype, g, d, gw, hp, ng):
-    """K-wide query variant of ``_paged_kernel`` — the speculative-
-    decoding verifier's attention.  Each program scores ``cq`` query
-    positions of one batch row (the just-written token plus the K
-    draft candidates) against the SAME staged paged prefix: per head,
-    the q block holds ``qr = roundup(g * cq, 8)`` rows ordered
-    ``c * g + gi`` (query position c, grouped query head gi), and the
-    softmax mask is CAUSAL per row — query c sees cache rows
-    ``<= lens[b] + c``, so each draft position attends exactly the
-    prefix the sequential decode loop would have given it (the greedy-
-    equivalence contract of the verifier).  DMA traffic is still one
-    sweep of the valid prefix (now ``lens + cq - 1`` rows) — the whole
-    point: K+1 positions scored for one cache sweep plus one weight
-    sweep.
-
-    Scratch-reuse invariant (same as ``_kernel``, stated in full): the
-    VMEM scratch is SHARED across the sequentially-executed grid —
-    ``vbuf`` is zeroed at program 0 ONLY, ``kbuf`` is NEVER zeroed.
-    The masked-logit flush (every logit past a query row's causal
-    frontier set to -1e30 before exp) hides stale K, and the one-time
-    vbuf memset guarantees a zero weight never multiplies an undefined
-    NaN bit pattern in V; both properties require the Pallas-TPU
-    'arbitrary' (sequential) grid order — a 'parallel' batch dimension
-    would race programs on the shared scratch."""
-    bi = pl.program_id(0)
-    length = lens_ref[bi]              # first query's global slot
-    n_blk = jnp.minimum((length + cq - 1) // block_len + 1, n_blocks_max)
-    rows = n_blocks_max * block_len
-
-    @pl.when(bi == 0)
-    def _():
-        vbuf[...] = jnp.zeros_like(vbuf)
-
-    for c in range(n_blocks_max):             # static unroll, guarded
-        @pl.when(c < n_blk)
-        def _(c=c):
-            pltpu.make_async_copy(
-                k_hbm.at[tbl_ref[bi, c]],
-                kbuf.at[pl.ds(c * block_len, block_len), :],
-                ksem.at[c]).start()
-            pltpu.make_async_copy(
-                v_hbm.at[tbl_ref[bi, c]],
-                vbuf.at[pl.ds(c * block_len, block_len), :],
-                vsem.at[c]).start()
-
-    for c in range(n_blocks_max):
-        @pl.when(c < n_blk)
-        def _(c=c):
-            pltpu.make_async_copy(
-                k_hbm.at[tbl_ref[bi, c]],
-                kbuf.at[pl.ds(c * block_len, block_len), :],
-                ksem.at[c]).wait()
-
-    for p in range(ng):
-        lg_ref[p] = jax.lax.dot_general(
-            qcat_ref[0, p], kbuf[:, p * gw:(p + 1) * gw],
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [hp*qr, rows]
-
-    # per-row causal mask: q row r = c*g + gi within its head's qr
-    # block is a real query iff r < g*cq, and sees rows <= length + c
-    sub = jax.lax.broadcasted_iota(jnp.int32, (ng, hp * qr, rows), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (ng, hp * qr, rows), 2)
-    qsub = jax.lax.rem(sub, qr)
-    keep = (row <= length + qsub // g) & (qsub < g * cq)
-    lg = jnp.where(keep, lg_ref[...], _NEG_INF)
-    m = jnp.max(lg, axis=-1, keepdims=True)
-    p_ = jnp.exp(lg - m)
-    l = jnp.sum(p_, axis=-1, keepdims=True)    # [ng, hp*qr, 1]
-    lg_ref[...] = p_
-
-    for c in range(n_blocks_max):
-        @pl.when(c < n_blk)
-        def _(c=c):
-            pltpu.make_async_copy(
-                v_hbm.at[tbl_ref[bi, c]],
-                vbuf.at[pl.ds(c * block_len, block_len), :],
-                vsem.at[c]).wait()
-
-    for p in range(ng):
-        pv_w = jax.lax.dot_general(
-            lg_ref[p].astype(vbuf.dtype), vbuf[:, p * gw:(p + 1) * gw],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [hp*qr, gw]
-        for j in range(hp):
-            h = p * hp + j
-            o_ref[0, h] = (pv_w[j * qr:j * qr + cq * g,
-                                j * d:(j + 1) * d]
-                           / l[p, j * qr:j * qr + cq * g]
-                           ).astype(out_dtype)
-
-
 def _paged_multi_kernel_q(lens_ref, tbl_ref, qcat_ref, k_hbm, v_hbm,
                           ks_hbm, vs_hbm, o_ref,
                           kbuf, vbuf, ksbuf, vsbuf, lg_ref,
                           ksem, vsem, kssem, vssem,
                           *, block_len, n_blocks_max, cq, qr, scale,
                           out_dtype, g, d, gw, hp, ng):
-    """INT8 variant of ``_paged_multi_kernel`` (the speculative
-    verifier's attention over the quantized cache): int8 K/V codes +
+    """K-wide query variant of ``_paged_kernel_q`` (the speculative
+    verifier's attention over the quantized cache, on the same staged
+    body): each program scores ``cq`` query positions of one batch row
+    against the same staged prefix; per head the q block holds
+    ``qr = roundup(g * cq, 8)`` rows ordered ``c * g + gi``, and query
+    c sees cache rows ``<= lens[b] + c``.  int8 K/V codes +
     [L, H_kv] f32 scale planes are DMA'd per staged block and
     dequantized in VMEM right before each dot, exactly as in
-    ``_paged_kernel_q``.  The per-row causal frontier masking of the
-    bf16 kernel is unchanged.  Scratch-reuse invariant as adjusted for
+    ``_paged_kernel_q``.  Scratch-reuse invariant as adjusted for
     int8 in ``_paged_kernel_q``: code buffers need no memset (int8 is
     always finite), ``vsbuf`` takes the program-0 memset (an undefined
     f32 scale is the only NaN entry point into the PV dot), ``ksbuf``
@@ -1029,15 +1052,16 @@ def _guard_replicated_tables(tables):
 
 def _paged_dispatch(kernel, qcat, operands, tables, lens, *, b, hkv, d,
                     q_rows, out_rows, gw, ng, s, n_blocks_max):
-    """Shared grid-spec + dispatch body of the four paged wrappers
-    (single/K-wide x float/int8-quantized) — ONE place for the BlockSpec
-    geometry so a fix never has to land four times.  ``operands`` is
-    the HBM operand tuple after the prefetched scalars and q: (k, v)
-    arenas, plus the two f32 scale planes for the quantized kernels.
-    Each operand gets an ANY BlockSpec, a VMEM landing buffer ((s, W)
-    in the arena dtype for the code arenas, (s, H_kv) f32 for scale
-    planes) and an n_blocks_max-deep DMA semaphore array, in operand
-    order — matching the scratch signature of every paged kernel."""
+    """Grid-spec + dispatch body of the two STAGED paged wrappers
+    (single/K-wide over the int8-quantized cache) — ONE place for the
+    BlockSpec geometry so a fix never has to land twice.  ``operands``
+    is the HBM operand tuple after the prefetched scalars and q: the
+    (k, v) code arenas and the two f32 scale planes.  Each operand gets
+    an ANY BlockSpec, a VMEM landing buffer ((s, W) in the arena dtype
+    for the code arenas, (s, H_kv) f32 for scale planes) and an
+    n_blocks_max-deep DMA semaphore array, in operand order — matching
+    the scratch signature of both ``_q`` kernels.  The streaming float
+    kernel has a scratch layout of its own (``_paged_stream``)."""
     _guard_replicated_tables(tables)
     w = operands[0].shape[2]
     land = [pltpu.VMEM((s, w), operands[0].dtype),
@@ -1066,26 +1090,72 @@ def _paged_dispatch(kernel, qcat, operands, tables, lens, *, b, hkv, d,
       *operands)
 
 
+def _build_qall(q5):
+    """Block-diagonal q over the whole cache width: [B, C, H_kv, G, D]
+    -> [B, P, H_kv*D], row ``h*C*G + c*G + gi`` holding position c of
+    query head gi of KV head h in lanes [h*D, (h+1)*D) and zeros
+    elsewhere; P is H_kv*C*G rounded up to the sublane unit (the
+    padding rows are zero queries: finite, dropped by the caller)."""
+    b, cq, hkv, g, d = q5.shape
+    qh = jnp.transpose(q5, (0, 2, 1, 3, 4)).reshape(b, hkv, cq * g, d)
+    eye = jnp.eye(hkv, dtype=q5.dtype)
+    qall = jnp.einsum("bhrd,hk->bhrkd", qh, eye).reshape(
+        b, hkv * cq * g, hkv * d)
+    pad = _stream_rows(hkv, cq, g) - hkv * cq * g
+    return jnp.pad(qall, ((0, 0), (0, pad), (0, 0)))
+
+
+def _paged_stream(q5, k_arena, v_arena, tables, lens):
+    """Dispatch ``_paged_stream_kernel``.  q5: [B, C, H_kv, G, D]; float
+    arenas packed [NB+1, L, H_kv*D] (last row = trash block); tables:
+    [B, max_blocks] int32 arena row indices; lens: [B] global slot of
+    the FIRST query.  Returns [B, H_kv, C*G, D], rows ``c*G + gi``."""
+    _guard_replicated_tables(tables)
+    b, cq, hkv, g, d = q5.shape
+    blk_len, w = k_arena.shape[1:]
+    bpg = _stage_blocks(k_arena, tables)
+    rows = bpg * blk_len
+    qall = _build_qall(q5)
+    n_rows = qall.shape[1]
+    kernel = functools.partial(
+        _paged_stream_kernel, block_len=blk_len, bpg=bpg,
+        n_blocks_max=tables.shape[1], cq=cq, g=g, hkv=hkv, d=d,
+        scale=1.0 / (d ** 0.5), out_dtype=q5.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, n_rows, w),
+                               lambda bi, lens_p, tbl_p: (bi, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, n_rows, d),
+                               lambda bi, lens_p, tbl_p: (bi, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, rows, w), k_arena.dtype),    # K stages
+            pltpu.VMEM((2, rows, w), v_arena.dtype),    # V stages
+            pltpu.VMEM((n_rows, 1), jnp.float32),       # running max
+            pltpu.VMEM((n_rows, 1), jnp.float32),       # running sum
+            pltpu.VMEM((n_rows, w), jnp.float32),       # accumulator
+            pltpu.SMEM((1,), jnp.int32),                # live stage
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n_rows, d), q5.dtype),
+        compiler_params=_STREAM_COMPILER_PARAMS,
+        interpret=not on_tpu(),
+    )(lens.astype(jnp.int32), tables.astype(jnp.int32), qall, k_arena,
+      v_arena)
+    return out[:, :hkv * cq * g].reshape(b, hkv, cq * g, d)
+
+
 def _decode_attention_pallas_paged(q4, k_arena, v_arena, tables, lens):
     """q4: [B, H_kv, G, D]; arenas packed [NB+1, L, H_kv*D] (last row =
     trash block); tables: [B, max_blocks] int32 arena row indices."""
-    b, hkv, g, d = q4.shape
-    blk_len = k_arena.shape[1]
-    w = k_arena.shape[2]
-    n_blocks_max = tables.shape[1]
-    s = n_blocks_max * blk_len
-    gw = max(_LANES, d)
-    hp = gw // d
-    ng = w // gw
-    kernel = functools.partial(
-        _paged_kernel, block_len=blk_len, n_blocks_max=n_blocks_max,
-        scale=1.0 / (d ** 0.5), out_dtype=q4.dtype, hkv=hkv, g=g, d=d,
-        gw=gw, hp=hp, ng=ng)
-    qcat = _build_qcat(q4, hp, ng, gw)
-    return _paged_dispatch(
-        kernel, qcat, (k_arena, v_arena), tables, lens, b=b, hkv=hkv,
-        d=d, q_rows=hp * _GPAD, out_rows=g, gw=gw, ng=ng, s=s,
-        n_blocks_max=n_blocks_max)
+    return _paged_stream(q4[:, None], k_arena, v_arena, tables, lens)
 
 
 def _decode_attention_pallas_paged_q(q4, k_arena, v_arena, k_scales,
@@ -1132,24 +1202,7 @@ def _decode_attention_pallas_paged_multi(q5, k_arena, v_arena, tables,
     row = trash block); tables: [B, max_blocks] int32; lens: [B] global
     position of the FIRST query.  Returns [B, C, H_kv, G, D]."""
     b, cq, hkv, g, d = q5.shape
-    blk_len = k_arena.shape[1]
-    w = k_arena.shape[2]
-    n_blocks_max = tables.shape[1]
-    s = n_blocks_max * blk_len
-    gw = max(_LANES, d)
-    hp = gw // d
-    ng = w // gw
-    qr = -(-(g * cq) // _GPAD) * _GPAD
-    kernel = functools.partial(
-        _paged_multi_kernel, block_len=blk_len,
-        n_blocks_max=n_blocks_max, cq=cq, qr=qr,
-        scale=1.0 / (d ** 0.5), out_dtype=q5.dtype, g=g, d=d,
-        gw=gw, hp=hp, ng=ng)
-    qcat = _build_qcat_multi(q5, hp, ng, gw, qr)
-    out = _paged_dispatch(
-        kernel, qcat, (k_arena, v_arena), tables, lens, b=b, hkv=hkv,
-        d=d, q_rows=hp * qr, out_rows=cq * g, gw=gw, ng=ng, s=s,
-        n_blocks_max=n_blocks_max)
+    out = _paged_stream(q5, k_arena, v_arena, tables, lens)
     # head-major rows c*g+gi back to [B, C, H_kv, G, D]
     return jnp.transpose(out.reshape(b, hkv, cq, g, d), (0, 2, 1, 3, 4))
 

@@ -933,6 +933,168 @@ def test_decode_attention_paged_kernel_parity():
                                     "pallas_unavailable")
 
 
+def _stream_case(monkeypatch, seed, b, hkv, g, d, blk_len=8, mb=12,
+                 stage_bytes=16 << 10, extra=3):
+    """A float32 arena with scattered tables and a stage small enough
+    that a tiny table holds several groups (the stage's bytes are a
+    constant of the module; its rows follow from the arena)."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "_STAGE_BYTES", stage_bytes)
+    rng = np.random.default_rng(seed)
+    w = hkv * d
+    nb = b * mb + extra
+    ka = jnp.asarray(rng.standard_normal((nb + 1, blk_len, w)),
+                     jnp.float32)
+    va = jnp.asarray(rng.standard_normal((nb + 1, blk_len, w)),
+                     jnp.float32)
+    tables = jnp.asarray(rng.permutation(nb)[:b * mb].reshape(b, mb),
+                         jnp.int32)
+    rows = da._stage_blocks(ka, tables) * blk_len
+    return da, rng, ka, va, tables, rows
+
+
+_STREAM_EDGES = ("one_row", "group_less_one", "group", "group_plus_one",
+                 "several_groups", "full_width")
+_stream_parity_batches = {}
+
+
+def _stream_parity_batch(monkeypatch, hkv, g, d):
+    """The kernel's and the reference's outputs for ONE batch of six
+    slots whose valid lengths sit at every edge of the group walk, in
+    ``_STREAM_EDGES`` order; computed once a geometry."""
+    if (hkv, g, d) not in _stream_parity_batches:
+        b, mb, blk_len = len(_STREAM_EDGES), 12, 8
+        da, rng, ka, va, tables, rows = _stream_case(
+            monkeypatch, 31, b, hkv, g, d, blk_len, mb)
+        assert 1 < mb * blk_len // rows        # several groups a table
+        valid = [1, rows - 1, rows, rows + 1, 2 * rows + 5, mb * blk_len]
+        lens = jnp.asarray(valid, jnp.int32) - 1
+        q4 = jnp.asarray(rng.standard_normal((b, hkv, g, d)), jnp.float32)
+        out = da._decode_attention_pallas_paged(q4, ka, va, tables, lens)
+        ref = _ref_decode_attention(q4, da.paged_gather_view(ka, tables),
+                                    da.paged_gather_view(va, tables), lens)
+        _stream_parity_batches[hkv, g, d] = np.asarray(out), np.asarray(ref)
+    return _stream_parity_batches[hkv, g, d]
+
+
+@pytest.mark.parametrize("hkv,g,d", [(2, 2, 64), (2, 1, 128)])
+@pytest.mark.parametrize("which", _STREAM_EDGES)
+def test_paged_stream_kernel_parity(monkeypatch, hkv, g, d, which):
+    """The streaming paged kernel (interpret mode) against the XLA
+    reference over the gathered view, in ONE batch of six slots whose
+    valid lengths sit at every edge of the group walk — one row, a row
+    short of a group, exactly a group, a group and a row, several
+    groups and a part, the table's full width — so each slot's first
+    group arrives through the cross-slot prefetch of a neighbour of
+    another length.  ``which`` names the slot this case asserts."""
+    out, ref = _stream_parity_batch(monkeypatch, hkv, g, d)
+    i = _STREAM_EDGES.index(which)
+    np.testing.assert_allclose(out[i], ref[i], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("b,lens", [
+    (3, [40, 0, 70]),      # an empty slot between two live ones
+    (1, [50]),             # a batch of one: nothing to prefetch
+    (2, [0, 0]),           # nothing but empty slots
+])
+def test_paged_stream_kernel_empty_slots_and_batch_of_one(monkeypatch, b,
+                                                          lens):
+    """An empty slot (``lens`` 0, every table entry the trash block)
+    costs one group of one block and leaves its neighbours' pipeline
+    intact; a batch of one primes and prefetches nothing further."""
+    mb, blk_len, hkv, g, d = 12, 8, 2, 2, 64
+    da, rng, ka, va, tables, rows = _stream_case(monkeypatch, 32, b, hkv,
+                                                 g, d, blk_len, mb)
+    trash = ka.shape[0] - 1
+    tables = jnp.where(jnp.asarray(lens)[:, None] == 0, trash, tables)
+    lens = jnp.asarray(lens, jnp.int32)
+    q4 = jnp.asarray(rng.standard_normal((b, hkv, g, d)), jnp.float32)
+    out = da._decode_attention_pallas_paged(q4, ka, va, tables, lens)
+    ref = _ref_decode_attention(q4, da.paged_gather_view(ka, tables),
+                                da.paged_gather_view(va, tables), lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("poison", ["past_length", "previous_slot"])
+def test_paged_stream_kernel_ignores_stale_rows(monkeypatch, poison):
+    """The stale-buffer invariant of the two stages.  ``past_length``:
+    huge values in every arena row past a slot's length — the rest of
+    its last block, every block it does not own, the trash block —
+    leave every output as it was.  ``previous_slot``: slot 0 walks
+    three groups and so leaves its own (huge) rows in BOTH stages,
+    the one its last group's prefetch then half-overwrites with slot
+    1's two blocks included; slots 1 and 2 must read as if slot 0 had
+    held ordinary values."""
+    b, mb, blk_len, hkv, g, d = 3, 12, 8, 2, 2, 64
+    da, rng, ka, va, tables, rows = _stream_case(monkeypatch, 33, b, hkv,
+                                                 g, d, blk_len, mb)
+    lens = [mb * blk_len - 1, 10, rows + 3]
+    q4 = jnp.asarray(rng.standard_normal((b, hkv, g, d)), jnp.float32)
+    run = lambda k, v: np.asarray(da._decode_attention_pallas_paged(
+        q4, k, v, tables, jnp.asarray(lens, jnp.int32)))
+    out1 = run(ka, va)
+    big = 1e6
+    ka2, va2 = np.array(ka), np.array(va)
+    tbl = np.asarray(tables)
+    if poison == "past_length":
+        owned = np.zeros(ka2.shape[:2], bool)
+        for r in range(b):
+            for pos in range(lens[r] + 1):
+                owned[tbl[r, pos // blk_len], pos % blk_len] = True
+        ka2[~owned] = big
+        va2[~owned] = -big
+        check = slice(None)
+    else:
+        ka2[tbl[0]] = big
+        va2[tbl[0]] = -big
+        check = slice(1, None)
+    out2 = run(jnp.asarray(ka2), jnp.asarray(va2))
+    assert np.isfinite(out2).all()
+    np.testing.assert_allclose(out1[check], out2[check], atol=1e-5)
+
+
+@pytest.mark.parametrize("lens_of", ["mid_group", "frontier_crosses_group"])
+def test_paged_stream_kernel_k_wide_over_groups(monkeypatch, lens_of):
+    """The K-wide verify queries on the streaming body over several
+    groups: query c's causal frontier ``lens + c`` inside a group, and
+    a frontier that crosses into the next group (the last group then
+    holds rows only the later queries see)."""
+    from paddle_tpu.ops.pallas.decode_attention import _paged_multi_xla
+    b, mb, blk_len, hkv, g, d, cq = 3, 12, 8, 2, 2, 64, 5
+    da, rng, ka, va, tables, rows = _stream_case(monkeypatch, 34, b, hkv,
+                                                 g, d, blk_len, mb)
+    lens = {"mid_group": [5, rows + 9, 2 * rows + 1],
+            "frontier_crosses_group": [rows - 2, 2 * rows - 1,
+                                       mb * blk_len - cq]}[lens_of]
+    lens = jnp.asarray(lens, jnp.int32)
+    q = jnp.asarray(rng.standard_normal((b, cq, hkv * g, d)), jnp.float32)
+    q5 = q.reshape(b, cq, hkv, g, d)
+    out = da._decode_attention_pallas_paged_multi(q5, ka, va, tables, lens)
+    ref = _paged_multi_xla(q, ka, va, tables, lens).reshape(
+        b, cq, hkv, g, d)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("blk_len,w,dtype,width,want", [
+    (16, 2048, jnp.bfloat16, 88, 8),     # the serving cell: 128 rows
+    (16, 2048, jnp.float32, 88, 4),      # item size halves the rows
+    (16, 512, jnp.bfloat16, 64, 32),     # a narrower cache, more rows
+    (32, 2048, jnp.bfloat16, 88, 4),     # longer blocks, as many rows
+    (16, 512, jnp.bfloat16, 5, 5),       # never more than the table
+    (32, 16384, jnp.float32, 88, 1),     # never less than a block
+])
+def test_paged_stream_stage_rows_follow_the_arena(blk_len, w, dtype, width,
+                                                  want):
+    """A stage's rows are derived from what the code sees (width, item
+    size, block length) against the module's fixed stage bytes."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+    arena = jax.ShapeDtypeStruct((9, blk_len, w), dtype)
+    tables = jax.ShapeDtypeStruct((2, width), jnp.int32)
+    assert da._stage_blocks(arena, tables) == want
+
+
 def test_decode_attention_paged_multi_kernel_parity():
     """K-wide paged verify kernel (interpret mode) vs the gather-based
     XLA multi-position path: per-offset causal masking (query c sees
@@ -1284,20 +1446,47 @@ def test_decode_attention_paged_multi_int8_kernel_parity():
                                atol=1e-5, rtol=1e-5)
 
 
-def test_paged_gate_table_width_rule(monkeypatch):
-    """The v5e's semaphore memory bounds the block table (PR 22): two
-    staged operands compile at 208 blocks and fail at 224, so the gate
-    admits 219 and rejects 220 under ``paged_dma_sems`` — for the
-    K-wide verify kernel alike."""
+@pytest.mark.parametrize("route", ["single", "k_wide", "int8",
+                                   "k_wide_int8"])
+def test_paged_gate_table_width_rule(monkeypatch, route):
+    """What bounds the table is the kernel's body.  The streaming float
+    kernel (single query and K-wide) stages two fixed-size stages and
+    holds four semaphores, so it admits a 220-block table and a context
+    past the 1445 rows that the staged body reached at 16 KV heads of
+    128 (PR 27).  The int8 kernels keep the staged body: the v5e's
+    semaphore memory bounds their table (four operands, a semaphore a
+    block: 109 blocks, ``paged_dma_sems``, PR 22) and their landing
+    buffers the context (``vmem_budget``)."""
     from paddle_tpu.ops.pallas import decode_attention as da
     monkeypatch.setattr(da, "pallas_enabled", lambda: True)
-    q4 = jnp.zeros((2, 2, 2, 64), jnp.bfloat16)
-    q5 = jnp.zeros((2, 3, 2, 2, 64), jnp.bfloat16)
-    arena = jnp.zeros((9, 8, 128), jnp.bfloat16)
-    for width, want in ((219, True), (220, False)):
+    if route in ("single", "k_wide"):
+        ok = "paged_ok" if route == "single" else "paged_multi_ok"
+        decide = (da._route_decision_paged if route == "single"
+                  else da._route_decision_paged_multi)
+        lead = (2,) if route == "single" else (2, 3)
+        # (kv heads, head dim, block length, table width)
+        for hkv, d, blk_len, width in ((2, 64, 8, 219), (2, 64, 8, 220),
+                                       (16, 128, 16, 91),    # 1456 rows
+                                       (16, 128, 16, 220)):  # 3520 rows
+            q = jnp.zeros(lead + (hkv, 1, d), jnp.bfloat16)
+            arena = jnp.zeros((9, blk_len, hkv * d), jnp.bfloat16)
+            tables = jnp.zeros((2, width), jnp.int32)
+            assert decide(q, arena, tables) == (True, ok)
+        return
+    hkv, d = 128, 32            # the one head count whose planes DMA
+    planes = lambda blk_len: (jnp.ones((9, blk_len, hkv), jnp.float32),) * 2
+    if route == "int8":
+        ok, decide = "paged_int8_ok", da._route_decision_paged
+        q = jnp.zeros((2, hkv, 1, d), jnp.bfloat16)
+    else:
+        ok, decide = "paged_multi_int8_ok", da._route_decision_paged_multi
+        q = jnp.zeros((2, 3, hkv, 1, d), jnp.bfloat16)
+    arena = jnp.zeros((9, 8, hkv * d), jnp.int8)
+    for width, want in ((109, ok), (110, "paged_dma_sems")):
         tables = jnp.zeros((2, width), jnp.int32)
-        use, reason = da._route_decision_paged(q4, arena, tables)
-        assert use is want
-        assert reason == ("paged_ok" if want else "paged_dma_sems")
-        use, reason = da._route_decision_paged_multi(q5, arena, tables)
-        assert reason == ("paged_multi_ok" if want else "paged_dma_sems")
+        use, reason = decide(q, arena, tables, planes(8))
+        assert (use, reason) == (want == ok, want)
+    # inside the semaphores' reach, past the landing buffers'
+    arena = jnp.zeros((9, 32, hkv * d), jnp.int8)
+    tables = jnp.zeros((2, 100), jnp.int32)
+    assert decide(q, arena, tables, planes(32)) == (False, "vmem_budget")
