@@ -13,9 +13,8 @@ from .serve_common import ServeRun
 
 def run(ctx, control=None):
     run_ = ServeRun(ctx)
-    cfg, mix, sess, eng = run_.cfg, run_.mix, run_.sess, run_.eng
-    vocab = cfg["vocab_size"]
-    todo = iter(traffic.closed_sequence(mix, ctx.seed, vocab))
+    mix, sess, eng = run_.mix, run_.sess, run_.eng
+    todo = iter(traffic.closed_sequence(mix, ctx.seed, run_.vocab))
     tracked = []
 
     def send(client, due):

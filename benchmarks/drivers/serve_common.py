@@ -9,7 +9,6 @@ import time
 import numpy as np
 
 from ..harness import compare, serving
-from ..harness.spec import resolve
 
 
 class ServeRun:
@@ -18,13 +17,13 @@ class ServeRun:
     def __init__(self, ctx):
         self.ctx = ctx
         self.cfg = ctx.cell.config["model"]
+        self.vocab = ctx.cell.family.vocab_size(self.cfg)
         self.geo = ctx.cell.config["serving"]
         self.mix = ctx.cell.traffic
-        self.model = serving.build_model(self.cfg, self.cfg["dtype"], ctx.seed,
-                                         ctx.phases)
+        self.model = serving.build_model(ctx.cell, ctx.seed, ctx.phases)
         self.eng = serving.build_engine(self.model, self.cfg, self.geo)
         ctx.phases.mark("engine")
-        serving.warm_up(self.eng, self.cfg, self.geo)
+        serving.warm_up(self.eng, self.vocab, self.geo)
         ctx.phases.mark("warm_up")
         self.routes = serving.check_routes(
             ctx.cell.config.get("expected_routes", []))
@@ -74,7 +73,7 @@ class ServeRun:
                "block_samples": self.sess.block_samples,
                "compiles_in_window": ctx.compiles_in_window(),
                "latency": serving.latency_metrics(tracked),
-               "serve_flops": serving.serve_flops_of(cfg, work)}
+               "serve_flops": serving.serve_flops_of(ctx.cell, work)}
         if self.trace is not None:
             s0, s1 = self.trace_stats
             tw = serving.span_work(cfg, tracked, "trace0", "trace1")
@@ -89,8 +88,7 @@ class ServeRun:
         requests ``answered``, read the device, free the program's state and
         only then let the reference take the chip."""
         from ..harness.context import device_info
-        vocab = self.cfg["vocab_size"]
-        whys = [serving.failed_reason(tr, vocab) for tr in answered]
+        whys = [serving.failed_reason(tr, self.vocab) for tr in answered]
         for why in [w for w in whys if w][:5]:
             print(f"failed request: {why}", flush=True)
         pairs = [(np.asarray(tr.spec["prompt"]), np.asarray(tr.req.output))
@@ -109,11 +107,10 @@ def check(ctx, finished, control=None):
     cell = ctx.cell
     cfg = cell.config["model"]
     chk = cell.config["check"]["serve"]
-    ref = resolve(cell.config["reference"])
     t = time.perf_counter()
     sample = compare.pick_sample(finished, int(chk["requests"]), ctx.seed)
-    w = compare.reference_weights(cfg, ctx.seed)
-    got = compare.served_gaps(ref, w, cfg, sample,
+    w = compare.reference_weights(cell, ctx.seed)
+    got = compare.served_gaps(cell.reference, w, cfg, sample,
                               bucket=int(chk["bucket"]), control=control)
     got["reference_s"] = time.perf_counter() - t
     print(f"check {got}", flush=True)

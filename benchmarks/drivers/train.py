@@ -14,8 +14,7 @@ import time
 
 import numpy as np
 
-from ..harness import compare, flops, serving, traffic, weights
-from ..harness.spec import resolve
+from ..harness import compare, serving, traffic, weights
 
 
 class _Rows:
@@ -39,13 +38,14 @@ def _leaf_norms(arrays):
     return [float(v) for v in fn(list(arrays))]
 
 
-def _change_norms(cfg, seed, dtype, params):
+def _change_norms(cell, seed, params):
     """Per leaf, the norm of what the parameters moved by since the seed's
     values, which are made again rather than kept."""
     import jax
     import jax.numpy as jnp
+    cfg = cell.config["model"]
     values = [p._value for p in params]
-    start = weights.make(cfg, seed, dtype,
+    start = weights.make(cell.family, cfg, seed, cfg["dtype"],
                          shardings=serving.param_shardings(values))
     fn = jax.jit(lambda a, b: [jnp.sqrt(jnp.sum(jnp.square(
         x.astype(jnp.float32) - y.astype(jnp.float32)))) for x, y in zip(a, b)])
@@ -56,10 +56,10 @@ def build(ctx):
     import paddle_tpu as paddle
     from paddle_tpu.io import DataLoader
     from paddle_tpu.jit.train_step import TrainStep
-    from paddle_tpu.models import LlamaPretrainingCriterion
     cell = ctx.cell
     cfg, mix = cell.config["model"], cell.traffic
-    hyper = cell.config["training"]["optimizer"]
+    training = cell.config["training"]
+    hyper = training["optimizer"]
     layout = mix.get("fleet")
     if layout:
         from paddle_tpu.distributed import fleet
@@ -70,26 +70,19 @@ def build(ctx):
             "pp_degree": 1, "sharding_degree": 1, "sep_degree": 1}
         fleet.init(is_collective=True, strategy=strategy)
     paddle.set_flags({"FLAGS_use_fused_adamw_kernel":
-                      bool(cell.config["training"].get("fused_adamw_kernel"))})
-    config_kw = dict(mix.get("model_options", {}))
-    config_kw["tensor_parallel"] = bool(layout and layout["mp"] > 1)
-    config_kw["fused_linear_loss"] = not config_kw["tensor_parallel"]
-    model = serving.build_model(cfg, cfg["dtype"], ctx.seed, ctx.phases,
-                                train=True, **config_kw)
-    criterion = LlamaPretrainingCriterion(model.config)
-
-    def loss_fn(net, tokens, labels):
-        if model.config.fused_linear_loss:
-            return net(tokens, labels=labels)[0]
-        return criterion(net(tokens), labels)
-
+                      bool(training.get("fused_adamw_kernel"))})
+    model = serving.build_model(
+        cell, ctx.seed, ctx.phases, train=True,
+        tensor_parallel=bool(layout and layout["mp"] > 1),
+        **mix.get("model_options", {}))
     opt = paddle.optimizer.AdamW(
         learning_rate=hyper["lr"], beta1=hyper["beta1"], beta2=hyper["beta2"],
         epsilon=hyper["epsilon"], weight_decay=hyper["weight_decay"],
-        parameters=model.parameters(), multi_precision=False)
-    step = TrainStep(model, loss_fn, opt)
+        parameters=model.parameters(),
+        multi_precision=bool(training["multi_precision"]))
+    step = TrainStep(model, cell.family.train_loss(model), opt)
     rows = traffic.token_rows(mix["dataset_batches"] * mix["batch"], mix["seq"],
-                              cfg["vocab_size"], ctx.seed)
+                              cell.family.vocab_size(cfg), ctx.seed)
     loader = DataLoader(_Rows(rows), batch_size=mix["batch"], shuffle=False,
                         drop_last=True, num_workers=0)
     ctx.phases.mark("step_object")
@@ -99,7 +92,6 @@ def build(ctx):
 def first_steps(ctx, model, step, feed, n_follow):
     """The first steps, through the window's own call and feed; returns the
     program's readings for the comparison."""
-    cfg = ctx.cell.config["model"]
     hyper = ctx.cell.config["training"]["optimizer"]
     losses, grad_norms = [], None
     for i in range(n_follow):
@@ -109,7 +101,7 @@ def first_steps(ctx, model, step, feed, n_follow):
             ctx.phases.mark("first_step")
             m_norms = _leaf_norms([s["m"] for s in step._state])
             grad_norms = [v / (1.0 - hyper["beta1"]) for v in m_norms]
-    change = _change_norms(cfg, ctx.seed, cfg["dtype"], step._params)
+    change = _change_norms(ctx.cell, ctx.seed, step._params)
     ctx.phases.mark("followed_steps")
     return {"losses": losses, "grad_norms": grad_norms, "change_norms": change}
 
@@ -163,8 +155,9 @@ def run(ctx, control=None):
            "train_tok_s": steps * batch_tokens / (t_end - t0),
            "loader_wait_ms_per_step": 1e3 * wait_s / max(steps, 1),
            "compiles_in_window": ctx.compiles_in_window(),
-           "flops_per_token": flops.train_flops_per_token(cfg, mix["seq"]),
-           "attention_flops_per_step": flops.attention_train_flops(
+           "flops_per_token": cell.family.train_flops_per_token(
+               cfg, mix["seq"]),
+           "attention_flops_per_step": cell.family.attention_train_flops(
                cfg, mix["batch"], mix["seq"]),
            "attempted": steps, "failed": 0 if np.isfinite(final_loss) else steps,
            "device": device_info(cell.chips), "trace": trace,
@@ -188,7 +181,7 @@ def check(ctx, program, rows, control=None):
     cfg, mix = cell.config["model"], cell.traffic
     chk = cell.config["check"]["train"]
     hyper = cell.config["training"]["optimizer"]
-    ref = resolve(cell.config["reference"])
+    ref = cell.reference
     n_follow, b = int(chk["steps"]), mix["batch"]
     devs = jax.devices()[:cell.chips]
     t = time.perf_counter()
@@ -211,7 +204,7 @@ def check(ctx, program, rows, control=None):
     controls = control.split(",") if control else []
     for mode in ["f32"] + controls:
         t_mode, c0 = time.perf_counter(), ctx.clock.seconds
-        w = compare.reference_weights(cfg, ctx.seed, shard)
+        w = compare.reference_weights(cell, ctx.seed, shard)
         fed = batches
         if mode == "half":      # the fault: half of the rows, mean over them
             fed, mode = [(t_[:b // 2], l_[:b // 2]) for t_, l_ in batches], "f32"
@@ -219,7 +212,7 @@ def check(ctx, program, rows, control=None):
             w, cfg, fed, hyper, mode=mode, store=cfg["dtype"],
             row_block=int(chk["row_block"]),
             moments_on_host=bool(chk.get("moments_on_host")), put_rows=put_rows)
-        start = compare.reference_weights(cfg, ctx.seed, shard)
+        start = compare.reference_weights(cell, ctx.seed, shard)
         got["change_norms"] = [
             float(ref._norm(a - b_)) for a, b_ in
             zip(ref.flat_leaves(w), ref.flat_leaves(start))]

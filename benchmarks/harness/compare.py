@@ -10,15 +10,17 @@ import numpy as np
 from . import weights
 
 
-def reference_weights(cfg, seed, shard=None):
+def reference_weights(cell, seed, shard=None):
     """The seed's weights as the reference wants them: float32 values of the
     stored type's values, made by the benchmark, nothing taken from the
     program.  ``shard`` places each leaf (a function of its shape)."""
     import jax.numpy as jnp
-    specs = weights.leaf_specs(cfg)
+    family, cfg = cell.family, cell.config["model"]
+    specs = family.leaf_specs(cfg)
     sh = None if shard is None else [shard(s[1]) for s in specs]
-    leaves = weights.make(cfg, seed, jnp.dtype(cfg["dtype"]), shardings=sh)
-    return weights.as_reference(cfg, leaves)
+    leaves = weights.make(family, cfg, seed, jnp.dtype(cfg["dtype"]),
+                          shardings=sh)
+    return family.as_reference(cfg, leaves)
 
 
 def pick_sample(finished, k, seed):

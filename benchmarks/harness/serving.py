@@ -10,38 +10,35 @@ handed it over, which is what a client would see.
 
 from __future__ import annotations
 
+import re
 import time
 
 import numpy as np
 
-from . import flops, weights
+from . import weights
 from .clocks import percentile
 
 
-def build_model(cfg, dtype, seed, phases, train=False, **config_kw):
-    """The program's model with the benchmark's seeded weights put into its
-    parameters, each on the sharding the program gave it."""
+def build_model(cell, seed, phases, **build_kw):
+    """The program's model, built by the cell's family, with the benchmark's
+    seeded weights put into its parameters, each on the sharding the program
+    gave it."""
     import paddle_tpu as paddle
-    from paddle_tpu import models
-    keys = ("vocab_size", "hidden_size", "intermediate_size",
-            "num_hidden_layers", "num_attention_heads", "num_key_value_heads",
-            "max_position_embeddings", "rms_norm_eps", "rope_theta",
-            "tie_word_embeddings")
-    lcfg = models.LlamaConfig(**{k: cfg[k] for k in keys}, **config_kw)
+    family, cfg = cell.family, cell.config["model"]
+    dtype = cfg["dtype"]
     paddle.seed(int(seed) % 2147483629)
-    model = models.LlamaForCausalLM(lcfg)
-    model.train() if train else model.eval()
+    model = family.build(cfg, **build_kw)
     if dtype != "float32":
         model.to(dtype=dtype)
     phases.mark("model_init")
     named = list(model.named_parameters())
-    specs = weights.leaf_specs(cfg)
+    specs = family.leaf_specs(cfg)
     got = [(n, tuple(p.shape)) for n, p in named]
     want = [(n, tuple(s)) for n, s, _ in specs]
     if got != want:
         raise RuntimeError(f"the model's parameters are not the configuration's: "
                            f"{[g for g, w in zip(got, want) if g != w][:3]} ...")
-    values = weights.make(cfg, seed, dtype, shardings=param_shardings(
+    values = weights.make(family, cfg, seed, dtype, shardings=param_shardings(
         [p._value for _, p in named]))
     for (_, p), v in zip(named, values):
         p.set_value(v)
@@ -81,7 +78,7 @@ def build_engine(model, cfg, geometry):
         compute_dtype=cfg["dtype"])
 
 
-def warm_up(eng, cfg, geometry):
+def warm_up(eng, vocab, geometry):
     """Drive the programs this cell's traffic uses, and no others: the chunk
     program (a prompt of two chunks), the whole decode block and the one-step
     program that every request's tail takes.  Each decode program has two
@@ -93,23 +90,30 @@ def warm_up(eng, cfg, geometry):
     for wave in (((chunk + chunk // 2, 1 + 3 * spc + 3), (chunk // 2, 1 + spc + 2),
                   (chunk // 4, 3)), ((chunk // 2, 1 + 2 * spc),)):
         for n_prompt, n_new in wave:
-            eng.submit(rng.integers(0, cfg["vocab_size"], max(n_prompt, 1))
+            eng.submit(rng.integers(0, vocab, max(n_prompt, 1))
                        .astype(np.int32), max_new_tokens=n_new)
         done = eng.run(wall_timeout_s=1500.0)
         if len(done) != len(wave) or any(r.state != "finished" for r in done):
             raise RuntimeError("warm-up requests did not finish")
 
 
+ROUTE_COUNTER = re.compile(r"^pallas\.([\w\-]+)\.route$")
+
+
 def check_routes(expected):
     """The kernels this cell expects to have run, from the program's route
-    counters; a cell whose decode fell back to XLA is another cell."""
+    counters, whichever the registry holds (``pallas.<kernel>.route``; an
+    expected route reads ``<kernel>:<key>``); a cell whose decode fell back
+    to XLA is another cell."""
     from paddle_tpu.observability.metrics import get_registry
-    snap = get_registry().snapshot()
     seen = {}
-    for name in ("pallas.decode_attention.route", "pallas.quantized_matmul.route"):
-        for key, v in snap.get(name, {}).get("values", {}).items():
+    for name, counter in get_registry().snapshot().items():
+        kernel = ROUTE_COUNTER.match(name)
+        if not kernel:
+            continue
+        for key, v in counter.get("values", {}).items():
             if v:
-                seen[f"{name.split('.')[1]}:{key}"] = int(v)
+                seen[f"{kernel.group(1)}:{key}"] = int(v)
     print(f"routes {seen}", flush=True)
     missing = [e for e in expected if not any(e in k for k in seen)]
     if missing:
@@ -252,6 +256,7 @@ def latency_metrics(tracked):
             "n_ttft": len(ttft), "n_tpot": len(tpot)}
 
 
-def serve_flops_of(cfg, work):
-    return flops.serve_flops(cfg, work["prompt_tokens"] + work["decode_tokens"],
-                             work["context"])
+def serve_flops_of(cell, work):
+    return cell.family.serve_flops(
+        cell.config["model"], work["prompt_tokens"] + work["decode_tokens"],
+        work["context"])

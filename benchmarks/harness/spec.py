@@ -27,8 +27,16 @@ def resolve(dotted):
     return getattr(module, attr) if attr else module
 
 
+# the harness's own sections of a configuration's file; every other key is the
+# model's configuration as published, dicts and lists included
+HARNESS_SECTIONS = ("source", "family", "reference", "reduced", "assumed",
+                    "deployment", "serving", "training", "check",
+                    "expected_routes")
+
+
 class Cell:
-    """One entry of ``workloads`` with its configuration, mix and metrics."""
+    """One entry of ``workloads`` with its configuration, its family, its mix
+    and its metrics."""
 
     def __init__(self, benchmark, name, bench_dir=BENCH_DIR, traffic_dir=None):
         cells = {w["name"]: w for w in benchmark["workloads"]}
@@ -42,9 +50,12 @@ class Cell:
         self.config = load_json(os.path.join(
             os.path.dirname(bench_dir), self.config_entry["file"]))
         # the published keys sit at the top level of the file, as in the
-        # source's config.json; "model" is that same dict under a name
+        # source's config.json; "model" is that same dict under a name, and
+        # what the family is handed
         self.config["model"] = {k: v for k, v in self.config.items()
-                                if not isinstance(v, (dict, list))}
+                                if k not in HARNESS_SECTIONS}
+        self.family = resolve(self.config["family"])
+        self.reference = resolve(self.config["reference"])
         self.traffic = load_json(os.path.join(
             traffic_dir or os.path.join(bench_dir, "traffic"),
             self.entry["traffic"] + ".json"))

@@ -6,6 +6,15 @@ per chip with the lines ``XLA Modules`` (one event per program execution, named
 HLO text) and ``Async XLA Ops``; one plane ``/host:CPU`` whose ``python3`` line
 carries ``TraceAnnotation`` spans.  All on one clock, in nanoseconds.
 
+An operation's metadata carries what the program called it (looked at by hand,
+PR 30): ``tf_op``, the HLO metadata's ``op_name`` (``jit(block)/pallas_call:``;
+every ``jax.named_scope`` and a ``pallas_call(name=)`` are components of that
+path), and ``source``, the file and line of the program that made the call.
+Both are kept beside the HLO text as the operation's given name,
+``<op_name> @ <file>:<line>``, the file relative to the checkout; a Pallas
+kernel is a ``pallas_call`` made in its own file under its own name, whatever
+program holds it and whatever its shapes.
+
 Busy time is the union of the ``XLA Ops`` intervals; an asynchronous copy or
 collective that overlaps compute adds nothing to it.
 """
@@ -17,9 +26,13 @@ import os
 import re
 from bisect import bisect_right
 
+from . import xplane
+from .spec import ROOT
+
 SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.window"
 CONTAINER = re.compile(r"%?(while|conditional|call)[.\d]* = ")
+KERNEL_CALL = re.compile(r"pallas_call @ (?:\S*/)?([^/\s]+)$")
 
 
 def find_xplane(trace_dir):
@@ -43,30 +56,43 @@ def short_name(text):
     return f"{name}:{shape}"
 
 
+def given_name(stats):
+    """``<op_name> @ <file>:<line>`` from an operation's metadata stats, or
+    whichever of the two it has; empty where the program named nothing."""
+    source = stats.get("source") or ""
+    if source.startswith(ROOT + os.sep):
+        source = os.path.relpath(source, ROOT)
+    op_name = (stats.get("tf_op") or "").rstrip(":")
+    return " @ ".join(part for part in (op_name, source) if part)
+
+
 class Trace:
-    """Events of one trace, cut to the ``bench.window`` span."""
+    """Events of one trace, cut to the ``bench.window`` span.  An operation
+    is ``(HLO text, start_s, end_s, given name)``, a program execution and a
+    span ``(name, start_s, end_s)``."""
 
     def __init__(self, path):
-        from jax.profiler import ProfileData
-        data = ProfileData.from_file(path)
         self.devices = []      # per chip: {"ops": [...], "modules": [...]}
         self.spans = []        # (name, start_s, end_s) of bench.* annotations
-        for plane in data.planes:
-            if plane.name.startswith("/device:TPU:"):
-                dev = {"ops": [], "modules": []}
-                for line in plane.lines:
-                    if line.name == "XLA Ops":
-                        dev["ops"] = self._events(line)
-                    elif line.name == "XLA Modules":
-                        dev["modules"] = self._events(line)
-                if dev["ops"]:
-                    self.devices.append(dev)
-            elif plane.name == "/host:CPU":
-                for line in plane.lines:
-                    for e in line.events:
-                        if e.name.startswith(SPAN_PREFIX):
-                            s = e.start_ns * 1e-9
-                            self.spans.append((e.name, s, s + e.duration_ns * 1e-9))
+        for plane, lines in xplane.read(
+                path, lambda n: n.startswith("/device:TPU:") or n == "/host:CPU"):
+            if plane == "/host:CPU":
+                for _, events in lines:
+                    self.spans += [(n, s, e) for n, _, s, e in events
+                                   if n.startswith(SPAN_PREFIX)]
+                continue
+            dev = {"ops": [], "modules": []}
+            for line, events in lines:
+                if line == "XLA Ops":
+                    dev["ops"] = sorted(
+                        ((n, s, e, given_name(st)) for n, st, s, e in events),
+                        key=lambda x: x[1])
+                elif line == "XLA Modules":
+                    dev["modules"] = sorted(
+                        ((n, s, e) for n, _, s, e in events),
+                        key=lambda x: x[1])
+            if dev["ops"]:
+                self.devices.append(dev)
         win = [s for s in self.spans if s[0] == WINDOW_SPAN]
         if not win:
             raise ValueError(f"trace has no {WINDOW_SPAN} span")
@@ -74,26 +100,20 @@ class Trace:
         self.window_s = self.t1 - self.t0
         for dev in self.devices:
             for key in ("ops", "modules"):
-                dev[key] = [(n, max(s, self.t0), min(e, self.t1))
-                            for n, s, e in dev[key]
-                            if e > self.t0 and s < self.t1]
+                dev[key] = [(ev[0], max(ev[1], self.t0), min(ev[2], self.t1))
+                            + ev[3:] for ev in dev[key]
+                            if ev[2] > self.t0 and ev[1] < self.t1]
             dev["module_starts"] = [m[1] for m in dev["modules"]]
         self.spans = [s for s in self.spans
                       if s[0] != WINDOW_SPAN and s[2] > self.t0 and s[1] < self.t1]
-
-    @staticmethod
-    def _events(line):
-        out = [(e.name, e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
-               for e in line.events]
-        out.sort(key=lambda x: x[1])
-        return out
 
     # -- busy and idle ------------------------------------------------------
     @staticmethod
     def _union(events):
         """Merged (start, end) intervals of events sorted by start."""
         merged = []
-        for _, s, e in events:
+        for ev in events:
+            s, e = ev[1], ev[2]
             if merged and s <= merged[-1][1]:
                 if e > merged[-1][1]:
                     merged[-1][1] = e
@@ -131,15 +151,18 @@ class Trace:
                 if rx.search(n)]
         return sum(hits), len(hits)
 
-    def op_seconds(self, op_pattern, module_pattern=None, device=0):
-        """(seconds, events) of the operations whose HLO text matches, inside
-        programs whose name matches."""
-        rx = re.compile(op_pattern)
+    def op_seconds(self, op_pattern=None, module_pattern=None, device=0,
+                   kernel=None):
+        """(seconds, events) of the operations whose HLO text matches
+        ``op_pattern`` and whose given name matches ``kernel`` (either may be
+        left out), inside programs whose name matches."""
+        rx = re.compile(op_pattern) if op_pattern else None
+        krx = re.compile(kernel) if kernel else None
         mrx = re.compile(module_pattern) if module_pattern else None
         dev = self.devices[device]
         total, count = 0.0, 0
-        for n, s, e in dev["ops"]:
-            if not rx.search(n):
+        for n, s, e, given in dev["ops"]:
+            if (rx and not rx.search(n)) or (krx and not krx.search(given)):
                 continue
             if mrx is not None:
                 mod = self._module_of(dev, s)
@@ -149,15 +172,26 @@ class Trace:
             count += 1
         return total, count
 
+    def matching_seconds(self, module, op=None, kernel=None):
+        """(seconds, events) of the programs whose name matches ``module``,
+        or, where ``op`` or ``kernel`` is given, of the matching operations
+        inside them."""
+        if op is None and kernel is None:
+            return self.module_seconds(module)
+        return self.op_seconds(op, module, kernel=kernel)
+
     # -- the breakdown --------------------------------------------------------
     def top_ops(self, n=10, device=0):
         dev = self.devices[device]
         agg = {}
-        for name, s, e in dev["ops"]:
+        for name, s, e, given in dev["ops"]:
             if CONTAINER.match(name):
                 continue          # its body's operations are events of their own
             mod = self._module_of(dev, s) or "_no_module_"
             key = re.sub(r"\(\d+\)$", "", mod) + ":" + short_name(name)
+            kernel = KERNEL_CALL.search(given)
+            if kernel:            # a Pallas kernel: say which, by its call site
+                key += "@" + kernel.group(1)
             agg[key] = agg.get(key, 0.0) + (e - s)
         return sorted(agg.items(), key=lambda kv: -kv[1])[:n]
 

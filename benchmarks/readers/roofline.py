@@ -10,14 +10,11 @@ import sys
 from ..harness.spec import resolve
 
 
-def read(obs, ctx, work, module, op=None):
+def read(obs, ctx, work, module, op=None, kernel=None):
     trace = obs.get("trace")
     if trace is None:
         return None
-    if op is None:
-        seconds, n = trace.module_seconds(module)
-    else:
-        seconds, n = trace.op_seconds(op, module)
+    seconds, n = trace.matching_seconds(module, op, kernel)
     need = resolve(work)(obs, ctx)
     if not n or seconds <= 0 or need is None:
         return None

@@ -4,14 +4,11 @@ match, per unit of work counted over the traced span; milliseconds."""
 from .common import dig
 
 
-def read(obs, ctx, module, per, op=None, per_scale=1.0):
+def read(obs, ctx, module, per, op=None, kernel=None, per_scale=1.0):
     trace = obs.get("trace")
     if trace is None:
         return None
-    if op is None:
-        seconds, n = trace.module_seconds(module)
-    else:
-        seconds, n = trace.op_seconds(op, module)
+    seconds, n = trace.matching_seconds(module, op, kernel)
     units = dig(obs, per)
     if not n or not units:
         return None

@@ -40,7 +40,8 @@ def test_a_fault_is_lifted_when_its_block_ends():
 
 
 @pytest.mark.parametrize("name, control", [("tiny_train", "int8,half"),
-                                           ("tiny_sat", "fp8")])
+                                           ("tiny_sat", "fp8"),
+                                           ("gpt_tiny_sat", "fp8")])
 def test_the_control_comes_out_not_correct(name, control):
     """int8 (training) and float8 (serving, where a few dozen tokens of a toy
     model seldom flip under int8) in the reference's matmuls, put in the

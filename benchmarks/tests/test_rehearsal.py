@@ -7,6 +7,7 @@ of the last line.  The drivers are called directly:
 import io
 import json
 import os
+import re
 import time
 from contextlib import redirect_stderr, redirect_stdout
 
@@ -41,7 +42,7 @@ def drive(name, seed=3, seconds=1.5, trace=0, **kw):
 
 
 @pytest.mark.parametrize("name", ["tiny_sat", "tiny_train",
-                                  "tiny_train_dp2mp2"])
+                                  "tiny_train_dp2mp2", "gpt_tiny_sat"])
 def test_untraced_run_is_correct(name):
     c, ctx, (obs, rows, ok) = drive(name)
     assert ok, rows
@@ -59,7 +60,7 @@ def test_untraced_run_is_correct(name):
     assert err.getvalue().startswith("compared ")
 
 
-@pytest.mark.parametrize("name", ["tiny_sat", "tiny_train"])
+@pytest.mark.parametrize("name", ["tiny_sat", "tiny_train", "gpt_tiny_sat"])
 def test_traced_run_reads_its_trace(name):
     c, ctx, (obs, rows, ok) = drive(name, trace=1)
     assert ok, rows
@@ -74,3 +75,25 @@ def test_control_reads_beside_the_reference():
     ctl = obs["check"]["control"]
     assert ctl["fp8"]["loss_gap_max"] > ctl["int8"]["loss_gap_max"] \
         > 10 * obs["check"]["loss_gap_max"]
+
+
+def test_a_second_family_needs_its_own_files_and_no_other():
+    """What a ``model_config`` PR may do: files and entries.  The rehearsal's
+    GPT family is reached by the names in its configuration's file alone: the
+    harness, the drivers and the readers do not know it, nor the first
+    family, nor any leaf or published key of either."""
+    c = cell("gpt_tiny_sat")
+    assert c.family.__name__ == "benchmarks.rehearsal.families.gpt_tiny"
+    assert c.reference.__name__ == "benchmarks.rehearsal.reference.gpt_tiny"
+    assert c.config["model"]["architectures"] == ["GPT2LMHeadModel"]
+    assert cell("tiny_sat").family is not c.family
+    words = re.compile(r"gpt|llama|q_proj|qkv|intermediate_size|n_embd", re.I)
+    for d in ("harness", "drivers", "readers"):
+        for f in sorted(os.listdir(os.path.join(BENCH_DIR, d))):
+            if f.endswith(".py"):
+                with open(os.path.join(BENCH_DIR, d, f)) as fh:
+                    found = [line for line in fh if words.search(line)]
+                assert not found, (d, f, found)
+    # the first family does not know the second either
+    with open(os.path.join(BENCH_DIR, "families", "llama_dense.py")) as fh:
+        assert not re.search(r"gpt", fh.read(), re.I)
