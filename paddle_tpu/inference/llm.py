@@ -34,9 +34,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generation import (GenerationConfig, beam_scan_body,
-                                 decode_scan_body, init_kv_cache,
-                                 model_arrays, sample_token, swap_call,
+from ..models.generation import (GenerationConfig,
+                                 beam_scan_body, decode_scan_body,
+                                 init_kv_cache, model_arrays, sample_token,
+                                 slot_state_spec, swap_call,
                                  _gather_tree_arrays)
 
 
@@ -141,6 +142,13 @@ def build_weight_quant_plan(model, weight_dtype) -> WeightQuantPlan:
                                           quantize_channelwise)
     from ..ops.pallas.quantized_matmul import pack_int4
     bits = {"int8": 8, "int4": 4}[weight_dtype]
+    from ..nn import RoutedExperts
+    if any(isinstance(l, RoutedExperts) for l in model.sublayers()):
+        raise ValueError(
+            f"weight_dtype={weight_dtype!r}: {type(model).__name__} has "
+            "expert planes (nn.RoutedExperts), which the weight "
+            "quantisation plan does not cover — the grouped matmul reads "
+            "them at the compute dtype")
     if not hasattr(model, "quant_projections"):
         raise ValueError(
             f"weight_dtype={weight_dtype!r} needs a model exposing "
@@ -346,6 +354,31 @@ def _flatten_paged_kvs(kvs):
     return flat
 
 
+def _split_slot_state(model, flat_arenas):
+    """A program's trailing donated arrays as (KV arenas, slot-state
+    arenas): the state arenas of ``slot_state_spec`` ride behind the KV
+    arenas, so a model without such state sees exactly its KV arenas."""
+    flat = list(flat_arenas)
+    n_kv = len(flat) - len(slot_state_spec(model))
+    return flat[:n_kv], flat[n_kv:]
+
+
+def _poison_rows(arena, finished):
+    """A slot-state arena with the rows of the slots that ``finished``
+    inside this block set to NaN (a float arena; another is left as it
+    is).  Nothing may read a finished slot's state: its next prompt starts
+    from zeros (``prefill_chunk`` selects, it does not multiply), and a
+    decode program skips the rows that enter it ``stale``.  A program that
+    loses that reset would otherwise serve plausible tokens from a stale
+    convolution tail or recurrent state, wrong by less than bfloat16's
+    own noise; from NaN it serves garbage, which every comparison sees."""
+    if not jnp.issubdtype(arena.dtype, jnp.inexact):
+        return arena
+    b = finished.shape[0]
+    rows = finished.reshape((b,) + (1,) * (arena.ndim - 1))
+    return arena.at[:b].set(jnp.where(rows, jnp.nan, arena[:b]))
+
+
 def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
                               kv_int8=False,
                               samp_flags=(False, False, False, False),
@@ -405,8 +438,17 @@ def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
     sampled, _filtered, penalty, _bias = samp_flags
 
     def _scan(tok, lens, done, budget, samp, tables, flat_arenas):
-        kvs = _pack_paged_kvs(_constrain_arenas(flat_arenas, shard),
+        flat_kv, state = _split_slot_state(model, flat_arenas)
+        kvs = _pack_paged_kvs(_constrain_arenas(flat_kv, shard),
                               tables, kv_int8)
+        if state:
+            # the second kind of state rides the scan carry as one more
+            # entry of ``kvs``; the scan body never looks inside.
+            # ``stale``: the rows that were done when the block began,
+            # whose state rows hold nothing to read (vacant, prefilling,
+            # or poisoned below by the block they finished in)
+            kvs.append({"state": state, "stale": done,
+                        "counters": model.init_block_counters()})
         pos0 = samp["pos"] if sampled else jnp.zeros_like(lens)
         pres0 = samp["presence"] if penalty else None
         with _shard_scope(shard):
@@ -415,9 +457,15 @@ def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
                     sampled_decode_scan_body(model, cfg, samp, samp_flags),
                     (tok, lens, kvs, pos0, pres0, done, budget),
                     None, length=steps_per_call)
+        tail = ()
+        if state:
+            slot_state = kvs_f.pop()
+            tail = tuple(_poison_rows(a, done_f & ~done)
+                         for a in slot_state["state"]) \
+                + (slot_state["counters"],)
         return ((toks.T.astype(jnp.int32), tok_f, lens_f, done_f,
                  budget_f) + tuple(_constrain_arenas(
-                     _flatten_paged_kvs(kvs_f), shard)))
+                     _flatten_paged_kvs(kvs_f), shard)) + tail)
 
     if lora:
         def block_pure(p_values, tok, lens, done, budget, samp,
@@ -558,14 +606,22 @@ def build_chunk_prefill(model, cfg: GenerationConfig, kv_int8=False,
     penalty = samp_flags[2]
 
     def _chunk(ids, start, n_valid, tables, samp, flat_arenas):
-        kvs = _pack_paged_kvs(_constrain_arenas(flat_arenas, shard),
+        flat_kv, state = _split_slot_state(model, flat_arenas)
+        kvs = _pack_paged_kvs(_constrain_arenas(flat_kv, shard),
                               tables, kv_int8)
+        if state:
+            # ``tables`` carries the slot index behind the slot's blocks
+            # ([1, max_blocks + 1]): the chunk reads and writes that row of
+            # the state arenas, with no argument and no copy of its own
+            kvs = [kv[:-1] + (tables[:, :-1],) for kv in kvs]
+            kvs.append({"state": state, "slot": tables[0, -1]})
         with _shard_scope(shard):
             logits, kvs_f = model.prefill_chunk(ids, start, n_valid, kvs)
         tok = sample_rows(logits, samp, samp_flags,
                           samp["presence"] if penalty else None)
+        tail = tuple(kvs_f.pop()["state"]) if state else ()
         return (tok,) + tuple(_constrain_arenas(
-            _flatten_paged_kvs(kvs_f), shard))
+            _flatten_paged_kvs(kvs_f), shard)) + tail
 
     if lora:
         def chunk_pure(p_values, ids, start, n_valid, tables, samp,

@@ -45,10 +45,15 @@ prefill) design, restricted to what XLA's static shapes allow:
   blake2b chains, HBM-only, reclaim forgets) remains as the bench A/B
   arm.
 - **Chunked prefill**: prompts are computed ``chunk_len`` tokens at a
-  time, at most ONE chunk per ``step()`` alongside the shared decode
-  block — a long prompt no longer stalls in-flight decoding for its
-  full prompt pass, and TTFT of queued requests overlaps decode
-  instead of serializing behind it.
+  time alongside the shared decode block — a long prompt no longer
+  stalls in-flight decoding for its full prompt pass, and TTFT of
+  queued requests overlaps decode instead of serializing behind it.
+  A ``step()`` runs ONE chunk while no more than ``steps_per_call``
+  slots wait for their prompt, and a ``steps_per_call``-th of the
+  waiting slots' chunks beyond that (``_prefill_chunks``): a backlog
+  of prompts (many slots, or a burst of arrivals) is worked off
+  geometrically instead of one chunk a step, which would hold a wide
+  batch at a fraction of its slots.
 - **Paged reads**: decode attention goes through the block table — the
   Pallas flash-decode kernel gained a block-table DMA variant
   (``decode_attention_paged``; gate reasons ``paged_ok`` /
@@ -223,6 +228,7 @@ replica router of ``inference/router.py`` reads as its load signal.
 from __future__ import annotations
 
 import hashlib
+import logging
 import time
 import warnings
 from collections import OrderedDict, deque
@@ -233,8 +239,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generation import (GenerationConfig, init_paged_kv_arena,
-                                 model_arrays)
+from ..models.generation import (GenerationConfig, SlotStateError,
+                                 init_paged_kv_arena, init_slot_state,
+                                 model_arrays, slot_state_spec)
 from ..observability import metrics as obs_metrics
 from ..observability.flightrec import ENGINE_EVENT, FlightRecorder
 from ..observability.spans import instant as _span_instant
@@ -420,6 +427,15 @@ class _ServingInstruments:
             "not decode steps, and are excluded — see serving.spec.*)")
         self.block_dispatches = r.counter(
             "serving.block_dispatches", "compiled decode block calls")
+        self.moe_expert_tokens = r.counter(
+            "moe.expert_tokens", "live decode rows routed to each expert, "
+            "summed over decode steps and expert layers (fed at harvest "
+            "from the decode block's counters)", labels=("expert",))
+        self.moe_layer_steps = r.counter(
+            "moe.layer_steps", "(expert layer, decode step) pairs counted")
+        self.moe_experts_touched = r.counter(
+            "moe.experts_touched", "experts that got at least one live "
+            "row, summed over the (expert layer, decode step) pairs")
         self.tokens_emitted = r.counter(
             "serving.tokens_emitted", "tokens emitted to requests "
             "(prefill first-tokens + decode-block harvest; "
@@ -1155,6 +1171,7 @@ class _PendingBlock:
     lens_d: object                 # remaining budget (the last two
     done_d: object                 # form the finish-bitmap protocol)
     budget_d: object
+    counters_d: object = None      # the model's block counters, if any
 
 
 class _LazyStacks:
@@ -1206,6 +1223,9 @@ class _SwapRecord:
     tok: int
     lens: int
     state: str                     # "prefill" | "decode"
+    # the slot's rows of the per-slot state arenas (``slot_state_spec``
+    # models), which travel with the blocks: one host array an arena
+    slot_state: Optional[List[np.ndarray]] = None
 
 
 @dataclass
@@ -1375,7 +1395,7 @@ class ServingEngine:
     ``submit()`` enqueues requests (optionally with a future
     ``arrival_time`` for trace replay); ``cancel()`` drops a
     still-queued one; ``step()`` runs one scheduler iteration (admit +
-    at most one prefill chunk + one decode block); ``run()`` drains
+    the step's prefill chunks + one decode block); ``run()`` drains
     everything and returns the finished requests.  Greedy output is
     token-for-token identical to per-request static ``generate()`` —
     see ``_build_decode_block``'s row-independence contract and the
@@ -1432,6 +1452,37 @@ class ServingEngine:
                 raise ValueError(
                     f"prefix_cache_mode must be 'radix', 'digest' or "
                     f"'none', got {prefix_cache_mode!r}")
+        # a model that keeps per-slot state beside its blocks
+        # (slot_state_spec: a convolution tail, a recurrent state) can
+        # take nothing that shares, moves or rewinds blocks without the
+        # state: a prefix hit would start the suffix from a zero state.
+        # Such a model is served with the prefix cache off, and every
+        # other such feature refuses it by name where it is asked for.
+        self._state_spec = slot_state_spec(model)
+        self.prefix_cache_disabled = bool(self._state_spec) and \
+            mode != "none"
+        if self.prefix_cache_disabled:
+            logging.getLogger(__name__).info(
+                "%s keeps per-slot state (%s) beside its paged KV: serving "
+                "it with prefix_cache_mode='none' (asked: %r)",
+                type(model).__name__,
+                ", ".join(n for n, _ in self._state_spec), mode)
+            mode = "none"
+        if self._state_spec:
+            if drafter is not None:
+                raise SlotStateError(model, "speculative decoding (a "
+                                     "drafter)")
+            if self.role != "both":
+                raise SlotStateError(
+                    model, f"a disaggregated handoff parcel (role="
+                    f"{self.role!r})")
+            if host_cache_blocks:
+                raise SlotStateError(
+                    model, f"HostTier demotion and swap-in of cached "
+                    f"blocks (host_cache_blocks={host_cache_blocks})")
+            if mesh is not None and "model" in mesh.axis_names and \
+                    int(mesh.shape["model"]) > 1:
+                raise SlotStateError(model, "a mesh with mp > 1")
         self.prefix_cache_mode = mode
         self.enable_prefix_cache = mode != "none"
         if self.num_slots < 1:
@@ -1555,6 +1606,16 @@ class ServingEngine:
         self._arenas: List = []
         for entry in arenas:
             self._arenas += list(entry)
+        # the second kind of state: one arena a spec entry, indexed by
+        # slot, donated through the chunk and decode programs behind the
+        # KV arenas (empty for a model whose only state is keys and values)
+        self._slot_state: List = init_slot_state(
+            self._state_spec, self.num_slots,
+            jnp.dtype(self.cfg.compute_dtype))
+        self._slot_state_bytes = sum(int(a.nbytes)
+                                     for a in self._slot_state)
+        self._state_read_fn = None
+        self._state_write_fn = None
         # -- tensor-parallel serving over a device mesh (PR 18) --
         # ``mesh=Mesh(...)`` shards every arena plane's kv-head axis
         # (codes [NB+1, L, Hkv*D] and int8 scales [NB+1, L, Hkv] both
@@ -1598,6 +1659,8 @@ class ServingEngine:
                 _decode_attn.count_shard_route(hkv, n_sh, False)
             self._arenas = [jax.device_put(a, kv_ns)
                             for a in self._arenas]
+            self._slot_state = [jax.device_put(a, rep)
+                                for a in self._slot_state]
             self._pb = [jax.device_put(v, rep) for v in self._pb]
             self.shard_group = {
                 "n_shards": n_sh if tp_ok else 1,
@@ -1657,8 +1720,9 @@ class ServingEngine:
         # spec verify take (pb, <4 planes>, samp, *arenas); the decode
         # block grew the finish-bitmap ``budget`` carry, shifting its
         # arenas one right
-        self._donate = tuple(range(6, 6 + len(self._arenas)))
-        self._donate_blk = tuple(range(7, 7 + len(self._arenas)))
+        n_donated = len(self._arenas) + len(self._slot_state)
+        self._donate = tuple(range(6, 6 + n_donated))
+        self._donate_blk = tuple(range(7, 7 + n_donated))
         # compiled programs are cached per (static shape, sampling
         # feature flags): an all-greedy engine compiles exactly the
         # argmax-only program shapes, and each sampling feature
@@ -2062,6 +2126,7 @@ class ServingEngine:
                 tok = np.array(p.tok_d)   # np.array: writable host copies
                 lens = np.array(p.lens_d)
                 done = np.array(p.done_d)     # the finish bitmap
+                self._count_experts(p)
                 self._charge_overlap(wait.stop())
             toks = self._checked_harvest(toks)
             n_before = len(out)
@@ -2466,6 +2531,9 @@ class ServingEngine:
         sp = sampling if sampling is not None else self._default_sampling
         spec_k = None
         if spec_decode is not None:
+            if self._slot_state:
+                raise SlotStateError(self._model, "speculative decoding "
+                                     "(submit(spec_decode=))")
             spec_k = int(spec_decode)
             if spec_k < 1:
                 raise ValueError(
@@ -2721,6 +2789,9 @@ class ServingEngine:
         spills to another replica); parcel re-admissions join the
         swap list, which is never bounded (exactly like preemption).
         """
+        if parcel is not None and self._slot_state:
+            raise SlotStateError(self._model, "an exact-bytes migration "
+                                 "parcel (migrate_in(parcel=))")
         ids = np.asarray(getattr(prompt_ids, "_value", prompt_ids))
         ids = np.asarray(ids).reshape(-1).astype(np.int32)
         if ids.size < 1 or ids.size > self.prompt_len:
@@ -3167,6 +3238,38 @@ class ServingEngine:
             out.append(r)
 
     # -- preemption + host-RAM swap --
+    def _read_slot_state(self, slot: int):
+        """Slot ``slot``'s rows of the per-slot state arenas as host
+        arrays (None for a model without such state): what a swap record
+        carries beside the blocks, since this engine preempts by
+        swapping bytes and never by recomputing."""
+        if not self._slot_state:
+            return None
+        if self._state_read_fn is None:
+            self._state_read_fn = jax.jit(
+                lambda i, *arenas: tuple(a[i] for a in arenas))
+        rows = self._state_read_fn(jnp.asarray(slot, jnp.int32),
+                                   *self._slot_state)
+        return [np.asarray(r) for r in rows]        # sync: preempt
+
+    def _write_slot_state(self, slot: int, rows):
+        """Put a swap record's state rows into slot ``slot``'s rows of
+        the state arenas (donated, as the swap-in scatter's are)."""
+        if not self._slot_state:
+            return
+        if rows is None:
+            raise SlotStateError(self._model, "a swap record without "
+                                 "state rows")
+        if self._state_write_fn is None:
+            n = len(self._slot_state)
+            self._state_write_fn = jax.jit(
+                lambda i, *a: tuple(arena.at[i].set(row) for row, arena
+                                    in zip(a[:n], a[n:])),
+                donate_argnums=tuple(range(1 + n, 1 + 2 * n)))
+        self._slot_state = list(self._state_write_fn(
+            jnp.asarray(slot, jnp.int32),
+            *[jnp.asarray(r) for r in rows], *self._slot_state))
+
     def _swap_out(self):
         if self._swap_out_fn is None:
             self._swap_out_fn = jax.jit(
@@ -3225,7 +3328,8 @@ class ServingEngine:
         req.swap = _SwapRecord(host_key=key, n_blocks=n,
                                tok=int(self._tok[slot]),
                                lens=int(self._lens[slot]),
-                               state=req.state)
+                               state=req.state,
+                               slot_state=self._read_slot_state(slot))
         if req in self._prefilling:
             self._prefilling.remove(req)
         self._release_blocks(req)
@@ -3332,6 +3436,7 @@ class ServingEngine:
                 # into the trash row through the trash-padded ``row``)
                 self._scatter_rows(
                     row, self._host_tier.entry(rec.host_key).rows)
+                self._write_slot_state(slot, rec.slot_state)
         except BaseException:
             for b in fresh:
                 self._pool.unpin(b)
@@ -3925,6 +4030,22 @@ class ServingEngine:
         return not np.asarray(mp.allowed(), bool).any()
 
     # graftlint: plan-phase
+    def _prefill_chunks(self, out: List[Request]):
+        """This step's prompt chunks: one, and where more than
+        ``steps_per_call`` slots wait for their prompt, one for every
+        ``steps_per_call`` of them (FIFO, so the head of the line may
+        take several).  One chunk a step admits about one request a
+        step whatever the slots: a batch of hundreds of slots whose
+        requests live a hundred steps never fills, and every decode
+        step pays its whole weight sweep for a fraction of the rows.  A
+        prompt now waits for a chunk no longer than a decoded token
+        waits for its harvest, ``steps_per_call`` steps, and the live
+        rows' stall a step is bounded by that share of the backlog."""
+        for _ in range(max(1, -(-len(self._prefilling)
+                                // self.steps_per_call))):
+            self._prefill_chunk(out)
+
+    # graftlint: plan-phase
     def _prefill_chunk(self, out: List[Request]):
         """Run at most ONE prompt chunk (FIFO over admissions).  The
         final chunk of a prompt samples the request's first token and
@@ -3951,9 +4072,9 @@ class ServingEngine:
                     jnp.asarray(req.chunk_ids[None, start:start + c]),
                     jnp.asarray(start, jnp.int32),
                     jnp.asarray(req.seq_len, jnp.int32),
-                    jnp.asarray(self._tables[req.slot][None, :]), samp,
-                    *lora_args, *self._arenas)
-                self._arenas = list(outp[1:])
+                    jnp.asarray(self._chunk_tables(req.slot)), samp,
+                    *lora_args, *self._arenas, *self._slot_state)
+                self._adopt_arenas(outp[1:])
                 # a non-final chunk's sampled token is meaningless (the
                 # engine never advances decode state from it): the
                 # dispatch-ahead engine leaves it un-forced, so the chunk
@@ -4159,6 +4280,41 @@ class ServingEngine:
         sequential greedy stream either way)."""
         return r.state == "decode" and (r.spec_k is None
                                         or i in self._spec_fallback)
+
+    def _chunk_tables(self, slot: int) -> np.ndarray:
+        """The chunk program's table row ``[1, max_blocks]``; for a model
+        with per-slot state the slot's index rides behind its blocks
+        (``[1, max_blocks + 1]``), so that the program finds the slot's
+        row of the state arenas with no argument of its own."""
+        row = self._tables[slot][None, :]
+        if not self._slot_state:
+            return row
+        return np.concatenate(
+            [row, np.full((1, 1), slot, np.int32)], axis=1)
+
+    def _adopt_arenas(self, donated):
+        """Take back what a chunk or decode program returns behind its
+        own outputs: the KV arenas, then the per-slot state arenas, then
+        (decode programs of a model that counts) the block's counters,
+        which are returned for the harvest to fetch."""
+        n_kv, n_st = len(self._arenas), len(self._slot_state)
+        self._arenas = list(donated[:n_kv])
+        self._slot_state = list(donated[n_kv:n_kv + n_st])
+        rest = donated[n_kv + n_st:]
+        return rest[0] if rest else None
+
+    def _count_experts(self, p: _PendingBlock):
+        """Fetch a harvested decode block's expert-load array, where the
+        model counts one (rows routed to each expert, then the (layer,
+        step) pairs, then the experts that got a row summed over the
+        pairs), and feed it to the registry."""
+        if p.counters_d is None:
+            return
+        counters = np.asarray(p.counters_d)
+        for e in np.flatnonzero(counters[:-2]):
+            self._m.moe_expert_tokens.inc(int(counters[e]), expert=str(e))
+        self._m.moe_layer_steps.inc(int(counters[-2]))
+        self._m.moe_experts_touched.inc(int(counters[-1]))
 
     def _decode_tables(self) -> np.ndarray:
         """The decode block's table view: real rows for slots riding
@@ -4446,7 +4602,7 @@ class ServingEngine:
                     self._fault.record_tier_evicts(applied)
                     self._update_host_gauge()
             self._admit(t_now, finished)
-        self._prefill_chunk(finished)
+        self._prefill_chunks(finished)
         self._spec_fallback = set()
         self._spec_verify(finished)
         # re-assert spec rows' block state for THIS iteration: fallback
@@ -4595,8 +4751,8 @@ class ServingEngine:
             out = _call_quiet(
                 self._block_fn(n_total, flags, lora_on, iters=iters),
                 self._pb, tok_in, lens_in, done_in, budget_in, samp,
-                *lora_args, tables_in, *self._arenas)
-            self._arenas = list(out[5:])
+                *lora_args, tables_in, *self._arenas, *self._slot_state)
+            counters_d = self._adopt_arenas(out[5:])
         self._disp_s += ph.seconds
         # plan-known accounting lands at DISPATCH (same step as the
         # lockstep engine); output-dependent accounting (KV sweep,
@@ -4617,7 +4773,7 @@ class ServingEngine:
             iters=iters, active=list(active),
             reqs=[self._slots[i] for i in active], pre_lens=pre_lens,
             toks_d=out[0], tok_d=out[1], lens_d=out[2], done_d=out[3],
-            budget_d=out[4])
+            budget_d=out[4], counters_d=counters_d)
         self._pend_q.append(new_pend)
         # THE overlap points: older dispatches' outputs are forced
         # only now, after this iteration's host work ran and its
@@ -4652,6 +4808,7 @@ class ServingEngine:
                     tok = np.array(new_pend.tok_d)  # writable copies
                     lens = np.array(new_pend.lens_d)
                     done = np.array(new_pend.done_d)
+                    self._count_experts(new_pend)
                 # ... and the new dispatch's sync materialization is
                 # part of the dispatch, exactly the lockstep engine's
                 # attribution
@@ -4908,6 +5065,11 @@ class ServingEngine:
             "handoff_bytes": int(
                 self._m.since_init(self._m.handoff_bytes)),
             "role": self.role,
+            # the second kind of state (slot_state_spec models): the bytes
+            # of the per-slot state arenas, and whether the prefix cache
+            # that was asked for is off because a hit cannot carry them
+            "slot_state_bytes": self._slot_state_bytes,
+            "prefix_cache_disabled": self.prefix_cache_disabled,
             "mean_tpot_s": (sum(tpots) / len(tpots)) if tpots else None,
             "slo_attained": int(
                 self._m.since_init(self._m.slo_attained)),

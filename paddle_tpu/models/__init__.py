@@ -15,6 +15,8 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForSequenceClassification,
 from .gpt import (GPTConfig, GPTModel, GPTForCausalLM,
                   GPTPretrainingCriterion, gpt2_small_config,
                   gpt3_13b_config, tiny_gpt_config)
+from .lfm2 import (Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel,
+                   tiny_lfm2_config)
 from .ocr import (DBNet, DBNetConfig, DBLoss, DBFPN, DBHead, db_postprocess,
                   CRNN, CRNNConfig, CTCHeadLoss, ctc_greedy_decode,
                   PPOCRSystem)
@@ -32,6 +34,8 @@ __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM",
            "GPTConfig", "GPTModel", "GPTForCausalLM",
            "GPTPretrainingCriterion", "gpt2_small_config",
            "gpt3_13b_config", "tiny_gpt_config",
+           "Lfm2MoeConfig", "Lfm2MoeModel", "Lfm2MoeForCausalLM",
+           "tiny_lfm2_config",
            "DBNet", "DBNetConfig", "DBLoss", "DBFPN", "DBHead",
            "db_postprocess", "CRNN", "CRNNConfig", "CTCHeadLoss",
            "ctc_greedy_decode", "PPOCRSystem"]

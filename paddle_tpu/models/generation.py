@@ -149,6 +149,34 @@ def init_paged_kv_arena(num_layers, num_blocks, block_len, num_kv_heads,
             for _ in range(num_layers)]
 
 
+class SlotStateError(NotImplementedError):
+    """A feature that shares, moves or rewinds KV blocks was asked of a model
+    that keeps per-slot state beside them (``slot_state_spec``), which the
+    feature cannot carry: served tokens would come from a stale or zero
+    state."""
+
+    def __init__(self, model, feature):
+        names = ", ".join(name for name, _ in slot_state_spec(model))
+        super().__init__(
+            f"{feature} cannot carry the per-slot state ({names}) that "
+            f"{type(model).__name__} keeps beside its paged KV")
+
+
+def slot_state_spec(model):
+    """``[(name, shape a slot)]`` of what a slot of ``model`` keeps beside
+    its blocks; empty for a model whose only state is keys and values."""
+    spec = getattr(model, "slot_state_spec", None)
+    return list(spec()) if spec is not None else []
+
+
+def init_slot_state(spec, num_slots, dtype):
+    """One arena a spec entry, ``[num_slots + 1, *shape]``: row ``s`` is slot
+    ``s``'s state, and the extra last row takes the masked writes of vacant
+    and frozen rows, as the trash block does for keys and values."""
+    return [jnp.zeros((num_slots + 1,) + tuple(shape), dtype)
+            for _, shape in spec]
+
+
 def quantize_kv_heads(kv):
     """Per-entry per-kv-head absmax int8 quantization of K/V planes.
 

@@ -9,6 +9,7 @@ from .clip import (ClipGradByGlobalNorm, ClipGradByNorm,  # noqa: F401
 from .layer.activation import *  # noqa: F401,F403
 from .layer.common import *  # noqa: F401,F403
 from .layer.container import *  # noqa: F401,F403
+from .layer.experts import RoutedExperts  # noqa: F401
 from .layer.conv import *  # noqa: F401,F403
 from .layer.layers import Layer, ParamAttr, Parameter  # noqa: F401
 from .layer.loss import *  # noqa: F401,F403

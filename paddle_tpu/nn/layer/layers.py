@@ -139,10 +139,17 @@ class Layer:
         dtype = convert_dtype(dtype) or self._dtype
         init = attr.initializer or default_initializer or (
             Constant(0.0) if is_bias else XavierUniform())
-        from ..lazy import lazy_init_scope
-        with lazy_init_scope():
-            value = init(tuple(int(s) for s in shape), dtype)
+        from ..lazy import lazy_init_scope, placeholder
+        shape = tuple(int(s) for s in shape)
+        deferred = placeholder(shape, dtype)
+        if deferred is None:
+            with lazy_init_scope():
+                value = init(shape, dtype)
+        else:
+            value = jnp.zeros((), dtype)
         p = Parameter(value, trainable=attr.trainable, name=attr.name)
+        if deferred is not None:
+            p._value = deferred
         p.optimize_attr["learning_rate"] = attr.learning_rate
         p.regularizer = attr.regularizer
         p.need_clip = attr.need_clip

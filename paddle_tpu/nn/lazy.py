@@ -13,15 +13,60 @@ RAM while the mesh placement decides where each shard lives.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 
 __all__ = ["LazyGuard", "in_lazy_mode"]
 
 _LAZY = False
+_PLACEHOLDERS = False
 
 
 def in_lazy_mode() -> bool:
     return _LAZY
+
+
+class Unmaterialized:
+    """What a parameter holds between its construction under
+    ``placeholders()`` and its first ``set_value``: the shape, type and
+    placement its value will have, and no memory anywhere."""
+
+    __slots__ = ("shape", "dtype", "sharding")
+
+    def __init__(self, shape, dtype, sharding):
+        self.shape, self.sharding = tuple(shape), sharding
+        self.dtype = jax.numpy.dtype(dtype)
+
+    ndim = property(lambda self: len(self.shape))
+
+    def astype(self, dtype):
+        return Unmaterialized(self.shape, dtype, self.sharding)
+
+
+@contextlib.contextmanager
+def placeholders():
+    """For a loader, not a mode a user trains under: layers constructed
+    inside get ``Unmaterialized`` parameters, which the loader then fills
+    one by one with ``set_value``.  A model whose weights are about to be
+    read from a checkpoint or generated on the device needs no initial
+    values, and one that fills the device cannot afford them beside the
+    real ones (10.5 GB twice over on a 16 GB chip)."""
+    global _PLACEHOLDERS
+    prev, _PLACEHOLDERS = _PLACEHOLDERS, True
+    try:
+        yield
+    finally:
+        _PLACEHOLDERS = prev
+
+
+def placeholder(shape, dtype):
+    """The placeholder of a parameter created now, or None outside
+    ``placeholders()``."""
+    if not _PLACEHOLDERS:
+        return None
+    return Unmaterialized(
+        shape, dtype, jax.sharding.SingleDeviceSharding(jax.devices()[0]))
 
 
 class LazyGuard:

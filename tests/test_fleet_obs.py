@@ -63,7 +63,10 @@ def _run_trace(netm):
     rng = np.random.default_rng(77)
     prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
                for n in (10, 7, 8)]
-    news = [6, 5, 4]
+    # long enough that the trace outlives the kill by several router steps
+    # (the probes, the ring's overflow) now that an engine of
+    # ``steps_per_call=1`` prefills both its waiting prompts in one step
+    news = [9, 8, 7]
 
     regs = [MetricsRegistry() for _ in range(2)]
     recs = [FlightRecorder() for _ in range(2)]

@@ -977,7 +977,7 @@ def _stream_parity_batch(monkeypatch, hkv, g, d):
     return _stream_parity_batches[hkv, g, d]
 
 
-@pytest.mark.parametrize("hkv,g,d", [(2, 2, 64), (2, 1, 128)])
+@pytest.mark.parametrize("hkv,g,d", [(2, 2, 64), (2, 1, 128), (8, 4, 64)])
 @pytest.mark.parametrize("which", _STREAM_EDGES)
 def test_paged_stream_kernel_parity(monkeypatch, hkv, g, d, which):
     """The streaming paged kernel (interpret mode) against the XLA
@@ -990,6 +990,23 @@ def test_paged_stream_kernel_parity(monkeypatch, hkv, g, d, which):
     out, ref = _stream_parity_batch(monkeypatch, hkv, g, d)
     i = _STREAM_EDGES.index(which)
     np.testing.assert_allclose(out[i], ref[i], atol=2e-5, rtol=2e-5)
+
+
+def test_paged_gate_at_sparse_hybrid_geometry(monkeypatch):
+    """The gate at the geometry of the sparse hybrid cell: 256 slots, 8 KV
+    heads of 64 with 4 query heads each, bfloat16 arenas of 24,576 blocks of
+    16 and tables of 88 blocks (1408 positions).  With Pallas available it
+    answers ``paged_ok``: the streaming kernel's two stages and its 32-row
+    accumulator are well inside the VMEM budget."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "pallas_enabled", lambda: True)
+    q4 = jax.ShapeDtypeStruct((256, 8, 4, 64), jnp.bfloat16)
+    arena = jax.ShapeDtypeStruct(
+        da.paged_arena_shape(24576 + 1, 8, 16, 64), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((256, 88), jnp.int32)
+    assert arena.shape == (24577, 16, 512)
+    assert da._route_decision_paged(q4, arena, tables) == (True, "paged_ok")
+    assert da._stream_rows(8, 1, 4) == 32
 
 
 @pytest.mark.parametrize("b,lens", [

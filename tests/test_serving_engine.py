@@ -272,6 +272,36 @@ def test_paged_prefix_parity_chunked_prefill(netm):
     assert eng.stats()["cancelled"] == 1
 
 
+@pytest.mark.parametrize("slots,spc,want", [(6, 2, [3, 3, 2, 1, 1, 1, 1]),
+                                             (6, 8, [1] * 12),
+                                             (3, 1, [3, 2, 1])])
+def test_backlog_of_prompts_is_worked_off_by_its_share_a_step(
+        netm, slots, spc, want):
+    """A step runs one chunk while no more than ``steps_per_call`` slots
+    wait for their prompt, and a ``steps_per_call``-th of the waiting
+    slots' chunks beyond that (rounded up, FIFO): with one chunk a step a
+    batch of hundreds of slots never fills.  Prompts of two chunks each;
+    the tokens are the oracle's whatever the share."""
+    cfg, net = netm
+    rng = np.random.default_rng(5)
+    eng = ServingEngine(net, num_slots=slots, prompt_len=P, max_cache_len=C,
+                        steps_per_call=spc, block_len=4, chunk_len=3,
+                        compute_dtype="float32", prefix_cache_mode="none")
+    prompts = [rng.integers(0, cfg.vocab_size, (5,)).astype(np.int32)
+               for _ in range(slots)]
+    reqs = [eng.submit(p, max_new_tokens=3) for p in prompts]
+    got, before = [], 0
+    while len(got) < len(want):
+        eng.step()
+        now = eng.stats()["prefill_chunks"]
+        got.append(now - before)
+        before = now
+    assert got == want
+    eng.run()
+    for p, r in zip(prompts, reqs):
+        assert list(r.output) == list(_oracle(net, _pad(p), 5, 3))
+
+
 def test_stats_before_any_finish_returns_nones(netm):
     """stats() on a virgin engine (and mid-flight before any request
     finishes) must not divide by zero: mean latency/TTFT over the empty
