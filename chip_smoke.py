@@ -13,9 +13,13 @@ weights random from a seed):
    decode, all through the arenas with the kernels routed) against the
    model's plain full forward in float32 at ``highest`` precision, and
    of the int8 KV cache against the float one;
-3. training — ``TrainStep`` at b8 x s2048 with the fused AdamW kernel:
+3. moe_experts — the routed-expert layer's grouped matmul
+   (``ops/pallas/grouped_matmul.py``) at the two shapes of the sparse
+   serving cell, through its gate, against ``jax.lax.ragged_dot``:
+   results and gradients;
+4. training — ``TrainStep`` at b8 x s2048 with the fused AdamW kernel:
    loss finite and falling, ``tpu_custom_call`` in the lowered step;
-4. with four or more devices, both paths sharded and one engine per chip.
+5. with four or more devices, both paths sharded and one engine per chip.
 
 It needs a TPU and says so at once when there is none; there is no CPU
 mode and no size switch.  One process holds the chip from start to end:
@@ -60,6 +64,16 @@ KV_INT8_TOL = 0.15
 # computations, each within PAGED_TOL of the float32 forward.  Measured
 # 0.047 on the four-chip host (chip run, PR 22).
 SHARDED_TOL = 0.10
+# MOE_TOL: the grouped-matmul kernel against ``jax.lax.ragged_dot``, both
+# bf16 roundings of a float32 accumulation over the whole of K; as
+# max|d| over the RMS of ragged_dot's result.  Measured 0.0 on the v5e at
+# both shapes (chip run, PR 34: the same bits); one bf16 step on the
+# largest entry, about five times the RMS, would read 0.02.
+MOE_TOL = 0.02
+# rows, K, N of the sparse serving cell's two grouped matmuls (w1/w3 and
+# w2 of LFM2-24B-A2B at 256 slots x 4 experts a token), and its experts
+MOE_SHAPES = ((1024, 2048, 1536), (1024, 1536, 2048))
+MOE_GROUPS = 64
 
 
 class SmokeFailure(AssertionError):
@@ -136,7 +150,8 @@ def _bytes_in_use():
             for d in jax.devices()]
 
 
-_ROUTES = ("pallas.decode_attention.route", "pallas.quantized_matmul.route")
+_ROUTES = ("pallas.decode_attention.route", "pallas.quantized_matmul.route",
+           "pallas.moe_experts.route")
 
 
 def _route_snapshot():
@@ -454,6 +469,51 @@ def agreement(model, sizes):
 
 
 # ---------------------------------------------------------------------------
+# the expert layer's grouped matmul
+# ---------------------------------------------------------------------------
+
+def moe_experts(shapes, groups, seed=3):
+    """``grouped_matmul`` through its gate at each (rows, K, N), against
+    ``jax.lax.ragged_dot`` on the same operands: three groups in four get
+    rows, of unequal sizes, as the serving cell's do.  Returns the largest
+    relative error of the results and of the gradients, and the route
+    deltas."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    rng = np.random.default_rng(seed)
+    before = _route_snapshot()
+    errs, grad_errs = [], []
+    for m, k, n in shapes:
+        share = rng.random(groups) * (rng.random(groups) < 0.75)
+        share[0] += 1e-3
+        sizes = np.floor(share / share.sum() * m).astype(np.int32)
+        sizes[0] += m - sizes.sum()
+        xs = jnp.asarray(rng.standard_normal((m, k), np.float32),
+                         jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal((groups, k, n), np.float32)
+                        * 0.02, jnp.bfloat16)
+        sz = jnp.asarray(sizes)
+        got = jax.jit(grouped_matmul)(xs, w, sz)
+        ref = jax.jit(jax.lax.ragged_dot)(xs, w, sz)
+        got, ref = (np.asarray(a, np.float32) for a in (got, ref))
+        if not np.isfinite(got).all():
+            raise SmokeFailure("non-finite grouped matmul")
+        errs.append(rel_err(got, ref))
+        # the layer is differentiable through either body
+        pull = jnp.asarray(rng.standard_normal((m, n), np.float32))
+
+        def grads(body):
+            return jax.jit(jax.grad(
+                lambda a, b: jnp.sum(body(a, b, sz) * pull), (0, 1)))(xs, w)
+        for a, b in zip(grads(grouped_matmul), grads(jax.lax.ragged_dot)):
+            grad_errs.append(rel_err(np.asarray(a, np.float32),
+                                     np.asarray(b, np.float32)))
+    return {"vs_ragged_dot": max(errs), "grad_vs_ragged_dot": max(grad_errs),
+            "routes": _route_delta(before)}
+
+
+# ---------------------------------------------------------------------------
 # training
 # ---------------------------------------------------------------------------
 
@@ -689,6 +749,14 @@ def main():
             raise SmokeFailure(
                 f"int8 KV off the float paged path by "
                 f"{agree['kv_int8_vs_paged']:.4f} > {KV_INT8_TOL}")
+
+    with phase("moe_experts", clock, report) as out:
+        out.update(moe_experts(MOE_SHAPES, MOE_GROUPS))
+        require_routes(out["routes"], ["moe_experts:pallas:grouped_ok"])
+        for key in ("vs_ragged_dot", "grad_vs_ragged_dot"):
+            if not out[key] <= MOE_TOL:
+                raise SmokeFailure(
+                    f"grouped matmul {key} {out[key]:.4f} > {MOE_TOL}")
 
     if len(devs) >= 4:
         with phase("multichip_serving", clock, report) as out:

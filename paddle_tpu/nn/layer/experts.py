@@ -5,8 +5,10 @@ width, and returns the part of the result that its own experts give.
 What an absent expert would add is left out; on one chip there is no exchange
 and nothing stands in for the chips that hold the others.  Dropless by
 construction: the (token, expert) assignments are sorted by expert and run
-through one grouped matmul (``jax.lax.ragged_dot``) whose groups are as long
-as the routing made them, so no capacity is chosen and no token can exceed it.
+through one grouped matmul whose groups are as long as the routing made them
+(``ops/pallas/grouped_matmul.py``: on the chip a kernel that reads each
+touched expert's plane once, elsewhere ``jax.lax.ragged_dot``), so no
+capacity is chosen and no token can exceed it.
 ``incubate/distributed/models/moe`` dispatches into ``[experts, capacity,
 width]`` buffers instead, which at a dropless capacity is ``num_experts /
 top_k`` times the useful work.
@@ -18,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.tensor import Tensor
+from ...ops.pallas.grouped_matmul import grouped_matmul
 from ..initializer import Constant, Normal
 from .layers import Layer
 
@@ -60,12 +63,15 @@ def grouped_experts(u, chosen, weights, w1, w3, w2, held):
         order = jnp.argsort(key, stable=True)
         sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
         xs = jnp.take(u, order // k, axis=0)
-        gate = jax.lax.ragged_dot(xs, w1, sizes)
-        up = jax.lax.ragged_dot(xs, w3, sizes)
-        y = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes)
+        gate = grouped_matmul(xs, w1, sizes)
+        up = grouped_matmul(xs, w3, sizes)
+        y = grouped_matmul(jax.nn.silu(gate) * up, w2, sizes)
         y = jnp.take(y, jnp.argsort(order), axis=0).reshape(n, k, -1)
-        w = jnp.where(mine.reshape(n, k), weights, 0.0)
-        out = jnp.einsum("nk,nkh->nh", w, y.astype(jnp.float32))
+        # an absent expert's rows are past every group: the kernel stores
+        # zeros there, but on the chip ``ragged_dot`` leaves what the
+        # memory held (4.6 and NaN in two tries, PR 34), and 0 x NaN is NaN
+        y = jnp.where(mine.reshape(n, k, 1), y.astype(jnp.float32), 0.0)
+        out = jnp.einsum("nk,nkh->nh", weights, y)
         return out.astype(u.dtype)
 
 

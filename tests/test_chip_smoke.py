@@ -83,6 +83,17 @@ def test_agreement(tiny):
     assert 0 < got["kv_int8_vs_paged"] < chip_smoke.KV_INT8_TOL
 
 
+def test_moe_experts_phase():
+    out = chip_smoke.moe_experts(((32, 256, 128), (32, 128, 256)), 8)
+    # on the CPU the gate answers ragged_dot, which is what it is held to
+    assert out["vs_ragged_dot"] == 0.0 and out["grad_vs_ragged_dot"] == 0.0
+    assert out["routes"] == {
+        "moe_experts:decision=xla,reason=pallas_unavailable": 4}
+    with pytest.raises(chip_smoke.SmokeFailure, match="route decisions"):
+        chip_smoke.require_routes(out["routes"],
+                                  ["moe_experts:pallas:grouped_ok"])
+
+
 def test_train_phase():
     out = chip_smoke.train(_tiny_config(), TINY)
     assert len(out["losses"]) == TINY.train_steps

@@ -673,6 +673,7 @@ def test_metrics_name_lint_clean():
              "serving.shard.", "serving.transport.",
              "serving.handoff.", "serving.role",
              "pallas.decode_attention.route",
+             "pallas.moe_experts.route",
              "serving.tpot_seconds")), n
         assert n in names, n
     kinds = {r[3]: r[2] for r in regs}

@@ -193,6 +193,11 @@ REQUIRED_INSTRUMENTS = {
     "serving.shard.groups": ("gauge", ()),
     "serving.shard.width": ("gauge", ()),
     "pallas.decode_attention.route": ("counter", ("decision", "reason")),
+    # the routed-expert layer's grouped matmul (PR 34,
+    # ops/pallas/grouped_matmul.py): kernel vs jax.lax.ragged_dot with
+    # the gating reason (MOE_ROUTE_REASONS); chip_smoke.py's moe_experts
+    # arm and the serving harness's route check key on it
+    "pallas.moe_experts.route": ("counter", ("decision", "reason")),
     # wire transport (PR 19, inference/transport.py
     # _TransportInstruments): frames moved per kind (the determinism
     # surface the bench multiproc arm gates on), encoded byte totals

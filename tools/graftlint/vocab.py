@@ -89,6 +89,12 @@ VOCABS: Tuple[VocabSpec, ...] = (
     # _shard_route_reason producer's literal returns
     VocabSpec("DECODE_ROUTE_REASONS", dead=False,
               producers=("_shard_route_reason",)),
+    # grouped-matmul routing reasons of the routed-expert layer (PR 34,
+    # ops/pallas/grouped_matmul.py): every label the
+    # pallas.moe_experts.route counter can carry is a literal return of
+    # the _moe_route_reason / _geometry_reason producers
+    VocabSpec("MOE_ROUTE_REASONS",
+              producers=("_moe_route_reason", "_geometry_reason")),
     # wire-transport frame kinds (PR 19, inference/transport.py):
     # every request kind has a literal transport.rpc("<kind>", ...)
     # site (RemoteReplica and friends), every reply kind a literal
