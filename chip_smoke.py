@@ -95,8 +95,7 @@ class Sizes:
     dtype: str
 
 
-# the engine geometry of bench.py's TPU serving arm and the first rung of
-# its training ladder
+# the 1.1B configuration's serving geometry and training batch
 FULL = Sizes(num_slots=8, prompt_len=128, max_cache_len=1024,
              steps_per_call=8, block_len=16, spec_k=4, train_batch=8,
              train_seq=2048, train_steps=4, dtype="bfloat16")

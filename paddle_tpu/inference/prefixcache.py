@@ -1,12 +1,9 @@
 """Tiered radix-tree prefix cache: token-level longest-prefix match
 over the paged KV block pool, with a host-RAM second tier.
 
-PR 3's prefix cache was a block-aligned chained-digest map living
-entirely in HBM: a prompt matched only in whole-block multiples of
-identical digest chains, and a cached block the LRU reclaimed was
-simply forgotten — the next sharer recomputed it.  This module is the
-RadixAttention design (SGLang, Zheng et al., 2023) layered over the
-vLLM-style block pool, extended with an explicit memory hierarchy:
+This module is the RadixAttention design (SGLang, Zheng et al., 2023)
+layered over the vLLM-style block pool, extended with an explicit
+memory hierarchy:
 
 - **Token-level radix tree** (``RadixPrefixCache``): nodes own RUNS of
   token ids (path compression) and the KV blocks whose spans those
@@ -320,14 +317,13 @@ class RadixPrefixCache:
 
     The tree REFERENCES blocks, it never owns refcounts: an HBM block
     the tree holds is marked ``tree_hold`` in the ``BlockPool`` so an
-    unpin parks it reclaimable-but-mapped (the radix analogue of the
-    digest LRU), and the pool's reclaim callback routes through the
-    engine's demote path back into :meth:`demote`.  Host locations are
-    ``HostTier`` keys.  All methods are host-side and synchronous with
-    the scheduler; the dtype-salting discipline of PR 5 carries over
-    structurally — the tree is per-engine and an engine has exactly
-    one at-rest cache dtype, so bf16 and int8 bytes can never alias
-    through it."""
+    unpin parks it reclaimable-but-mapped, and the pool's reclaim
+    callback routes through the engine's demote path back into
+    :meth:`demote`.  Host locations are ``HostTier`` keys.  All
+    methods are host-side and synchronous with the scheduler; cache
+    dtypes are kept apart structurally — the tree is per-engine and
+    an engine has exactly one at-rest cache dtype, so bf16 and int8
+    bytes can never alias through it."""
 
     def __init__(self, block_len: int, pool, tier: HostTier):
         self.block_len = int(block_len)
@@ -387,10 +383,9 @@ class RadixPrefixCache:
         """Register a prefilled prompt's tokens ``ids[:n_blocks*L]``
         and offer its computed blocks for positions ``[start_block,
         n_blocks)``.  First writer wins on an occupied HBM position
-        (the duplicate stays private to its request, exactly the
-        digest-map rule); a HOST twin is superseded by the freshly
-        computed HBM copy unless a queued request still pins its
-        bytes."""
+        (the duplicate stays private to its request); a HOST twin is
+        superseded by the freshly computed HBM copy unless a queued
+        request still pins its bytes."""
         L = self.block_len
         n_tok = n_blocks * L
         if n_tok == 0:

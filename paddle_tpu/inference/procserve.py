@@ -29,7 +29,7 @@ is everything on the OTHER side of the boundary:
   without launching — the ``MULTICHIP_r*`` pattern, so tier-1 can
   assert the launch surface without paying a process.
 - ``tiny_llama_engine`` — the importable engine factory children
-  build from (the bench/test geometry: seeded 1-layer llama), with a
+  build from (the tests' geometry: seeded 1-layer llama), with a
   deterministic in-child fault schedule (``exit_at_step`` puts a real
   ``os._exit`` on a chosen scheduler step — a REAL process death at a
   deterministic point, no parent-side kill races).
@@ -74,7 +74,7 @@ class EngineHost:
     ``"exit_at_step"`` arms ``FaultInjector.exit_at_step`` — the host
     consumes it with ``take_exit`` and dies with ``os._exit``: a real
     process death at a deterministic scheduler step, which is what
-    the slow lane and the bench's ``multiproc`` arm kill with."""
+    the slow test lane kills with."""
 
     def __init__(self, engine, *, label: str = "replica",
                  fault_spec: Optional[dict] = None):
@@ -557,7 +557,7 @@ def tiny_llama_engine(*, seed: int = 1234, num_slots: int = 2,
                       with_fault_injector: bool = False,
                       role: str = "both"):
     """Deterministic tiny-llama ``ServingEngine`` — the importable
-    factory ``EngineProcess`` children build from (and the bench's
+    factory ``EngineProcess`` children build from (and a test's
     in-process reference builds from, so socket-vs-reference token
     parity is a pure-transport comparison)."""
     import paddle_tpu as paddle

@@ -31,7 +31,7 @@ every parity test in this repo leans on):
   ``RadixPrefixCache`` match (``ServingEngine.prefix_match()``,
   read-only), so a conversation lands where its history is hottest
   and PR-8's hit tokens multiply across replicas instead of diluting.
-  ``affinity=False`` is pure round-robin — the bench A/B arm — and a
+  ``affinity=False`` is pure round-robin, and a
   single-replica router schedules byte-identically to the bare
   engine either way (the acceptance anchor).
 - **Workload policies**: ``submit(policy=)`` selects per-request
@@ -933,7 +933,7 @@ class Router:
 
         Each failover consumes one unit of the request's retry
         budget; exhaustion is the typed terminal state ``"failed"``.
-        With ``failover=False`` (the bench kill-switch arm) every
+        With ``failover=False`` every
         affected request goes terminal ``"failed"`` instead and the
         replica stays out of the routing set."""
         fault = _classify_fault(err)

@@ -40,10 +40,8 @@ prefill) design, restricted to what XLA's static shapes allow:
   Admission is cache-aware: within a scheduling class, queued
   requests whose matched prefix is HBM-resident admit first, then
   host-resident, then cold — a strict tie-break, so traces with no
-  shared prefixes schedule exactly as before.  The PR-3 block-aligned
-  chained-digest map (``prefix_cache_mode="digest"``: full-block
-  blake2b chains, HBM-only, reclaim forgets) remains as the bench A/B
-  arm.
+  shared prefixes schedule exactly as before.
+  ``prefix_cache_mode="none"`` turns the cache off.
 - **Chunked prefill**: prompts are computed ``chunk_len`` tokens at a
   time alongside the shared decode block — a long prompt no longer
   stalls in-flight decoding for its full prompt pass, and TTFT of
@@ -70,27 +68,18 @@ every position of a sequence's dense view is either masked (past
 ``lens``) or was written by exactly the math the dense engine ran at
 that position, and row-independence of the decode body is unchanged.
 
-``static_batching=True`` still degrades the SAME engine to gang
-scheduling (admit only into an empty pool) — the A/B baseline of
-``bench.py``'s ``llm_serving`` section; ``enable_prefix_cache=False``
-is the A/B arm for the shared-prefix trace.
-
 **Int8 KV cache** (``kv_cache_dtype="int8"``): decode at scale is
 KV-bandwidth-bound — the step streams the arena once per token — so
 the arenas can be stored QUANTIZED: int8 codes plus parallel
 per-entry per-kv-head f32 absmax scale arenas.  Every writer
 (chunked prefill, decode scatter, the speculative verify scatter)
 quantizes on append (``models.generation.*_q``); every reader
-dequantizes on read — the paged Pallas kernels DMA codes + scales
-and dequantize in VMEM right before the dot (route reasons
-``paged_int8_ok`` / ``paged_multi_int8_ok`` / ``int8_geom``), the
-XLA gather fallback reads ``paged_dequant_view`` so CPU tests
-exercise the same math.  HBM swept per token roughly halves
-(1 + 4/D bytes/lane vs 2) and twice the KV blocks fit the same
-arena budget; scheduling is unchanged — block tables, prefix
-digests (salted by cache dtype), trash-block discipline and
-spec-decode rollback all operate on block indices, never on cache
-bytes.
+dequantizes on read through ``paged_dequant_view``, on the chip and on
+the CPU alike (route reason ``int8_scale_lanes``: the cache has no
+Pallas kernel).  Twice the KV blocks fit the same arena budget;
+scheduling is unchanged — block tables, the radix tree, trash-block
+discipline and spec-decode rollback all operate on block indices,
+never on cache bytes.
 
 **Speculative decoding** is a per-request mode on top
 (``submit(spec_decode=K)``, greedy engines only): each scheduler
@@ -227,7 +216,6 @@ replica router of ``inference/router.py`` reads as its load signal.
 
 from __future__ import annotations
 
-import hashlib
 import logging
 import time
 import warnings
@@ -325,9 +313,9 @@ GOODPUT_REASONS = (
 # the dispatch-ahead pipeline's closed forced-sync vocabulary: every
 # iteration that must materialize device outputs EARLY — instead of
 # after the next dispatch was enqueued — charges exactly ONE of these
-# to serving.async.syncs{reason=}.  The vocabulary is closed so the
-# bench's async A/B arm (and dashboards) can assert that syncs happen
-# only for documented, semantically-required reasons:
+# to serving.async.syncs{reason=}.  The vocabulary is closed so tests
+# (and dashboards) can assert that syncs happen only for documented,
+# semantically-required reasons:
 ASYNC_SYNC_REASONS = (
     "eos",          # EOS detection must observe every emitted token
     "budget",       # a rider's token budget can exhaust inside the block
@@ -539,8 +527,7 @@ class _ServingInstruments:
             "serving.prefix.partial_hits",
             "admissions whose token-level radix match extended past "
             "the last mappable full block (the partial tail was "
-            "recomputed — the match lengths the block-aligned digest "
-            "cache could not even see)")
+            "recomputed)")
         self.prefix_host_hits = r.counter(
             "serving.prefix.host_hits",
             "admissions whose matched span included >= 1 host-RAM-"
@@ -626,8 +613,7 @@ class _ServingInstruments:
             "modeled KV-arena bytes read by decode/verify/prefill-chunk "
             "dispatches, at the paged kernels' block-DMA granularity "
             "(valid prefix rounded up to whole blocks; codes + scale "
-            "planes for the int8 cache) — the roofline denominator of "
-            "the serving bench's achieved_GBps")
+            "planes for the int8 cache) — a roofline denominator")
         self.kv_quant_dtype = r.gauge(
             "serving.kv.quant_dtype",
             "1 for each KV-cache at-rest dtype an engine in this "
@@ -640,8 +626,7 @@ class _ServingInstruments:
             "forward (non-quantized params at the compute dtype; "
             "quantized projections at their code width — int8 codes, "
             "packed int4 nibbles — plus f32 scale planes).  The "
-            "weight-side twin of serving.kv.bytes_swept and the "
-            "roofline denominator of the weight_quant bench arm")
+            "weight-side twin of serving.kv.bytes_swept")
         self.shard_groups = r.gauge(
             "serving.shard.groups",
             "1 per engine serving as a tensor-parallel shard group "
@@ -873,26 +858,6 @@ def _call_quiet(fn, *args):
         return fn(*args)
 
 
-def _block_digests(ids: np.ndarray, n: int, block_len: int,
-                   salt: bytes = b"ptpu-paged-kv") -> List[bytes]:
-    """Chained blake2b digests of the prompt's FULL blocks: block i's
-    digest covers tokens [0, (i+1)*block_len) through the chain, so two
-    blocks share a digest only when their whole attention context is
-    identical — the property that makes mapping a cached block into a
-    new sequence exact, not just likely.  ``salt`` seeds the chain; the
-    engine salts with the KV cache dtype so a bf16 block and an int8
-    block of the same tokens can never alias (their arena bytes
-    differ)."""
-    out: List[bytes] = []
-    h = salt
-    for i in range(n // block_len):
-        h = hashlib.blake2b(
-            h + ids[i * block_len:(i + 1) * block_len].tobytes(),
-            digest_size=16).digest()
-        out.append(h)
-    return out
-
-
 _INF = float("inf")
 
 
@@ -910,26 +875,21 @@ class BlockPool:
     Lifecycle of a block: ``alloc`` hands it out with refcount 1;
     ``pin``/``unpin`` move the refcount as prefix sharers map it in and
     requests retire; a block whose refcount drops to 0 returns to the
-    free list UNLESS it is published in the prefix map — then it parks
-    in an LRU, still mapped, and is reclaimed (unmapped) only when the
-    free list runs dry.  The extra arena row ``trash`` is not managed
-    here: it is the fixed write-masking target and never allocated.
+    free list UNLESS the radix tree of ``inference/prefixcache.py``
+    holds it (``tree_hold``/``tree_touch``) — then it parks in
+    ``_tree_lru``, still mapped, and is reclaimed only when the free
+    list runs dry.  The extra arena row ``trash`` is not managed here:
+    it is the fixed write-masking target and never allocated.
 
-    Purely host state — the device never sees refcounts or digests,
-    only the int32 block tables (the "no per-step sync of the arena"
-    contract).
+    Purely host state — the device never sees refcounts, only the
+    int32 block tables (the "no per-step sync of the arena" contract).
 
-    Two cache indices can park unpinned blocks reclaimable-but-mapped:
-    the PR-3 chained-digest map (``register``/``lookup``, kept as the
-    ``prefix_cache_mode="digest"`` A/B arm) and the radix tree of
-    ``inference/prefixcache.py`` (``tree_hold``/``tree_touch``; the
-    default mode).  A tree-held block whose refcount drops to 0 parks
-    in ``_tree_lru``; when ``alloc`` reclaims some, ``reclaim_cb``
-    (the engine's demote path) fires once with the reclaimed list
-    before alloc returns — the caller has not written the rows yet,
-    so their bytes can still be gathered to the host tier in one
-    batched dispatch.  ``audit_hooks`` let the owning cache fold its
-    own invariants into ``check()``."""
+    When ``alloc`` reclaims tree-held blocks, ``reclaim_cb`` (the
+    engine's demote path) fires once with the reclaimed list before
+    alloc returns — the caller has not written the rows yet, so their
+    bytes can still be gathered to the host tier in one batched
+    dispatch.  ``audit_hooks`` let the owning cache fold its own
+    invariants into ``check()``."""
 
     def __init__(self, num_blocks: int, block_len: int):
         self.num_blocks = int(num_blocks)
@@ -937,9 +897,6 @@ class BlockPool:
         self.trash = self.num_blocks           # extra arena row index
         self._free = list(range(self.num_blocks - 1, -1, -1))
         self._ref = [0] * self.num_blocks
-        self._digest_of: List[Optional[bytes]] = [None] * self.num_blocks
-        self._by_digest = {}                   # digest -> block id
-        self._lru: OrderedDict = OrderedDict()  # digest -> block, ref==0
         self._tree_ref = set()                 # radix-tree-held blocks
         self._tree_lru: OrderedDict = OrderedDict()  # block -> True
         self.reclaim_cb = None                 # fires on tree-LRU reclaim
@@ -947,7 +904,7 @@ class BlockPool:
 
     def available(self) -> int:
         """Blocks allocatable right now (free + reclaimable cached)."""
-        return len(self._free) + len(self._lru) + len(self._tree_lru)
+        return len(self._free) + len(self._tree_lru)
 
     def in_use(self) -> int:
         """Blocks pinned by live or queued requests (refcount > 0)."""
@@ -955,16 +912,10 @@ class BlockPool:
 
     def cached(self) -> int:
         """Unpinned blocks kept mapped for future prefix hits."""
-        return len(self._lru) + len(self._tree_lru)
-
-    def lookup(self, digest: bytes) -> Optional[int]:
-        return self._by_digest.get(digest)
+        return len(self._tree_lru)
 
     def pin(self, block: int):
         if self._ref[block] == 0:
-            dg = self._digest_of[block]
-            if dg is not None:
-                self._lru.pop(dg, None)
             self._tree_lru.pop(block, None)
         self._ref[block] += 1
 
@@ -974,13 +925,7 @@ class BlockPool:
                 f"block {block} unpinned below refcount 0 — double free")
         self._ref[block] -= 1
         if self._ref[block] == 0:
-            # a block's digest is set/cleared atomically with its
-            # _by_digest entry (register never overwrites, alloc clears
-            # both), so digest-set means published-and-mapped
-            dg = self._digest_of[block]
-            if dg is not None:
-                self._lru[dg] = block          # reclaimable, still mapped
-            elif block in self._tree_ref:
+            if block in self._tree_ref:
                 self._tree_lru[block] = True   # reclaimable, still mapped
             else:
                 self._free.append(block)
@@ -1003,23 +948,12 @@ class BlockPool:
         if block in self._tree_lru:
             self._tree_lru.move_to_end(block)
 
-    def register(self, block: int, digest: bytes):
-        """Publish a fully-written prompt block for future prefix hits.
-        First writer wins: a concurrent duplicate computation keeps its
-        private copy unpublished (it returns to the plain free list on
-        unpin)."""
-        if digest in self._by_digest:
-            return
-        self._by_digest[digest] = block
-        self._digest_of[block] = digest
-
     def alloc(self, n: int) -> Optional[List[int]]:
         """``n`` blocks with refcount 1 each, reclaiming the oldest
-        refcount-0 cached blocks when the free list runs dry; None
-        when the pool cannot serve ``n``.  Digest-cached blocks unmap
-        (the PR-3 forget semantics); tree-held blocks fire
-        ``reclaim_cb`` first so the radix cache can demote their bytes
-        to the host tier before the row is overwritten."""
+        refcount-0 tree-held blocks when the free list runs dry; None
+        when the pool cannot serve ``n``.  Reclaimed blocks fire
+        ``reclaim_cb`` so the radix cache can demote their bytes to the
+        host tier before the row is overwritten."""
         if n > self.available():
             return None
         out = []
@@ -1027,14 +961,10 @@ class BlockPool:
         for _ in range(n):
             if self._free:
                 b = self._free.pop()
-            elif self._tree_lru:
+            else:
                 b, _ = self._tree_lru.popitem(last=False)
                 self._tree_ref.discard(b)
                 reclaimed.append(b)
-            else:
-                dg, b = self._lru.popitem(last=False)
-                del self._by_digest[dg]
-                self._digest_of[b] = None
             self._ref[b] = 1
             out.append(b)
         if reclaimed and self.reclaim_cb is not None:
@@ -1053,33 +983,27 @@ class BlockPool:
         invariants that define "no leak, no double-free, no refcount
         drift":
 
-        - conservation: free + pinned (ref > 0) + cached (digest LRU +
-          tree LRU) covers every block exactly once;
+        - conservation: free + pinned (ref > 0) + cached (tree LRU)
+          covers every block exactly once;
         - the free list has no duplicates and no pinned/cached member;
-        - free blocks are unmapped (no digest — alloc clears it) and
-          never tree-referenced;
-        - every LRU member has refcount 0 and a digest mapping back to
-          itself;
-        - ``_by_digest`` and ``_digest_of`` are a bijection;
-        - tree-referenced blocks are never also digest-mapped, and
-          every refcount-0 tree-referenced block sits in the tree LRU
-          (no unreclaimable limbo);
+        - free blocks are never tree-referenced;
+        - every refcount-0 tree-referenced block sits in the tree LRU
+          (no unreclaimable limbo), and every tree-LRU member is
+          tree-referenced;
         - no negative refcount (``unpin`` raises before one can form,
           so a violation here means state was corrupted directly);
         - every registered ``audit_hooks`` entry (the radix tree's
-          node <-> block-span bijection and host-tier consistency in
-          radix-mode engines) returns no errors."""
+          node <-> block-span bijection and host-tier consistency)
+          returns no errors."""
         errs = []
         free_set = set(self._free)
         if len(free_set) != len(self._free):
             errs.append(f"free list holds duplicates: {self._free}")
-        lru_set = set(self._lru.values())
         tlru_set = set(self._tree_lru)
         pinned = 0
         for b in range(self.num_blocks):
             ref = self._ref[b]
-            dg = self._digest_of[b]
-            cached_here = b in lru_set or b in tlru_set
+            cached_here = b in tlru_set
             if ref < 0:
                 errs.append(f"block {b}: negative refcount {ref}")
             if ref > 0:
@@ -1091,42 +1015,21 @@ class BlockPool:
             elif not (b in free_set or cached_here):
                 errs.append(f"block {b}: refcount 0 but neither free "
                             f"nor cached — leaked")
-            if b in free_set and (b in lru_set or b in tlru_set):
+            if b in free_set and cached_here:
                 errs.append(f"block {b}: both free and LRU-cached")
-            if b in free_set and dg is not None:
-                errs.append(f"block {b}: free but still digest-mapped")
             if b in free_set and b in self._tree_ref:
                 errs.append(f"block {b}: free but tree-referenced")
-            if b in self._tree_ref and dg is not None:
-                errs.append(f"block {b}: both tree-referenced and "
-                            f"digest-mapped")
             if b in self._tree_ref and ref == 0 and b not in tlru_set:
                 errs.append(f"block {b}: tree-referenced at refcount 0 "
                             f"but not in the tree LRU — unreclaimable")
             if b in tlru_set and b not in self._tree_ref:
                 errs.append(f"block {b}: in the tree LRU but not "
                             f"tree-referenced")
-            if dg is not None and self._by_digest.get(dg) != b:
-                errs.append(
-                    f"block {b}: digest points at block "
-                    f"{self._by_digest.get(dg)} in _by_digest")
-        for dg, b in self._by_digest.items():
-            if self._digest_of[b] != dg:
-                errs.append(f"_by_digest maps {dg.hex()} -> {b} but "
-                            f"block {b} carries digest "
-                            f"{self._digest_of[b] and self._digest_of[b].hex()}")
-        for dg, b in self._lru.items():
-            if self._ref[b] != 0:
-                errs.append(f"LRU block {b}: refcount {self._ref[b]}")
-            if self._digest_of[b] != dg:
-                errs.append(f"LRU digest {dg.hex()} maps block {b} "
-                            f"whose digest differs")
-        if len(self._free) + pinned + len(self._lru) \
-                + len(self._tree_lru) != self.num_blocks:
+        if len(self._free) + pinned + len(self._tree_lru) \
+                != self.num_blocks:
             errs.append(
                 f"conservation: free({len(self._free)}) + "
-                f"pinned({pinned}) + cached({len(self._lru)} digest + "
-                f"{len(self._tree_lru)} tree) != "
+                f"pinned({pinned}) + cached({len(self._tree_lru)}) != "
                 f"num_blocks({self.num_blocks})")
         for hook in self.audit_hooks:
             errs.extend(hook())
@@ -1286,7 +1189,6 @@ class Request:
     gp_recompute_to: int = 0
     n_emitted: int = 0                 # tokens at finish, before padding
     blocks: List[int] = field(default_factory=list)    # full block map
-    digests: List[bytes] = field(default_factory=list)
     registered: int = 0                # blocks published so far
     chunk_ids: Optional[np.ndarray] = None  # prompt padded to chunk grid
 
@@ -1405,14 +1307,14 @@ class ServingEngine:
     def __init__(self, model, *, num_slots, prompt_len,
                  max_cache_len=None, steps_per_call=1,
                  block_len=16, num_blocks=None, chunk_len=None,
-                 enable_prefix_cache=True, prefix_cache_mode=None,
+                 prefix_cache_mode="radix",
                  host_cache_blocks=None, drafter=None,
                  eos_token_id=None, pad_token_id=0,
                  do_sample=False, temperature=1.0, top_k=0, top_p=1.0,
                  compute_dtype="bfloat16", cache_dtype=None,
                  kv_cache_dtype=None, weight_dtype=None,
-                 seed=0, static_batching=False, clock=time.perf_counter,
-                 registry=None, max_queue=None, enable_preemption=True,
+                 seed=0, clock=time.perf_counter,
+                 registry=None, max_queue=None,
                  fault_injector=None, flight_recorder=None,
                  async_dispatch=True, async_depth=1,
                  adapter_store=None, tenant_weights=None, mesh=None,
@@ -1432,26 +1334,18 @@ class ServingEngine:
             raise ValueError(
                 f"max_queue must be >= 1 (or None = unbounded), got "
                 f"{max_queue}")
-        self.enable_preemption = bool(enable_preemption)
         self._fault = fault_injector
         self.prompt_len = int(prompt_len)
         self.max_cache_len = int(max_cache_len or (prompt_len + 256))
         self.steps_per_call = int(steps_per_call)
         self.block_len = int(block_len)
-        self.static_batching = bool(static_batching)
         # prefix-cache mode: "radix" (the default — token-level radix
-        # tree with host-RAM tiering), "digest" (the PR-3 block-
-        # aligned chained-digest map, kept as the bench A/B arm) or
-        # "none".  enable_prefix_cache=False is the legacy spelling of
-        # "none"; an explicit prefix_cache_mode wins over the bool.
-        if prefix_cache_mode is None:
-            mode = "radix" if enable_prefix_cache else "none"
-        else:
-            mode = str(prefix_cache_mode)
-            if mode not in ("radix", "digest", "none"):
-                raise ValueError(
-                    f"prefix_cache_mode must be 'radix', 'digest' or "
-                    f"'none', got {prefix_cache_mode!r}")
+        # tree with host-RAM tiering) or "none"
+        mode = str(prefix_cache_mode)
+        if mode not in ("radix", "none"):
+            raise ValueError(
+                f"prefix_cache_mode must be 'radix' or 'none', got "
+                f"{prefix_cache_mode!r}")
         # a model that keeps per-slot state beside its blocks
         # (slot_state_spec: a convolution tail, a recurrent state) can
         # take nothing that shares, moves or rewinds blocks without the
@@ -1484,7 +1378,6 @@ class ServingEngine:
                     int(mesh.shape["model"]) > 1:
                 raise SlotStateError(model, "a mesh with mp > 1")
         self.prefix_cache_mode = mode
-        self.enable_prefix_cache = mode != "none"
         if self.num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         if self.steps_per_call < 1:
@@ -1678,12 +1571,6 @@ class ServingEngine:
             row_bytes += 2 * hkv * 4       # f32 scale planes
         self._kv_row_bytes = row_bytes * n_layers
         self._pool = BlockPool(self.num_blocks, self.block_len)
-        # prefix digests are salted with the cache dtype: a bf16 block
-        # and an int8 block of the same tokens hold different bytes, so
-        # they must never alias in any (present or future) shared
-        # digest namespace
-        self._digest_salt = ("ptpu-paged-kv/"
-                             + self.kv_cache_dtype).encode()
         # ONE host-RAM block store for both host-tier uses: preemption
         # swap-outs (reason="preempt", pinned until resume) and prefix-
         # cache demotions (reason="cache", LRU-evicted under the
@@ -1860,8 +1747,7 @@ class ServingEngine:
         # host-seconds attribution; the _lazy_stacks list tracks
         # demote gathers enqueued during plan and reconciled at the
         # next harvest point.
-        # async_dispatch=False is the exact lockstep kill-switch — the
-        # A/B arm of the bench's ``async`` sub-object.
+        # async_dispatch=False is the exact lockstep kill-switch.
         # async_depth=1 (the default) keeps PR 10's double-buffered
         # pipeline AND its scheduling-identity contract (every
         # EOS-configured iteration still syncs, so dispatch counts
@@ -1922,9 +1808,7 @@ class ServingEngine:
         measured, and PARTICIPATING rows only: vacant/frozen rows in
         the same dispatch do DMA their (trash-routed) frontier, but
         that waste traffic is excluded so the counter reads as useful
-        KV bytes — the conservative roofline basis the serving bench's
-        achieved_GBps uses (both A/B arms share the model, so ratios
-        are unaffected)."""
+        KV bytes, a conservative roofline basis."""
         rows = sum(min(int(ix) // self.block_len + 1, self.max_blocks)
                    * self.block_len
                    for ix in last_indices)
@@ -1937,8 +1821,8 @@ class ServingEngine:
         params at the compute dtype, quantized projections at their
         code+scale width (``_weight_sweep_bytes``).  Modeled like
         ``_count_kv_sweep``, and charged for EVERY engine (full-
-        precision included) so the weight_quant bench arms compare the
-        same model on the same trace with strictly ordered bytes."""
+        precision included) so a quantized and a full-precision engine
+        on the same trace read strictly ordered bytes."""
         self._m.weights_bytes_swept.inc(
             int(forwards) * self._weight_sweep_bytes)
 
@@ -1956,8 +1840,7 @@ class ServingEngine:
         over PARTICIPATING rows only, the ``_count_kv_sweep``
         convention: vacant/frozen rows in the same compiled dispatch
         do burn FLOPs, but counting them would make goodput a function
-        of slot-pool geometry instead of scheduling quality (both A/B
-        bench arms share the convention, so ratios are unaffected).
+        of slot-pool geometry instead of scheduling quality.
         ``tenant`` attributes the whole call to one tenant (call sites
         split multi-tenant dispatches per rider), so conservation
         holds per tenant label too."""
@@ -2631,21 +2514,6 @@ class ServingEngine:
                 self._probe_radix(req)
                 if req.matched:
                     self._update_block_gauges()
-            elif self.enable_prefix_cache:
-                req.digests = _block_digests(padded, n, self.block_len,
-                                             salt=self._digest_salt)
-                # match at most (n-1)//block_len blocks: the block
-                # holding the prompt's LAST token is always recomputed —
-                # sampling the first output token needs its hidden
-                # state, which the cache does not carry
-                for dg in req.digests[:(n - 1) // self.block_len]:
-                    b = self._pool.lookup(dg)
-                    if b is None:
-                        break
-                    self._pool.pin(b)
-                    req.matched.append(b)
-                if req.matched:
-                    self._update_block_gauges()
             if sp is not None and sp.mask_processor is not None:
                 # host state-machine init + width check, AFTER the
                 # prefix probe: a raise here (bad table width, a
@@ -2879,9 +2747,6 @@ class ServingEngine:
         req.chunk_ids = np.full((self.prompt_len + self.chunk_len,),
                                 self.cfg.pad_token_id, np.int32)
         req.chunk_ids[:self.prompt_len] = req.prompt
-        if self.prefix_cache_mode == "digest":
-            req.digests = _block_digests(req.prompt, n, self.block_len,
-                                         salt=self._digest_salt)
         if spec_k is not None:
             if self._drafter is None:
                 self._drafter = NGramDrafter()
@@ -2934,15 +2799,6 @@ class ServingEngine:
             try:
                 if self._radix is not None:
                     self._probe_radix(req)
-                    if req.matched:
-                        self._update_block_gauges()
-                elif self.enable_prefix_cache:
-                    for dg in req.digests[:(n - 1) // self.block_len]:
-                        b = self._pool.lookup(dg)
-                        if b is None:
-                            break
-                        self._pool.pin(b)
-                        req.matched.append(b)
                     if req.matched:
                         self._update_block_gauges()
                 self._next_id += 1
@@ -3408,8 +3264,7 @@ class ServingEngine:
                 not any(r is not None for r in self._slots):
             self._release_queue_pins()
             fresh = self._alloc(rec.n_blocks)
-        if fresh is None and self.enable_preemption and \
-                self._preempt_for(req, rec.n_blocks, out):
+        if fresh is None and self._preempt_for(req, rec.n_blocks, out):
             fresh = self._alloc(rec.n_blocks)
         if fresh is None:
             if acquired:
@@ -3684,13 +3539,8 @@ class ServingEngine:
         then PREEMPTION of strictly-worse victims are tried before
         giving up until blocks retire.  Admission is head-of-line:
         a stuck best candidate is never skipped for a worse one that
-        would fit (no priority inversion by backfill).  Gang mode
-        (``static_batching``) only admits into an EMPTY pool — the
-        static-batch baseline scheduler."""
+        would fit (no priority inversion by backfill)."""
         self._sweep_timeouts(now, out)
-        if self.static_batching and \
-                any(r is not None for r in self._slots):
-            return
         # candidate order: _sched_key (priority, then EDF) extended by
         # the FAIR-SHARE term and a residency rank — inside a class,
         # the least-normalized-served tenant admits first (deficit-
@@ -3701,8 +3551,8 @@ class ServingEngine:
         # resident, then host-resident, then cold.  The rank is a
         # STRICT tie-break inside a (class, tenant-deficit) bucket and
         # the sort is stable over submission order, so a trace with no
-        # shared prefixes (or a non-radix engine, where the rank is
-        # constant) keeps FIFO within its bucket.  Ranks are probed
+        # shared prefixes (or an engine without the prefix cache, where
+        # the rank is constant) keeps FIFO within its bucket.  Ranks are probed
         # once per candidate per _admit CALL (memoized — not once per
         # sort comparison or per freed slot): the tree only improves
         # mid-call (promotion/registration), and a call-stale rank
@@ -3769,18 +3619,6 @@ class ServingEngine:
                 # re-pin before sizing the allocation
                 self._reprobe_radix(req)
                 n_hbm = len(req.matched)
-            elif self.enable_prefix_cache:
-                # blocks computed between submit and now may extend the
-                # match (e.g. the prefix holder finished its prefill
-                # while this request queued)
-                for dg in req.digests[len(req.matched):
-                                      (req.seq_len - 1) // self.block_len]:
-                    b = self._pool.lookup(dg)
-                    if b is None:
-                        break
-                    self._pool.pin(b)
-                    req.matched.append(b)
-                n_hbm = len(req.matched)
             else:
                 n_hbm = 0
             # adapter residency before block sizing: the gathered
@@ -3807,7 +3645,7 @@ class ServingEngine:
                 self._release_queue_pins()
                 n_hbm = 0
                 fresh = self._alloc(total)
-            if fresh is None and self.enable_preemption and \
+            if fresh is None and \
                     self._preempt_for(req, total - n_hbm, out):
                 fresh = self._alloc(total - n_hbm)
             if fresh is None:
@@ -3815,7 +3653,7 @@ class ServingEngine:
                     self._adapters.release(req.adapter)
                 break                     # pool drains as requests retire
             matchable = ((req.seq_len - 1) // self.block_len
-                         if self.enable_prefix_cache else 0)
+                         if self._radix is not None else 0)
             if self._radix is not None:
                 # host-resident span entries swap their exact at-rest
                 # bytes back into the leading fresh blocks (one batched
@@ -3831,7 +3669,6 @@ class ServingEngine:
                     raise
                 req.blocks = mapped + fresh
                 hit_tokens = len(mapped) * self.block_len
-                self._m.prefix_hit_tokens.inc(hit_tokens)
                 partial = req.rmatch_tokens > hit_tokens
                 if partial:
                     self._m.prefix_partial_hits.inc()
@@ -3853,16 +3690,9 @@ class ServingEngine:
                 req.matched = []
                 req.rspan = []
             else:
-                mapped = req.matched
-                req.blocks = req.matched + fresh
-                self._m.prefix_hit_tokens.inc(
-                    len(mapped) * self.block_len)
-                if mapped:
-                    self._fr.emit(
-                        "prefix_hit", req.request_id, self._step_idx,
-                        tier="hbm", blocks=len(mapped),
-                        tokens=len(mapped) * self.block_len,
-                        partial=0)
+                mapped, hit_tokens = [], 0
+                req.blocks = fresh
+            self._m.prefix_hit_tokens.inc(hit_tokens)
             self._queue.remove(req)
             if acquired:
                 req.adapter_slot = self._adapters.slot_of(req.adapter)
@@ -4109,12 +3939,6 @@ class ServingEngine:
                     self._radix.insert(req.prompt, req.blocks, full,
                                        start_block=req.registered)
                     req.registered = full
-            elif self.enable_prefix_cache:
-                full = min(req.pf_pos, req.seq_len) // self.block_len
-                while req.registered < min(full, len(req.digests)):
-                    i = req.registered
-                    self._pool.register(req.blocks[i], req.digests[i])
-                    req.registered = i + 1
             if req.pf_pos < req.seq_len:
                 return                        # more chunks to go
             # final chunk: tok0 is the request's first generated token

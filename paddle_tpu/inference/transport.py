@@ -58,8 +58,8 @@ the request resumes or finishes.
 
 Sequence numbers are deterministic (a per-direction counter starting
 at 0, contiguity-checked at both ends), so two runs of one trace
-produce identical frame sequences — the bench's ``multiproc`` arm
-gates on exactly that.
+produce identical frame sequences (``tests/test_transport.py``
+asserts exactly that).
 """
 
 from __future__ import annotations
@@ -144,7 +144,7 @@ class TransportDeadError(ReplicaKilledError):
 def _canon_payload(obj) -> bytes:
     """Canonical JSON bytes: sorted keys, no whitespace — two encodes
     of one payload are byte-identical (the frame-sequence determinism
-    the bench gates on)."""
+    the tests assert)."""
     if obj is None:
         return b""
     return json.dumps(obj, sort_keys=True,
@@ -305,8 +305,7 @@ class _TransportInstruments:
             "serving.transport.frames",
             "wire frames moved through a replica transport, by frame "
             "kind (requests at send, replies at receive) — the frame-"
-            "sequence determinism surface the multiproc bench arm "
-            "gates on", labels=("kind",))
+            "sequence determinism surface", labels=("kind",))
         self.bytes_out = r.counter(
             "serving.transport.bytes_out",
             "encoded frame bytes sent to replica engine hosts "

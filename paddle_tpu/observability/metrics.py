@@ -457,8 +457,7 @@ def diff_snapshots(before: dict, after: dict) -> dict:
     whose activity stayed below an earlier window's peak reports the
     earlier peak (tracking per-window peaks would need stateful
     watermark resets, which snapshots deliberately avoid).  The shape
-    mirrors ``snapshot()`` so the same renderers work on deltas — this
-    is what ``bench.py`` embeds per section."""
+    mirrors ``snapshot()`` so the same renderers work on deltas."""
     out = {}
     for name, snap in after.items():
         prev = before.get(name)

@@ -37,17 +37,16 @@ CONTIGUOUS in lanes (head h at lane offset h*D).  Then:
 
 Two bodies live here.  The STAGED one lands a row's whole valid prefix
 in VMEM and then computes on all of it: the dense ``_kernel``
-(``LLMPredictor``'s contiguous cache) and the two int8 paged kernels
-(``_paged_kernel_q``, ``_paged_multi_kernel_q``); its landing buffers
-grow with the cache (``_VMEM_BUDGET``, reason ``vmem_budget``) and the
-paged pair holds a DMA semaphore a table block (``_SFLAG_BYTES``,
-reason ``paged_dma_sems``).  The STREAMING one
-(``_paged_stream_kernel``: the float paged cache, decode and the
+(``LLMPredictor``'s contiguous cache); its landing buffers grow with
+the cache (``_VMEM_BUDGET``, reason ``vmem_budget``).  The STREAMING
+one (``_paged_stream_kernel``: the float paged cache, decode and the
 K-wide verify alike) walks the prefix in double-buffered groups of
 blocks with an online softmax and prefetches across slots; what it
-stages does not grow with the table, and neither limit binds it
+stages does not grow with the table, and the budget does not bind it
 (PR 29: 80% of the HBM roofline at the serving cell's geometry where
-the staged body read 44%, kernel alone on the v5e).
+a staged body read 44%, kernel alone on the v5e).  The int8 paged
+cache has no kernel: it reads through ``paged_dequant_view`` on every
+platform (reason ``int8_scale_lanes``).
 """
 
 from __future__ import annotations
@@ -73,12 +72,11 @@ from ._common import (on_tpu, pallas_enabled, partitioned_scope,
 # program traced with its kv-head shard geometry accepted
 # (``sharded_ok``) or fell back to replicated arenas (``mesh_geom``).
 DECODE_ROUTE_REASONS = (
-    "ok", "paged_ok", "paged_int8_ok", "paged_multi_ok",
-    "paged_multi_int8_ok", "sharded_ok", "mesh_geom",
+    "ok", "paged_ok", "paged_multi_ok", "sharded_ok", "mesh_geom",
     "flag_disabled", "pallas_unavailable", "gspmd_partitioned",
     "unpacked_cache", "dtype_mismatch", "scales_mismatch", "geometry",
-    "int8_geom", "int8_scale_lanes", "group_too_wide", "seq_align",
-    "paged_block_len", "paged_dma_sems", "query_rows", "vmem_budget",
+    "int8_scale_lanes", "group_too_wide", "seq_align",
+    "paged_block_len", "query_rows", "vmem_budget",
 )
 
 
@@ -144,18 +142,17 @@ _NEG_INF = -1e30
 _GPAD = 8                      # q rows per head block (sublane unit)
 # What the gate admits is what the compiler is told: ``_VMEM_BUDGET``
 # bounds the buffers a kernel STAGES (K/V landing buffers or stages,
-# scale planes, the logits scratch), and every decode ``pallas_call``
-# sets Mosaic's scoped-VMEM limit to ``_VMEM_LIMIT`` so that the
-# softmax's temporaries — a handful of logits-sized values the estimate
-# does not itemise — have room on top of a full budget whatever the
-# compiler's default is.  The budget BINDS the kernels that stage a
-# whole context: the dense ``_kernel`` and the int8 ``_q`` kernels (on
-# the v5e the bf16 ``_kernel`` compiles and agrees at the budget's
-# edge, 5376 staged rows of 512 lanes, with this limit; 5120 rows also
-# fit the default limit: chip run, PR 22).  The streaming float kernel
-# (``_paged_stream_kernel``) stages two stages of ``_STAGE_BYTES`` an
-# operand whatever the table's width, so it passes the same estimate
-# at any context.
+# the logits scratch), and every decode ``pallas_call`` sets Mosaic's
+# scoped-VMEM limit to ``_VMEM_LIMIT`` so that the softmax's
+# temporaries — a handful of logits-sized values the estimate does not
+# itemise — have room on top of a full budget whatever the compiler's
+# default is.  The budget BINDS the one kernel that stages a whole
+# context, the dense ``_kernel`` (on the v5e the bf16 ``_kernel``
+# compiles and agrees at the budget's edge, 5376 staged rows of 512
+# lanes, with this limit; 5120 rows also fit the default limit: chip
+# run, PR 22).  The streaming paged kernel (``_paged_stream_kernel``)
+# stages two stages of ``_STAGE_BYTES`` an operand whatever the
+# table's width, so it passes the same estimate at any context.
 _VMEM_BUDGET = 12 << 20
 _VMEM_LIMIT = 32 << 20
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
@@ -181,30 +178,13 @@ def _stage_blocks(arena, tables):
     return max(1, min(_STAGE_BYTES // blk_bytes, tables.shape[1]))
 
 
-# The STAGED paged kernels (the int8 ``_q`` pair) hold one DMA
-# semaphore per table block per staged operand, and semaphores live in
-# the core's 2 KiB "sflag" memory, 4 bytes each, next to 292 bytes the
-# program keeps for itself ("Ran out of memory in memory space sflag.
-# Used 2.1K of 2.0K sflag", v5e / jax 0.9.0: two operands compile at
-# 208 blocks and fail at 224).  The streaming float kernel holds one
-# per stage and operand, four in all, and is not bound by this.
-_SFLAG_BYTES = 2048
-_SFLAG_RESERVED = 292
-
-
-def _paged_table_rule(arena, tables, kv_scales):
-    """(ok, reason) for what a paged kernel needs of the block table:
-    ``paged_block_len`` — the staged unit is a whole block, so
-    ``block_len`` must sit on the 8-row sublane tile (bf16 and f32
-    arenas compile at 8, 16 and 32 on the v5e); ``paged_dma_sems`` —
-    for the staged int8 kernels (``kv_scales`` given: four operands, a
-    semaphore a block each) the table is no wider than the semaphore
-    memory allows, 109 blocks."""
+def _paged_table_rule(arena):
+    """(ok, reason) for what the paged kernel needs of a block: the
+    staged unit is a whole block, so ``block_len`` must sit on the
+    8-row sublane tile (``paged_block_len``; bf16 and f32 arenas
+    compile at 8, 16 and 32 on the v5e)."""
     if arena.shape[1] % 8:
         return False, "paged_block_len"
-    if (kv_scales is not None
-            and 4 * tables.shape[1] * 4 + _SFLAG_RESERVED > _SFLAG_BYTES):
-        return False, "paged_dma_sems"
     return True, None
 
 
@@ -215,14 +195,11 @@ def _stream_rows(hkv, cq, g):
     return -(-(hkv * cq * g) // _GPAD) * _GPAD
 
 
-def _paged_staging(hkv, cq, g, arena, tables, kv_scales):
-    """(s, acc_rows) of ``_gate_shared``'s estimate for a paged kernel:
-    the rows of K (and of V) it holds in VMEM and the rows of its
-    full-width accumulator — both stages and ``_stream_rows`` for the
-    streaming float kernel, the whole table's width and no accumulator
-    for the staged int8 kernels."""
-    if kv_scales is not None:
-        return tables.shape[1] * arena.shape[1], 0
+def _paged_staging(hkv, cq, g, arena, tables):
+    """(s, acc_rows) of ``_gate_shared``'s estimate for the streaming
+    paged kernel: the rows of K (and of V) it holds in VMEM, both
+    stages, and the rows of its full-width accumulator
+    (``_stream_rows``)."""
     return (2 * _stage_blocks(arena, tables) * arena.shape[1],
             _stream_rows(hkv, cq, g))
 
@@ -278,11 +255,10 @@ def paged_dequant_view(arena, scales, tables, out_dtype):
     """Dense DEQUANTIZED per-sequence view of an int8 paged arena: the
     gather of ``paged_gather_view`` with each entry's per-kv-head
     absmax scale multiplied back in, cast to the compute dtype.  This
-    is the XLA fallback's read path for the quantized cache — one
-    definition of the dequant math shared by the gather fallback of
+    is the quantized cache's ONE read path, on the chip and on the
+    CPU alike — one definition of the dequant math shared by
     ``decode_attention_paged``, ``decode_attention_paged_multi`` and
-    ``paged_prefix_attention``, so CPU tier-1 tests exercise exactly
-    the arithmetic the in-kernel dequant mirrors."""
+    ``paged_prefix_attention``."""
     if jnp.dtype(arena.dtype) != jnp.dtype(jnp.int8):
         raise TypeError(
             "paged_dequant_view: kv_scales supplied for a "
@@ -306,42 +282,24 @@ def decode_attn_sig(b, hkv, g, s, d, dtype):
     return f"{b}x{hkv}x{g}x{s}x{d}/{np.dtype(dtype)}"
 
 
-_MIXED_DTYPE_ALLOWLIST = frozenset({
-    # (q dtype, cache dtype) pairs with a TESTED in-kernel conversion,
-    # beyond exact dtype equality: only the int8 quantized cache read
-    # by a float compute dtype, and only when the caller supplies the
-    # parallel scale arenas (the ``has_scales`` gate argument) — the
-    # kernels dequantize codes * scales to the compute dtype right
-    # before each dot.  Any other mix stays on the XLA fallback, which
-    # casts explicitly (fp32 logits, V cast at the PV dot).
-    (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.int8)),
-    (jnp.dtype(jnp.float32), jnp.dtype(jnp.int8)),
-})
-
-
 def _gate_shared(q4, cache, s, align_ok, align_reason, q_rows=_GPAD,
                  has_scales=False, acc_rows=0):
     """The gate checks common to the dense and paged dispatchers —
     ONE implementation so the two routes cannot silently diverge.
     ``s`` is the count of rows staged in VMEM (the whole cache for the
-    dense and the int8 paged kernels, ``_paged_staging`` for the
-    streaming one); ``align_ok``/``align_reason``
-    inject the path-specific sublane-tiling rule at its position in
-    the check order; ``q_rows`` is the per-head q-row block the caller
-    stages (``_GPAD`` for the single-token kernels, a multiple of it
-    for the K-wide verify kernel) and scales the logits-scratch VMEM
-    estimate; ``acc_rows`` is the rows of the streaming kernel's
-    full-width q block and float32 accumulator (0 for the staged
-    kernels, which have neither); ``has_scales`` says the caller
-    carries the int8 cache's
-    scale arenas — the requirement for the mixed (float q, int8 cache)
-    pairs of ``_MIXED_DTYPE_ALLOWLIST`` (every other q/cache dtype mix
-    rejects as ``dtype_mismatch``; an int8 pairing that fails the
-    packed-geometry check rejects as ``int8_geom`` so the route
-    counter separates it from bf16 ``geometry``, and one whose scale
-    planes Mosaic cannot DMA rejects as ``int8_scale_lanes``).  Returns
-    (use_pallas, reason-or-None); the caller maps None to its accept
-    reason."""
+    dense kernel, ``_paged_staging`` for the streaming one);
+    ``align_ok``/``align_reason`` inject the path-specific
+    sublane-tiling rule at its position in the check order; ``q_rows``
+    is the per-head q-row block the caller stages (``_GPAD`` for the
+    single-token kernels, a multiple of it for the K-wide verify) and
+    scales the logits-scratch VMEM estimate; ``acc_rows`` is the rows
+    of the streaming kernel's full-width q block and float32
+    accumulator (0 for the dense kernel, which has neither);
+    ``has_scales`` says the caller carries the int8 cache's scale
+    arenas: an int8 cache with them rejects as ``int8_scale_lanes``,
+    without them, like every other q/cache dtype mix, as
+    ``dtype_mismatch``.  Returns (use_pallas, reason-or-None); the
+    caller maps None to its accept reason."""
     from ...core.flags import flag
     if not flag("use_decode_attention_kernel"):
         return False, "flag_disabled"
@@ -351,32 +309,26 @@ def _gate_shared(q4, cache, s, align_ok, align_reason, q_rows=_GPAD,
         return False, "pallas_unavailable"
     if cache.ndim != 3:
         return False, "unpacked_cache"
-    int8_pair = False
     if jnp.dtype(q4.dtype) != jnp.dtype(cache.dtype):
-        pair = (jnp.dtype(q4.dtype), jnp.dtype(cache.dtype))
-        if not (has_scales and pair in _MIXED_DTYPE_ALLOWLIST):
-            return False, "dtype_mismatch"
-        int8_pair = True
-    elif has_scales:
-        # equal q/cache dtypes with scale arenas riding along: the
-        # int8-kernel selection downstream keys on scale presence, so
-        # letting a FLOAT cache through here would dequant-multiply
-        # real K/V in the _q kernels — reject instead of routing a
-        # kernel whose operand contract the caller violates
+        if has_scales and jnp.dtype(cache.dtype) == jnp.dtype(jnp.int8):
+            # a kernel would stage the [NB+1, L, H_kv] f32 scale planes
+            # by DMA, one [L, H_kv] slab per block, and Mosaic slices
+            # an HBM plane only in whole 128-lane tiles ("Slice shape
+            # along dimension 2 must be aligned to tiling (128), but is
+            # 8", v5e / jax 0.9.0, at block lengths 16 and 32 alike).
+            # No served model has 128 KV heads, so there is no int8
+            # kernel: the cache reads through ``paged_dequant_view``
+            # until the scale planes change shape.
+            return False, "int8_scale_lanes"
+        return False, "dtype_mismatch"
+    if has_scales:
+        # scale arenas beside a cache of the compute dtype: the caller
+        # broke the operand contract (``paged_dequant_view`` raises)
         return False, "scales_mismatch"
     b, hkv, g, d = q4.shape
     w = cache.shape[2]
     if not packed_ok(hkv, d) or w != hkv * d:
-        return False, "int8_geom" if int8_pair else "geometry"
-    if int8_pair and hkv % _LANES:
-        # the [NB+1, L, H_kv] f32 scale planes are staged by DMA, one
-        # [L, H_kv] slab per block, and Mosaic slices an HBM plane only
-        # in whole 128-lane tiles ("Slice shape along dimension 2 must
-        # be aligned to tiling (128), but is 8", v5e / jax 0.9.0, at
-        # block lengths 16 and 32 alike).  No served model has 128 KV
-        # heads, so on the chip the int8 cache reads through
-        # ``paged_dequant_view`` until the scale planes change shape.
-        return False, "int8_scale_lanes"
+        return False, "geometry"
     if g > _GPAD:        # q_cat blocks hold at most 8 query heads/KV head
         return False, "group_too_wide"
     if not align_ok:
@@ -385,8 +337,6 @@ def _gate_shared(q4, cache, s, align_ok, align_reason, q_rows=_GPAD,
     gw = max(_LANES, d)
     lg_bytes = (w // gw) * (gw // d) * q_rows * s * 4
     vmem = 2 * s * w * itemsize + lg_bytes
-    if int8_pair:
-        vmem += 2 * s * hkv * 4      # staged f32 scale planes
     vmem += acc_rows * w * (4 + 2 * jnp.dtype(q4.dtype).itemsize)
     if vmem > _VMEM_BUDGET:
         return False, "vmem_budget"
@@ -438,7 +388,7 @@ def should_use_pallas(q4, cache) -> bool:
     use, reason = _route_decision(q4, cache)
     # counted at trace/gate time (once per compiled program or direct
     # query, not per device step): the always-on Pallas-fallback-rate
-    # signal the bench JSON and Prometheus scrape expose
+    # signal the benchmark's route check and a Prometheus scrape read
     _count_route("pallas" if use else "xla", reason)
     return use
 
@@ -447,21 +397,15 @@ def _route_decision_paged(q4, arena, tables, kv_scales=None):
     """(use_pallas, reason) for the PAGED decode-attention gate: the
     shared gate (``_gate_shared``) evaluated on the arena geometry,
     with the paged-only table rule (``_paged_table_rule``) in place of
-    ``seq_align``.  Accepts route as
-    ``paged_ok`` so the route counter separates paged-kernel traffic
-    from dense ``ok`` — or as ``paged_int8_ok`` when the caller passes
-    the quantized cache's scale arenas (``kv_scales``), the explicitly
-    allowlisted (float q, int8 cache + scales) pairing that runs the
-    dequant-in-kernel variant."""
+    ``seq_align``.  Accepts route as ``paged_ok`` so the route counter
+    separates paged-kernel traffic from dense ``ok``; the quantized
+    cache (``kv_scales`` given) never accepts."""
     s, acc_rows = _paged_staging(q4.shape[1], 1, q4.shape[2], arena,
-                                 tables, kv_scales)
+                                 tables)
     use, reason = _gate_shared(
-        q4, arena, s, *_paged_table_rule(arena, tables, kv_scales),
+        q4, arena, s, *_paged_table_rule(arena),
         has_scales=kv_scales is not None, acc_rows=acc_rows)
-    if reason is not None:
-        return use, reason
-    return use, ("paged_int8_ok" if kv_scales is not None
-                 else "paged_ok")
+    return use, reason or "paged_ok"
 
 
 def should_use_pallas_paged(q4, arena, tables, kv_scales=None) -> bool:
@@ -485,20 +429,16 @@ def _route_decision_paged_multi(q5, arena, tables, kv_scales=None):
     ``_QROWS_MAX`` rows would blow the logits scratch for no win
     (reason ``query_rows``).  Accepts route as ``paged_multi_ok`` so
     the route counter separates verify traffic from single-token
-    ``paged_ok`` — or as ``paged_multi_int8_ok`` for the allowlisted
-    (float q, int8 cache + scales) pairing."""
+    ``paged_ok``."""
     b, cq, hkv, g, d = q5.shape
     qr = -(-(g * cq) // _GPAD) * _GPAD
     if qr > _QROWS_MAX:
         return False, "query_rows"
-    s, acc_rows = _paged_staging(hkv, cq, g, arena, tables, kv_scales)
+    s, acc_rows = _paged_staging(hkv, cq, g, arena, tables)
     use, reason = _gate_shared(
-        q5[:, 0], arena, s, *_paged_table_rule(arena, tables, kv_scales),
+        q5[:, 0], arena, s, *_paged_table_rule(arena),
         q_rows=qr, has_scales=kv_scales is not None, acc_rows=acc_rows)
-    if reason is not None:
-        return use, reason
-    return use, ("paged_multi_int8_ok" if kv_scales is not None
-                 else "paged_multi_ok")
+    return use, reason or "paged_multi_ok"
 
 
 def should_use_pallas_paged_multi(q5, arena, tables,
@@ -738,236 +678,6 @@ def _paged_stream_kernel(lens_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (out / l_ref[...]).astype(out_dtype)
 
 
-def _paged_kernel_q(lens_ref, tbl_ref, qcat_ref, k_hbm, v_hbm,
-                    ks_hbm, vs_hbm, o_ref,
-                    kbuf, vbuf, ksbuf, vsbuf, lg_ref,
-                    ksem, vsem, kssem, vssem,
-                    *, block_len, n_blocks_max, scale, out_dtype, hkv,
-                    g, d, gw, hp, ng):
-    """The paged kernel over the INT8 cache, one query position, on the
-    STAGED body (the block-table form of ``_kernel``: the slot's whole
-    valid prefix lands in VMEM, then one softmax over all of it; the
-    float cache reads through ``_paged_stream_kernel`` instead, and
-    this body cannot compile on the v5e, whose gate answers
-    ``int8_scale_lanes``) — the whole point of the
-    quantized cache: each staged block DMAs int8 K/V codes PLUS the
-    [L, H_kv] f32 scale plane, so HBM traffic per cache row drops from
-    2 bytes/lane (bf16) to 1 byte/lane + 4/D scale bytes, while the
-    MXU still sees the compute dtype — codes are dequantized in VMEM
-    (``codes * scales``, scales expanded head->lanes by the constant
-    0/1 matrix ``expand`` [hp, gw]) right before each dot.  The
-    arithmetic mirrors ``paged_dequant_view`` + the XLA fallback, so
-    interpret-mode parity holds against the gather-based path.
-
-    Scratch-reuse invariant, adjusted for int8: the code buffers need
-    NO memset at all — an int8 bit pattern is always a finite value,
-    so (b) of ``_kernel``'s invariant (no NaN may meet a zero weight)
-    is vacuous for them — but ``vsbuf`` takes over vbuf's program-0
-    memset: an undefined f32 SCALE is the one place a NaN could enter
-    the PV dot (0 weight * (code * NaN scale) = NaN).  ``ksbuf`` is
-    never zeroed, like kbuf: a NaN K scale only produces NaN logits at
-    rows past the prefix, which the masked-logit flush replaces with
-    -1e30 before exp.  All of it still rests on the sequential
-    'arbitrary' grid order."""
-    bi = pl.program_id(0)
-    length = lens_ref[bi]                     # last valid slot index
-    n_blk = length // block_len + 1
-    rows = n_blocks_max * block_len
-
-    @pl.when(bi == 0)
-    def _():
-        vsbuf[...] = jnp.zeros_like(vsbuf)
-
-    for c in range(n_blocks_max):             # static unroll, guarded
-        @pl.when(c < n_blk)
-        def _(c=c):
-            blk = tbl_ref[bi, c]
-            sl = pl.ds(c * block_len, block_len)
-            pltpu.make_async_copy(
-                k_hbm.at[blk], kbuf.at[sl, :], ksem.at[c]).start()
-            pltpu.make_async_copy(
-                v_hbm.at[blk], vbuf.at[sl, :], vsem.at[c]).start()
-            pltpu.make_async_copy(
-                ks_hbm.at[blk], ksbuf.at[sl, :], kssem.at[c]).start()
-            pltpu.make_async_copy(
-                vs_hbm.at[blk], vsbuf.at[sl, :], vssem.at[c]).start()
-
-    for c in range(n_blocks_max):
-        @pl.when(c < n_blk)
-        def _(c=c):
-            blk = tbl_ref[bi, c]
-            sl = pl.ds(c * block_len, block_len)
-            pltpu.make_async_copy(
-                k_hbm.at[blk], kbuf.at[sl, :], ksem.at[c]).wait()
-            pltpu.make_async_copy(
-                ks_hbm.at[blk], ksbuf.at[sl, :], kssem.at[c]).wait()
-
-    cdt = qcat_ref.dtype
-    expand = _scale_expand(hp, gw, d)
-    for p in range(ng):
-        ks = jax.lax.dot_general(
-            ksbuf[:, p * hp:(p + 1) * hp], expand,
-            (((1,), (0,)), ((), ())))                     # [rows, gw]
-        kd = (kbuf[:, p * gw:(p + 1) * gw].astype(jnp.float32)
-              * ks).astype(cdt)
-        lg_ref[p] = jax.lax.dot_general(
-            qcat_ref[0, p], kd,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [hp*8, rows]
-
-    sub = jax.lax.broadcasted_iota(jnp.int32, (ng, hp * _GPAD, rows), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (ng, hp * _GPAD, rows), 2)
-    keep = (row <= length) & (jax.lax.rem(sub, _GPAD) < g)
-    lg = jnp.where(keep, lg_ref[...], _NEG_INF)
-    m = jnp.max(lg, axis=-1, keepdims=True)
-    p_ = jnp.exp(lg - m)
-    l = jnp.sum(p_, axis=-1, keepdims=True)    # [ng, hp*8, 1]
-    lg_ref[...] = p_
-
-    for c in range(n_blocks_max):
-        @pl.when(c < n_blk)
-        def _(c=c):
-            blk = tbl_ref[bi, c]
-            sl = pl.ds(c * block_len, block_len)
-            pltpu.make_async_copy(
-                v_hbm.at[blk], vbuf.at[sl, :], vsem.at[c]).wait()
-            pltpu.make_async_copy(
-                vs_hbm.at[blk], vsbuf.at[sl, :], vssem.at[c]).wait()
-
-    for p in range(ng):
-        vs = jax.lax.dot_general(
-            vsbuf[:, p * hp:(p + 1) * hp], expand,
-            (((1,), (0,)), ((), ())))                     # [rows, gw]
-        vd = (vbuf[:, p * gw:(p + 1) * gw].astype(jnp.float32)
-              * vs).astype(cdt)
-        pv_w = jax.lax.dot_general(
-            lg_ref[p].astype(cdt), vd,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [hp*8, gw]
-        for j in range(hp):
-            h = p * hp + j
-            o_ref[0, h] = (pv_w[j * _GPAD:j * _GPAD + g,
-                                j * d:(j + 1) * d]
-                           / l[p, j * _GPAD:j * _GPAD + g]
-                           ).astype(out_dtype)
-
-
-def _paged_multi_kernel_q(lens_ref, tbl_ref, qcat_ref, k_hbm, v_hbm,
-                          ks_hbm, vs_hbm, o_ref,
-                          kbuf, vbuf, ksbuf, vsbuf, lg_ref,
-                          ksem, vsem, kssem, vssem,
-                          *, block_len, n_blocks_max, cq, qr, scale,
-                          out_dtype, g, d, gw, hp, ng):
-    """K-wide query variant of ``_paged_kernel_q`` (the speculative
-    verifier's attention over the quantized cache, on the same staged
-    body): each program scores ``cq`` query positions of one batch row
-    against the same staged prefix; per head the q block holds
-    ``qr = roundup(g * cq, 8)`` rows ordered ``c * g + gi``, and query
-    c sees cache rows ``<= lens[b] + c``.  int8 K/V codes +
-    [L, H_kv] f32 scale planes are DMA'd per staged block and
-    dequantized in VMEM right before each dot, exactly as in
-    ``_paged_kernel_q``.  Scratch-reuse invariant as adjusted for
-    int8 in ``_paged_kernel_q``: code buffers need no memset (int8 is
-    always finite), ``vsbuf`` takes the program-0 memset (an undefined
-    f32 scale is the only NaN entry point into the PV dot), ``ksbuf``
-    is never zeroed (NaN K scales only reach masked-and-flushed
-    logits), all under the sequential 'arbitrary' grid."""
-    bi = pl.program_id(0)
-    length = lens_ref[bi]              # first query's global slot
-    n_blk = jnp.minimum((length + cq - 1) // block_len + 1, n_blocks_max)
-    rows = n_blocks_max * block_len
-
-    @pl.when(bi == 0)
-    def _():
-        vsbuf[...] = jnp.zeros_like(vsbuf)
-
-    for c in range(n_blocks_max):             # static unroll, guarded
-        @pl.when(c < n_blk)
-        def _(c=c):
-            blk = tbl_ref[bi, c]
-            sl = pl.ds(c * block_len, block_len)
-            pltpu.make_async_copy(
-                k_hbm.at[blk], kbuf.at[sl, :], ksem.at[c]).start()
-            pltpu.make_async_copy(
-                v_hbm.at[blk], vbuf.at[sl, :], vsem.at[c]).start()
-            pltpu.make_async_copy(
-                ks_hbm.at[blk], ksbuf.at[sl, :], kssem.at[c]).start()
-            pltpu.make_async_copy(
-                vs_hbm.at[blk], vsbuf.at[sl, :], vssem.at[c]).start()
-
-    for c in range(n_blocks_max):
-        @pl.when(c < n_blk)
-        def _(c=c):
-            blk = tbl_ref[bi, c]
-            sl = pl.ds(c * block_len, block_len)
-            pltpu.make_async_copy(
-                k_hbm.at[blk], kbuf.at[sl, :], ksem.at[c]).wait()
-            pltpu.make_async_copy(
-                ks_hbm.at[blk], ksbuf.at[sl, :], kssem.at[c]).wait()
-
-    cdt = qcat_ref.dtype
-    expand = _scale_expand(hp, gw, d)
-    for p in range(ng):
-        ks = jax.lax.dot_general(
-            ksbuf[:, p * hp:(p + 1) * hp], expand,
-            (((1,), (0,)), ((), ())))                     # [rows, gw]
-        kd = (kbuf[:, p * gw:(p + 1) * gw].astype(jnp.float32)
-              * ks).astype(cdt)
-        lg_ref[p] = jax.lax.dot_general(
-            qcat_ref[0, p], kd,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [hp*qr, rows]
-
-    sub = jax.lax.broadcasted_iota(jnp.int32, (ng, hp * qr, rows), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (ng, hp * qr, rows), 2)
-    qsub = jax.lax.rem(sub, qr)
-    keep = (row <= length + qsub // g) & (qsub < g * cq)
-    lg = jnp.where(keep, lg_ref[...], _NEG_INF)
-    m = jnp.max(lg, axis=-1, keepdims=True)
-    p_ = jnp.exp(lg - m)
-    l = jnp.sum(p_, axis=-1, keepdims=True)    # [ng, hp*qr, 1]
-    lg_ref[...] = p_
-
-    for c in range(n_blocks_max):
-        @pl.when(c < n_blk)
-        def _(c=c):
-            blk = tbl_ref[bi, c]
-            sl = pl.ds(c * block_len, block_len)
-            pltpu.make_async_copy(
-                v_hbm.at[blk], vbuf.at[sl, :], vsem.at[c]).wait()
-            pltpu.make_async_copy(
-                vs_hbm.at[blk], vsbuf.at[sl, :], vssem.at[c]).wait()
-
-    for p in range(ng):
-        vs = jax.lax.dot_general(
-            vsbuf[:, p * hp:(p + 1) * hp], expand,
-            (((1,), (0,)), ((), ())))                     # [rows, gw]
-        vd = (vbuf[:, p * gw:(p + 1) * gw].astype(jnp.float32)
-              * vs).astype(cdt)
-        pv_w = jax.lax.dot_general(
-            lg_ref[p].astype(cdt), vd,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [hp*qr, gw]
-        for j in range(hp):
-            h = p * hp + j
-            o_ref[0, h] = (pv_w[j * qr:j * qr + cq * g,
-                                j * d:(j + 1) * d]
-                           / l[p, j * qr:j * qr + cq * g]
-                           ).astype(out_dtype)
-
-
-def _scale_expand(hp, gw, d):
-    """The head->lanes scale-expansion matrix of the int8 kernels: a
-    [hp, gw] 0/1 matrix with row j lighting lanes [j*d, (j+1)*d) —
-    ``scales[rows, hp] @ expand`` broadcasts each head's scale across
-    its D lanes as one small matmul (robust on the MXU, no in-kernel
-    gather/repeat).  Built from iota INSIDE the kernel body (Pallas
-    rejects captured array constants); the compiler folds it."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, gw), 1)
-    rowj = jax.lax.broadcasted_iota(jnp.int32, (hp, gw), 0)
-    return (lane // d == rowj).astype(jnp.float32)
-
-
 def _build_qcat(q4, hp, ng, gw):
     """Block-diagonal q: [B, H_kv, G, D] -> [B, ng, hp*8, gw] where
     group p, block j holds head p*hp+j's q in lane range [j*D, (j+1)*D)
@@ -1050,46 +760,6 @@ def _guard_replicated_tables(tables):
             f"never the tables)")
 
 
-def _paged_dispatch(kernel, qcat, operands, tables, lens, *, b, hkv, d,
-                    q_rows, out_rows, gw, ng, s, n_blocks_max):
-    """Grid-spec + dispatch body of the two STAGED paged wrappers
-    (single/K-wide over the int8-quantized cache) — ONE place for the
-    BlockSpec geometry so a fix never has to land twice.  ``operands``
-    is the HBM operand tuple after the prefetched scalars and q: the
-    (k, v) code arenas and the two f32 scale planes.  Each operand gets
-    an ANY BlockSpec, a VMEM landing buffer ((s, W) in the arena dtype
-    for the code arenas, (s, H_kv) f32 for scale planes) and an
-    n_blocks_max-deep DMA semaphore array, in operand order — matching
-    the scratch signature of both ``_q`` kernels.  The streaming float
-    kernel has a scratch layout of its own (``_paged_stream``)."""
-    _guard_replicated_tables(tables)
-    w = operands[0].shape[2]
-    land = [pltpu.VMEM((s, w), operands[0].dtype),
-            pltpu.VMEM((s, w), operands[1].dtype)]
-    land += [pltpu.VMEM((s, hkv), jnp.float32) for _ in operands[2:]]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[pl.BlockSpec((1, ng, q_rows, gw),
-                               lambda bi, lens_p, tbl_p: (bi, 0, 0, 0))]
-        + [pl.BlockSpec(memory_space=pl.ANY) for _ in operands],
-        out_specs=pl.BlockSpec((1, hkv, out_rows, d),
-                               lambda bi, lens_p, tbl_p: (bi, 0, 0, 0)),
-        scratch_shapes=land
-        + [pltpu.VMEM((ng, q_rows, s), jnp.float32)]
-        + [pltpu.SemaphoreType.DMA((n_blocks_max,)) for _ in operands],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, out_rows, d),
-                                       qcat.dtype),
-        compiler_params=_COMPILER_PARAMS,
-        interpret=not on_tpu(),
-    )(lens.astype(jnp.int32), tables.astype(jnp.int32), qcat,
-      *operands)
-
-
 def _build_qall(q5):
     """Block-diagonal q over the whole cache width: [B, C, H_kv, G, D]
     -> [B, P, H_kv*D], row ``h*C*G + c*G + gi`` holding position c of
@@ -1158,44 +828,6 @@ def _decode_attention_pallas_paged(q4, k_arena, v_arena, tables, lens):
     return _paged_stream(q4[:, None], k_arena, v_arena, tables, lens)
 
 
-def _decode_attention_pallas_paged_q(q4, k_arena, v_arena, k_scales,
-                                     v_scales, tables, lens):
-    """q4: [B, H_kv, G, D] float; arenas packed [NB+1, L, H_kv*D] int8
-    codes (last row = trash block); k/v_scales: [NB+1, L, H_kv] f32
-    per-entry per-head absmax scales; tables: [B, max_blocks] int32."""
-    b, hkv, g, d = q4.shape
-    blk_len = k_arena.shape[1]
-    w = k_arena.shape[2]
-    n_blocks_max = tables.shape[1]
-    s = n_blocks_max * blk_len
-    gw = max(_LANES, d)
-    hp = gw // d
-    ng = w // gw
-    kernel = functools.partial(
-        _paged_kernel_q, block_len=blk_len, n_blocks_max=n_blocks_max,
-        scale=1.0 / (d ** 0.5), out_dtype=q4.dtype, hkv=hkv, g=g, d=d,
-        gw=gw, hp=hp, ng=ng)
-    qcat = _build_qcat(q4, hp, ng, gw)
-    return _paged_dispatch(
-        kernel, qcat, (k_arena, v_arena, k_scales, v_scales), tables,
-        lens, b=b, hkv=hkv, d=d, q_rows=hp * _GPAD, out_rows=g, gw=gw,
-        ng=ng, s=s, n_blocks_max=n_blocks_max)
-
-
-def _build_qcat_multi(q5, hp, ng, gw, qr):
-    """Block-diagonal K-wide q: [B, C, H_kv, G, D] -> [B, ng, hp*qr, gw]
-    where group p, block j holds head p*hp+j's queries (row-ordered
-    ``c*g + gi``, zero-padded to qr rows) in lane range [j*D, (j+1)*D)
-    and zeros elsewhere."""
-    b, cq, hkv, g, d = q5.shape
-    qh = jnp.transpose(q5, (0, 2, 1, 3, 4)).reshape(b, hkv, cq * g, d)
-    qh = jnp.pad(qh, ((0, 0), (0, 0), (0, qr - cq * g), (0, 0)))
-    qg = qh.reshape(b, ng, hp, qr, d)
-    eye = jnp.eye(hp, dtype=q5.dtype)
-    qcat = jnp.einsum("bnjrd,jk->bnjrkd", qg, eye)
-    return qcat.reshape(b, ng, hp * qr, gw)
-
-
 def _decode_attention_pallas_paged_multi(q5, k_arena, v_arena, tables,
                                          lens):
     """q5: [B, C, H_kv, G, D]; arenas packed [NB+1, L, H_kv*D] (last
@@ -1203,35 +835,6 @@ def _decode_attention_pallas_paged_multi(q5, k_arena, v_arena, tables,
     position of the FIRST query.  Returns [B, C, H_kv, G, D]."""
     b, cq, hkv, g, d = q5.shape
     out = _paged_stream(q5, k_arena, v_arena, tables, lens)
-    # head-major rows c*g+gi back to [B, C, H_kv, G, D]
-    return jnp.transpose(out.reshape(b, hkv, cq, g, d), (0, 2, 1, 3, 4))
-
-
-def _decode_attention_pallas_paged_multi_q(q5, k_arena, v_arena,
-                                           k_scales, v_scales, tables,
-                                           lens):
-    """q5: [B, C, H_kv, G, D] float; int8 code arenas + f32 scale
-    arenas as ``_decode_attention_pallas_paged_q``; lens: [B] global
-    position of the FIRST query.  Returns [B, C, H_kv, G, D]."""
-    b, cq, hkv, g, d = q5.shape
-    blk_len = k_arena.shape[1]
-    w = k_arena.shape[2]
-    n_blocks_max = tables.shape[1]
-    s = n_blocks_max * blk_len
-    gw = max(_LANES, d)
-    hp = gw // d
-    ng = w // gw
-    qr = -(-(g * cq) // _GPAD) * _GPAD
-    kernel = functools.partial(
-        _paged_multi_kernel_q, block_len=blk_len,
-        n_blocks_max=n_blocks_max, cq=cq, qr=qr,
-        scale=1.0 / (d ** 0.5), out_dtype=q5.dtype, g=g, d=d,
-        gw=gw, hp=hp, ng=ng)
-    qcat = _build_qcat_multi(q5, hp, ng, gw, qr)
-    out = _paged_dispatch(
-        kernel, qcat, (k_arena, v_arena, k_scales, v_scales), tables,
-        lens, b=b, hkv=hkv, d=d, q_rows=hp * qr, out_rows=cq * g,
-        gw=gw, ng=ng, s=s, n_blocks_max=n_blocks_max)
     # head-major rows c*g+gi back to [B, C, H_kv, G, D]
     return jnp.transpose(out.reshape(b, hkv, cq, g, d), (0, 2, 1, 3, 4))
 
@@ -1285,11 +888,10 @@ def decode_attention_paged(q, k_arena, v_arena, tables, lens,
     lens: [B] = index of the LAST valid slot; kv_scales: None for a
     float cache, or the int8 cache's ``(k_scales, v_scales)`` pair of
     [NB+1, L, H_kv] f32 absmax planes.  On TPU (and when the block
-    geometry passes ``_route_decision_paged``) this runs the
+    geometry passes ``_route_decision_paged``) a float cache runs the
     block-table Pallas kernel — DMA indirection through the
-    scalar-prefetched table, no dense copy of the pool; the int8
-    pairing routes the dequant-in-kernel variant (reason
-    ``paged_int8_ok``).  Otherwise the gather-based XLA path
+    scalar-prefetched table, no dense copy of the pool.  Otherwise,
+    and always for the int8 cache, the gather-based XLA path
     materializes each row's dense view (``paged_gather_view``, or the
     dequantized ``paged_dequant_view`` for int8) and reuses the
     reference math.  Returns [B, H_q * D] in q.dtype.
@@ -1300,13 +902,8 @@ def decode_attention_paged(q, k_arena, v_arena, tables, lens,
     g = hq // hkv
     q4 = q.reshape(b, hkv, g, d)
     if should_use_pallas_paged(q4, k_arena, tables, kv_scales):
-        if kv_scales is not None:
-            out = _decode_attention_pallas_paged_q(
-                q4, k_arena, v_arena, kv_scales[0], kv_scales[1],
-                tables, lens)
-        else:
-            out = _decode_attention_pallas_paged(q4, k_arena, v_arena,
-                                                 tables, lens)
+        out = _decode_attention_pallas_paged(q4, k_arena, v_arena,
+                                             tables, lens)
     elif kv_scales is not None:
         out = _decode_attention_xla(
             q4, paged_dequant_view(k_arena, kv_scales[0], tables, q.dtype),
@@ -1359,9 +956,9 @@ def decode_attention_paged_multi(q, k_arena, v_arena, tables, lens,
     prefix acceptance exactly greedy-equivalent.  Unlike chunk prefill
     this path IS cache-sweep-bound (C is small, the prefix is long), so
     it gates into the K-wide paged Pallas kernel
-    (``_route_decision_paged_multi``; accept reason ``paged_multi_ok``,
-    or ``paged_multi_int8_ok`` with ``kv_scales``) with the
-    gather-based XLA path as the universal fallback.  Returns
+    (``_route_decision_paged_multi``; accept reason ``paged_multi_ok``)
+    with the gather-based XLA path as the universal fallback and the
+    int8 cache's (``kv_scales``) only reader.  Returns
     [B, C, H_q, D] in q.dtype."""
     b, cc, hq, d = q.shape
     hkv = (k_arena.shape[2] // d if k_arena.ndim == 3
@@ -1369,13 +966,8 @@ def decode_attention_paged_multi(q, k_arena, v_arena, tables, lens,
     g = hq // hkv
     q5 = q.reshape(b, cc, hkv, g, d)
     if should_use_pallas_paged_multi(q5, k_arena, tables, kv_scales):
-        if kv_scales is not None:
-            out = _decode_attention_pallas_paged_multi_q(
-                q5, k_arena, v_arena, kv_scales[0], kv_scales[1],
-                tables, lens)
-        else:
-            out = _decode_attention_pallas_paged_multi(
-                q5, k_arena, v_arena, tables, lens)
+        out = _decode_attention_pallas_paged_multi(
+            q5, k_arena, v_arena, tables, lens)
         return out.reshape(b, cc, hq, d)
     return _paged_multi_xla(q, k_arena, v_arena, tables, lens, kv_scales)
 

@@ -9,7 +9,7 @@ consult the store at trace time (timing is impossible inside jit), and
 fall back to their measured-default heuristics on a miss.
 
 Run ``python tools/tune_pallas_schedules.py`` on the chip to (re)search
-the bench shapes; winners land in the same schedule store the flash
+the 1.1B Llama shapes; winners land in the same schedule store the flash
 search uses (the tracked ``schedules.json`` beside this file, or
 $PTPU_AUTOTUNE_CACHE).
 """
@@ -333,10 +333,11 @@ def tune_decode_attention(b=32, hkv=8, g=4, s=2048, d=64,
 
 
 def tune_bench_shapes(iters: int = 3) -> Dict[str, Tuple]:
-    """Search every kernel at its bench.py / flagship-model shapes.
-    Returns {kernel/sig: (best, table)} for reporting."""
+    """Search every kernel at the 1.1B Llama shapes (no benchmark cell
+    runs them; ROADMAP D12).  Returns {kernel/sig: (best, table)} for
+    reporting."""
     out = {}
-    # Llama 1.1B bench: hidden 2048, b8 s2048 -> rms rows over 16384 rows
+    # Llama 1.1B: hidden 2048, b8 s2048 -> rms rows over 16384 rows
     out["rms_norm/16384x2048"] = tune_rms_norm(16384, 2048, iters=iters)
     out["rope/8x2048x32x64"] = tune_rope(8, 2048, 32, 64, iters=iters)
     out["quantized_matmul/2048x2048x8192"] = tune_quantized_matmul(

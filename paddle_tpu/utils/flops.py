@@ -3,9 +3,10 @@
 Counts the 2*MAC FLOPs of every ``dot_general`` and
 ``conv_general_dilated`` in a traced function, recursing through
 pjit/remat/custom-vjp wrappers and multiplying ``scan`` bodies by their
-trip count.  This is the honest-FLOPs source for conv-model MFU in
-``bench.py`` and the compute term of the auto-parallel cost model
-(reference analogue: the per-op flops registry behind
+trip count: an honest-FLOPs count for a model whose FLOPs no closed
+form gives, such as a conv net (nothing in the tree calls it but its
+tests; the benchmark's counts are the families' closed forms;
+reference analogue: the per-op flops registry behind
 ``python/paddle/distributed/auto_parallel/static/cost/estimate_cost.py``
 and the profiler flops columns of ``tools/check_op_benchmark_result.py``).
 """

@@ -1,5 +1,5 @@
 """run_steps multi-step scan, low-precision optimizer dtype stability, and
-the jaxpr MXU-FLOPs counter backing bench.py's conv MFU accounting."""
+the jaxpr MXU-FLOPs counter (``utils/flops.py``)."""
 
 import numpy as np
 import pytest

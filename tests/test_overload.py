@@ -360,16 +360,20 @@ def test_queue_delay_timeout_and_deadline_is_not_a_kill(netm):
 
 def test_blockpool_check_audit_and_idempotent_release():
     """BlockPool.check() catches refcount drift / double-free /
-    digest-map corruption; _release_blocks is idempotent (model-free
+    tree-index corruption; park, re-pin and reclaim move a tree-held
+    block as they should; _release_blocks is idempotent (model-free
     unit)."""
     pool = BlockPool(num_blocks=6, block_len=4)
     assert pool.check()
     blocks = pool.alloc(3)
-    pool.register(blocks[0], b"d0")
+    pool.tree_hold(blocks[0])
     assert pool.check()
-    pool.unpin(blocks[0])              # published -> parks in LRU
-    pool.unpin(blocks[1])              # unpublished -> free list
-    assert pool.check()
+    pool.unpin(blocks[0])              # tree-held -> parks in the LRU
+    pool.unpin(blocks[1])              # not held -> free list
+    assert pool.check() and list(pool._tree_lru) == [blocks[0]]
+    pool.pin(blocks[0])                # a hit re-pins it out of the LRU
+    assert pool.check() and not pool._tree_lru
+    pool.unpin(blocks[0])
     with pytest.raises(RuntimeError, match="double free"):
         pool.unpin(blocks[1])
     # direct corruption is caught by the audit
@@ -382,12 +386,24 @@ def test_blockpool_check_audit_and_idempotent_release():
     with pytest.raises(RuntimeError, match="free list"):
         pool.check()
     pool._free.pop()
-    dg_pool = BlockPool(num_blocks=2, block_len=4)
-    (b0,) = dg_pool.alloc(1)
-    dg_pool.register(b0, b"x")
-    dg_pool._by_digest[b"x"] = 1       # digest map points elsewhere
-    with pytest.raises(RuntimeError, match="digest"):
-        dg_pool.check()
+    tr_pool = BlockPool(num_blocks=2, block_len=4)
+    got = []
+    tr_pool.reclaim_cb = got.append
+    (b0,) = tr_pool.alloc(1)
+    tr_pool.tree_hold(b0)
+    tr_pool.unpin(b0)
+    del tr_pool._tree_lru[b0]          # held at ref 0, out of the LRU
+    with pytest.raises(RuntimeError, match="unreclaimable"):
+        tr_pool.check()
+    tr_pool._tree_lru[b0] = True
+    tr_pool._tree_ref.discard(b0)      # in the LRU, not held
+    with pytest.raises(RuntimeError, match="not tree-referenced"):
+        tr_pool.check()
+    tr_pool._tree_ref.add(b0)
+    assert tr_pool.check()
+    # reclaim: the free list first, then the parked block, one callback
+    assert tr_pool.alloc(2) == [1 - b0, b0] and got == [[b0]]
+    assert tr_pool.check() and not tr_pool._tree_ref
 
     # _release_blocks idempotence at the engine layer needs no engine:
     # the contract is "blocks cleared before return", so a double call

@@ -1339,61 +1339,50 @@ def _quantized_paged_case(seed, nb, blk_len, hkv, d):
 
 def test_decode_gate_mixed_dtype_rejects_and_int8_allowlisted(monkeypatch):
     """The dtype rule of the shared decode-attention gate: mixed
-    q/cache dtypes REJECT (``dtype_mismatch``) unless the pair is on
-    the explicit allowlist — (bf16|f32 q, int8 cache) — AND the caller
-    carries the scale arenas; an allowlisted pairing that fails the
-    packed-geometry check rejects as ``int8_geom``.  The chip's own
-    rule (PR 22): scale planes narrower than a 128-lane tile cannot be
-    DMA'd, so only ``H_kv % 128 == 0`` routes — every served head
-    count rejects as ``int8_scale_lanes``."""
+    q/cache dtypes REJECT (``dtype_mismatch``), and the int8 cache
+    with its scale arenas rejects as ``int8_scale_lanes`` at EVERY
+    head count, 128 included: scale planes narrower than a 128-lane
+    tile cannot be DMA'd (PR 22), no served model has 128 KV heads, so
+    there is no int8 kernel and the cache reads through
+    ``paged_dequant_view``."""
     from paddle_tpu.ops.pallas import decode_attention as da
     monkeypatch.setattr(da, "pallas_enabled", lambda: True)
-    for hkv_served in (2, 8):
-        q4 = jnp.zeros((2, hkv_served, 2, 64), jnp.bfloat16)
-        arena = jnp.zeros((9, 32, hkv_served * 64), jnp.int8)
-        planes = (jnp.ones((9, 32, hkv_served), jnp.float32),) * 2
-        tbl = jnp.zeros((2, 3), jnp.int32)
-        use, reason = da._route_decision_paged(q4, arena, tbl, planes)
-        assert not use and reason == "int8_scale_lanes"
-        use, reason = da._route_decision_paged_multi(
-            jnp.zeros((2, 3, hkv_served, 2, 64), jnp.bfloat16), arena,
-            tbl, planes)
-        assert not use and reason == "int8_scale_lanes"
+    for hkv in (2, 8, 128):
+        for qdt in (jnp.float32, jnp.bfloat16):
+            q4 = jnp.zeros((2, hkv, 2, 64), qdt)
+            arena = jnp.zeros((9, 32, hkv * 64), jnp.int8)
+            planes = (jnp.ones((9, 32, hkv), jnp.float32),) * 2
+            tbl = jnp.zeros((2, 3), jnp.int32)
+            use, reason = da._route_decision_paged(q4, arena, tbl, planes)
+            assert not use and reason == "int8_scale_lanes"
+            use, reason = da._route_decision_paged_multi(
+                jnp.zeros((2, 3, hkv, 2, 64), qdt), arena, tbl, planes)
+            assert not use and reason == "int8_scale_lanes"
+            # paged gate without scales: a plain dtype mix
+            use, reason = da._route_decision_paged(q4, arena, tbl)
+            assert not use and reason == "dtype_mismatch"
     b, hkv, g, blk_len, nb, mb, d = 2, 128, 2, 8, 8, 3, 64
     w = hkv * d
     tables = jnp.asarray(np.arange(nb)[:b * mb].reshape(b, mb), jnp.int32)
     sshape = (nb + 1, blk_len, hkv)
     ks = jnp.ones(sshape, jnp.float32)
     vs = jnp.ones(sshape, jnp.float32)
-    arena_i8 = jnp.zeros((nb + 1, blk_len, w), jnp.int8)
     for qdt in (jnp.float32, jnp.bfloat16):
         q4 = jnp.zeros((b, hkv, g, d), qdt)
-        # dense gate: mixed (float q, f32/int8 cache) with NO scales
-        # stays rejected — the dense path never carries scale arenas
-        cache_f64like = jnp.zeros((b, mb * blk_len, w), jnp.float16)
-        use, reason = da._route_decision(q4, cache_f64like)
+        # dense gate: a mixed (float q, f16 cache) pair stays rejected —
+        # the dense path never carries scale arenas
+        cache_f16 = jnp.zeros((b, mb * blk_len, w), jnp.float16)
+        use, reason = da._route_decision(q4, cache_f16)
         assert not use and reason == "dtype_mismatch"
-        # paged gate without scales: same rejection
-        use, reason = da._route_decision_paged(q4, arena_i8, tables)
-        assert not use and reason == "dtype_mismatch"
-        # paged gate WITH scales: the allowlisted int8 pairing routes
-        use, reason = da._route_decision_paged(q4, arena_i8, tables,
-                                               (ks, vs))
-        assert use and reason == "paged_int8_ok"
-    # K-wide verify gate mirrors it
-    q5 = jnp.zeros((b, 3, hkv, g, d), jnp.float32)
-    use, reason = da._route_decision_paged_multi(q5, arena_i8, tables,
-                                                 (ks, vs))
-    assert use and reason == "paged_multi_int8_ok"
-    # allowlisted pair + broken packing -> int8_geom (not plain
-    # geometry: the route counter separates the quantized route)
+    # the int8 pairing names its own reason whatever else is wrong with
+    # the arena: no geometry could route it
     arena_bad = jnp.zeros((nb + 1, blk_len, w + 128), jnp.int8)
     use, reason = da._route_decision_paged(
         jnp.zeros((b, hkv, g, d), jnp.float32), arena_bad, tables,
         (ks, vs))
-    assert not use and reason == "int8_geom"
-    # scale planes riding a FLOAT cache (equal q/cache dtypes, so the
-    # allowlist is never consulted) must NOT route the dequant kernel
+    assert not use and reason == "int8_scale_lanes"
+    # scale planes riding a FLOAT cache (equal q/cache dtypes) are a
+    # broken operand contract, not a route
     arena_f32 = jnp.zeros((nb + 1, blk_len, w), jnp.float32)
     use, reason = da._route_decision_paged(
         jnp.zeros((b, hkv, g, d), jnp.float32), arena_f32, tables,
@@ -1402,108 +1391,83 @@ def test_decode_gate_mixed_dtype_rejects_and_int8_allowlisted(monkeypatch):
     # ... and the XLA dequant view refuses the same contract violation
     with pytest.raises(TypeError, match="int8 code arena"):
         da.paged_dequant_view(arena_f32, ks, tables, jnp.float32)
+    assert not {"paged_int8_ok", "paged_multi_int8_ok", "paged_dma_sems",
+                "int8_geom"} & set(da.DECODE_ROUTE_REASONS)
 
 
-
-def test_decode_attention_paged_int8_kernel_parity():
-    """Dequant-in-kernel parity (the allowlisted-pair case): the int8
-    paged Pallas kernel (interpret mode) must match the gather-based
-    XLA fallback reading ``paged_dequant_view`` — same codes, same
-    scales, same math — tightly; and both must sit within the
-    quantization-step bound of the EXACT unquantized attention
-    (bounded logit drift)."""
-    from paddle_tpu.ops.pallas.decode_attention import (
-        _decode_attention_pallas_paged_q, _decode_attention_xla,
-        paged_dequant_view, paged_gather_view)
-    rng = np.random.default_rng(23)
-    b, hkv, g, blk_len, nb, mb, d = 3, 2, 2, 8, 12, 4, 64
-    kf, vf, kc, vc, ks, vs = _quantized_paged_case(23, nb, blk_len,
+@pytest.mark.parametrize("route", ["single", "k_wide"])
+def test_decode_attention_paged_int8_reads_dequant_view(monkeypatch, route):
+    """The int8 paged cache has one reader.  The public entries
+    (``decode_attention_paged`` for one query, ``_multi`` for the
+    K-wide verify, per-offset causal masking included) given
+    ``kv_scales`` count one XLA route under ``int8_scale_lanes``, even
+    with the kernels on, and answer with the float reference on the
+    dequantised cache; both sit within the quantization-step bound of
+    the EXACT unquantized attention (bounded logit drift)."""
+    from paddle_tpu.observability import metrics as obs
+    from paddle_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "pallas_enabled", lambda: True)
+    seed = 23 if route == "single" else 29
+    rng = np.random.default_rng(seed)
+    b, hkv, g, blk_len, nb, mb, d, cq = 3, 2, 2, 8, 12, 4, 64, 5
+    kf, vf, kc, vc, ks, vs = _quantized_paged_case(seed, nb, blk_len,
                                                    hkv, d)
-    q4 = jnp.asarray(rng.standard_normal((b, hkv, g, d)), jnp.float32)
+    kc, vc = jnp.asarray(kc), jnp.asarray(vc)
     tables = jnp.asarray(rng.permutation(nb)[:b * mb].reshape(b, mb),
                          jnp.int32)
-    lens = jnp.asarray([5, 17, 30], jnp.int32)   # mid-block frontiers
-    out = _decode_attention_pallas_paged_q(q4, jnp.asarray(kc),
-                                           jnp.asarray(vc), ks, vs,
-                                           tables, lens)
-    ref = _decode_attention_xla(
-        q4, paged_dequant_view(jnp.asarray(kc), ks, tables, jnp.float32),
-        paged_dequant_view(jnp.asarray(vc), vs, tables, jnp.float32),
-        lens)
+    kd = da.paged_dequant_view(kc, ks, tables, jnp.float32)
+    vd = da.paged_dequant_view(vc, vs, tables, jnp.float32)
+    kx = da.paged_gather_view(jnp.asarray(kf), tables)
+    vx = da.paged_gather_view(jnp.asarray(vf), tables)
+    ctr = obs.get_registry().counter("pallas.decode_attention.route",
+                                     labels=("decision", "reason"))
+    label = dict(decision="xla", reason="int8_scale_lanes")
+    before = ctr.value(**label)
+    if route == "single":
+        lens = jnp.asarray([5, 17, 30], jnp.int32)  # mid-block frontiers
+        q = jnp.asarray(rng.standard_normal((b, hkv * g, d)), jnp.float32)
+        q4 = q.reshape(b, hkv, g, d)
+        out = da.decode_attention_paged(q, kc, vc, tables, lens, (ks, vs))
+        ref = da._decode_attention_xla(q4, kd, vd, lens).reshape(out.shape)
+        exact = da._decode_attention_xla(q4, kx, vx, lens).reshape(out.shape)
+    else:
+        lens = jnp.asarray([5, 17, 26], jnp.int32)
+        q = jnp.asarray(rng.standard_normal((b, cq, hkv * g, d)),
+                        jnp.float32)
+        out = da.decode_attention_paged_multi(q, kc, vc, tables, lens,
+                                              (ks, vs))
+        # the float reference, query by query at its own frontier
+        q5 = q.reshape(b, cq, hkv, g, d)
+        ref = jnp.stack([da._decode_attention_xla(q5[:, c], kd, vd, lens + c)
+                         for c in range(cq)], axis=1).reshape(out.shape)
+        exact = jnp.stack([da._decode_attention_xla(q5[:, c], kx, vx,
+                                                    lens + c)
+                           for c in range(cq)], axis=1).reshape(out.shape)
+    assert ctr.value(**label) == before + 1
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
-    exact = _decode_attention_xla(
-        q4, paged_gather_view(jnp.asarray(kf), tables),
-        paged_gather_view(jnp.asarray(vf), tables), lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exact),
                                atol=5e-2, rtol=5e-2)
 
 
-def test_decode_attention_paged_multi_int8_kernel_parity():
-    """K-wide (speculative verify) twin of the int8 parity test: the
-    int8 multi kernel vs the dequantizing XLA multi path, per-offset
-    causal masking included."""
-    from paddle_tpu.ops.pallas.decode_attention import (
-        _decode_attention_pallas_paged_multi_q, _paged_multi_xla)
-    rng = np.random.default_rng(29)
-    b, hkv, g, blk_len, nb, mb, d, cq = 3, 2, 2, 8, 12, 4, 64, 5
-    kf, vf, kc, vc, ks, vs = _quantized_paged_case(29, nb, blk_len,
-                                                   hkv, d)
-    hq = hkv * g
-    q = jnp.asarray(rng.standard_normal((b, cq, hq, d)), jnp.float32)
-    q5 = q.reshape(b, cq, hkv, g, d)
-    tables = jnp.asarray(rng.permutation(nb)[:b * mb].reshape(b, mb),
-                         jnp.int32)
-    lens = jnp.asarray([5, 17, 26], jnp.int32)
-    out = _decode_attention_pallas_paged_multi_q(
-        q5, jnp.asarray(kc), jnp.asarray(vc), ks, vs, tables, lens)
-    ref = _paged_multi_xla(q, jnp.asarray(kc), jnp.asarray(vc), tables,
-                           lens, (ks, vs)).reshape(b, cq, hkv, g, d)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-5, rtol=1e-5)
-
-
-@pytest.mark.parametrize("route", ["single", "k_wide", "int8",
-                                   "k_wide_int8"])
+@pytest.mark.parametrize("route", ["single", "k_wide"])
 def test_paged_gate_table_width_rule(monkeypatch, route):
     """What bounds the table is the kernel's body.  The streaming float
     kernel (single query and K-wide) stages two fixed-size stages and
     holds four semaphores, so it admits a 220-block table and a context
     past the 1445 rows that the staged body reached at 16 KV heads of
-    128 (PR 27).  The int8 kernels keep the staged body: the v5e's
-    semaphore memory bounds their table (four operands, a semaphore a
-    block: 109 blocks, ``paged_dma_sems``, PR 22) and their landing
-    buffers the context (``vmem_budget``)."""
+    128 (PR 27)."""
     from paddle_tpu.ops.pallas import decode_attention as da
     monkeypatch.setattr(da, "pallas_enabled", lambda: True)
-    if route in ("single", "k_wide"):
-        ok = "paged_ok" if route == "single" else "paged_multi_ok"
-        decide = (da._route_decision_paged if route == "single"
-                  else da._route_decision_paged_multi)
-        lead = (2,) if route == "single" else (2, 3)
-        # (kv heads, head dim, block length, table width)
-        for hkv, d, blk_len, width in ((2, 64, 8, 219), (2, 64, 8, 220),
-                                       (16, 128, 16, 91),    # 1456 rows
-                                       (16, 128, 16, 220)):  # 3520 rows
-            q = jnp.zeros(lead + (hkv, 1, d), jnp.bfloat16)
-            arena = jnp.zeros((9, blk_len, hkv * d), jnp.bfloat16)
-            tables = jnp.zeros((2, width), jnp.int32)
-            assert decide(q, arena, tables) == (True, ok)
-        return
-    hkv, d = 128, 32            # the one head count whose planes DMA
-    planes = lambda blk_len: (jnp.ones((9, blk_len, hkv), jnp.float32),) * 2
-    if route == "int8":
-        ok, decide = "paged_int8_ok", da._route_decision_paged
-        q = jnp.zeros((2, hkv, 1, d), jnp.bfloat16)
-    else:
-        ok, decide = "paged_multi_int8_ok", da._route_decision_paged_multi
-        q = jnp.zeros((2, 3, hkv, 1, d), jnp.bfloat16)
-    arena = jnp.zeros((9, 8, hkv * d), jnp.int8)
-    for width, want in ((109, ok), (110, "paged_dma_sems")):
+    ok = "paged_ok" if route == "single" else "paged_multi_ok"
+    decide = (da._route_decision_paged if route == "single"
+              else da._route_decision_paged_multi)
+    lead = (2,) if route == "single" else (2, 3)
+    # (kv heads, head dim, block length, table width)
+    for hkv, d, blk_len, width in ((2, 64, 8, 219), (2, 64, 8, 220),
+                                   (16, 128, 16, 91),    # 1456 rows
+                                   (16, 128, 16, 220)):  # 3520 rows
+        q = jnp.zeros(lead + (hkv, 1, d), jnp.bfloat16)
+        arena = jnp.zeros((9, blk_len, hkv * d), jnp.bfloat16)
         tables = jnp.zeros((2, width), jnp.int32)
-        use, reason = decide(q, arena, tables, planes(8))
-        assert (use, reason) == (want == ok, want)
-    # inside the semaphores' reach, past the landing buffers'
-    arena = jnp.zeros((9, 32, hkv * d), jnp.int8)
-    tables = jnp.zeros((2, 100), jnp.int32)
-    assert decide(q, arena, tables, planes(32)) == (False, "vmem_budget")
+        assert decide(q, arena, tables) == (True, ok)

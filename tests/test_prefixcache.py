@@ -7,7 +7,7 @@ eviction) and the extended BlockPool.check() invariants.
 Tier-1 budget discipline (truncation-scored 870s wall on a 2-core
 box): the radix-tree and host-tier units are model-free with zero XLA
 dispatches; the compile-bearing unmarked tests are ONE multi-turn
-radix-vs-digest trace (tiny model, 1 slot, <= 4-chunk prompts, 2-token
+radix-vs-none trace (tiny model, 1 slot, <= 4-chunk prompts, 2-token
 budgets), one small admission-order engine and one fault-degradation
 engine.  The int8 twin and the fragmentation stress are
 ``slow``-marked."""
@@ -195,8 +195,8 @@ def _multiturn_trace(net, cfg, mode, kvdt=None, num_blocks=8):
     deliberately small HBM pool: every turn's prompt extends the
     conversation history over a 4-token shared system prompt, and the
     pool is small enough that turn N's blocks are reclaimed while the
-    other conversation runs — the digest cache forgets them, the
-    tiered radix cache demotes them to host RAM and swaps them back.
+    other conversation runs — the tiered radix cache demotes them to
+    host RAM and swaps them back.
     Returns (engine, [(prompt_ids, request), ...])."""
     rng = np.random.default_rng(3)
     sys_ids = rng.integers(0, cfg.vocab_size, (4,)).astype(np.int32)
@@ -231,27 +231,26 @@ def _multiturn_trace(net, cfg, mode, kvdt=None, num_blocks=8):
 
 def test_tiered_multiturn_parity_and_hit_tokens(netm):
     """The acceptance trace: the SAME multi-turn conversation trace
-    through a tiered-radix engine and a PR-3 digest engine.  Every
-    output is token-for-token generate()-exact in BOTH arms (so the
-    histories, and therefore the traces, are identical), the pool
-    audits clean after every step, the radix arm serves hits from the
-    host tier by exact-bytes swap-in, and it serves STRICTLY more
-    cache tokens than the digest arm — the whole point of remembering
-    what the LRU evicts."""
+    through a tiered-radix engine and an engine without the prefix
+    cache.  Every output is token-for-token generate()-exact in BOTH
+    arms (so the histories, and therefore the traces, are identical),
+    the pool audits clean after every step, the radix arm serves hits
+    from the host tier by exact-bytes swap-in, and it serves cache
+    tokens where the other arm serves none."""
     cfg, net = netm
     eng_r, served_r = _multiturn_trace(net, cfg, "radix")
-    eng_d, served_d = _multiturn_trace(net, cfg, "digest")
+    eng_d, served_d = _multiturn_trace(net, cfg, "none")
     for (ids_r, rr), (ids_d, rd) in zip(served_r, served_d):
         np.testing.assert_array_equal(ids_r, ids_d)   # same trace
         np.testing.assert_array_equal(rr.output, rd.output)
         np.testing.assert_array_equal(rr.output,
                                       _oracle(net, ids_r, 2))
     s_r, s_d = eng_r.stats(), eng_d.stats()
-    # the host tier really served hits the digest cache could not
+    # the host tier really served hits
     assert s_r["prefix_host_hits"] >= 1
     assert s_r["host_swapin_blocks"] >= 1
     assert s_r["swap_blocks_in"] >= s_r["host_swapin_blocks"]
-    assert s_r["prefix_hit_tokens"] > s_d["prefix_hit_tokens"]
+    assert s_r["prefix_hit_tokens"] > s_d["prefix_hit_tokens"] == 0
     # fewer recomputed chunks is the TTFT mechanism, trace-identical
     # so directly comparable
     assert s_r["prefill_chunks"] < s_d["prefill_chunks"]
@@ -471,19 +470,23 @@ def test_promotion_scatter_raise_releases_pins(netm, monkeypatch):
 
 
 def test_engine_guards_and_mode_validation(netm):
-    """Constructor guards: bad prefix_cache_mode / negative
-    host_cache_blocks raise; enable_prefix_cache=False still spells
-    "none"; host_cache_blocks=0 disables demotion (PR-3 forget
-    semantics) without disabling the radix index."""
+    """Constructor guards: bad prefix_cache_mode (the deleted
+    ``"digest"`` like any other) / negative host_cache_blocks raise;
+    ``"none"`` builds no index; host_cache_blocks=0 disables demotion
+    (reclaim forgets) without disabling the radix index."""
     cfg, net = netm
-    with pytest.raises(ValueError, match="prefix_cache_mode"):
+    for bad in ("lru", "digest"):
+        with pytest.raises(ValueError, match="prefix_cache_mode"):
+            ServingEngine(net, num_slots=1, prompt_len=4, max_cache_len=8,
+                          prefix_cache_mode=bad)
+    with pytest.raises(TypeError, match="enable_prefix_cache"):
         ServingEngine(net, num_slots=1, prompt_len=4, max_cache_len=8,
-                      prefix_cache_mode="lru")
+                      enable_prefix_cache=False)
     with pytest.raises(ValueError, match="host_cache_blocks"):
         ServingEngine(net, num_slots=1, prompt_len=4, max_cache_len=8,
                       host_cache_blocks=-1)
     e_none = ServingEngine(net, num_slots=1, prompt_len=4,
-                           max_cache_len=8, enable_prefix_cache=False)
+                           max_cache_len=8, prefix_cache_mode="none")
     assert e_none.prefix_cache_mode == "none" and e_none._radix is None
     e0 = ServingEngine(net, num_slots=1, prompt_len=4, max_cache_len=8,
                        host_cache_blocks=0)

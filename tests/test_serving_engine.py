@@ -186,7 +186,7 @@ def test_cancel_queued_request(netm):
 
 
 def test_block_pool_unit():
-    """Host-side BlockPool semantics: alloc/refcount/publish/LRU
+    """Host-side BlockPool semantics: alloc/refcount/tree-hold/LRU
     reclaim — no device work."""
     from paddle_tpu.inference.serving import BlockPool
     pool = BlockPool(4, block_len=2)
@@ -194,24 +194,30 @@ def test_block_pool_unit():
     blocks = pool.alloc(3)
     assert sorted(blocks) == [0, 1, 2] and pool.in_use() == 3
     assert pool.alloc(2) is None                  # only 1 left
-    pool.register(blocks[0], b"dg0")
-    pool.register(blocks[1], b"dg1")
-    pool.register(blocks[2], b"dg1")      # duplicate content: first wins
-    assert pool.lookup(b"dg1") == blocks[1]
+    reclaimed = []
+    pool.reclaim_cb = reclaimed.append
+    pool.tree_hold(blocks[0])
+    pool.tree_hold(blocks[1])
     for blk in blocks:
         pool.unpin(blk)
-    # published blocks park in the LRU (still mapped), others free
+    # tree-held blocks park in the LRU (still mapped), the other frees
     assert pool.available() == 4 and pool.cached() == 2
-    assert pool.lookup(b"dg0") == blocks[0]
-    hit = pool.lookup(b"dg1")
+    assert list(pool._tree_lru) == [blocks[0], blocks[1]]
+    hit = blocks[1]
     pool.pin(hit)                                 # prefix hit re-pins
     assert pool.cached() == 1 and pool.in_use() == 1
-    # exhausting the free list reclaims the LRU (dg0 unmaps)
+    with pytest.raises(RuntimeError, match="unpinned block"):
+        pool.tree_hold(blocks[2])                 # hold needs a pin
+    # exhausting the free list reclaims the LRU: free list first, then
+    # the oldest tree-held block, one reclaim_cb call for the alloc
     got = pool.alloc(3)
-    assert len(got) == 3 and pool.lookup(b"dg0") is None
+    assert len(got) == 3 and got[-1] == blocks[0]
+    assert reclaimed == [[blocks[0]]] and blocks[0] not in pool._tree_ref
     assert pool.alloc(1) is None                  # truly empty now
     pool.unpin(hit)
-    assert pool.lookup(b"dg1") == hit             # still cached
+    assert hit in pool._tree_lru and pool.cached() == 1   # still cached
+    pool.tree_touch(hit)
+    assert pool.check()
     with pytest.raises(RuntimeError, match="double free"):
         pool.unpin(hit)
 
@@ -434,42 +440,12 @@ def test_int8_kv_parity_trace_and_scheduling(netm):
     assert s_q["kv_bytes_swept"] * 2 < s_f["kv_bytes_swept"]
 
 
-def test_int8_blockpool_digest_dtype_separation(netm):
-    """Prefix digests are salted with the KV cache dtype: the same
-    prompt yields DISJOINT digest chains for bf16 vs int8 engines, so
-    a block published under one dtype can never be mapped into a cache
-    of the other (their arena bytes differ)."""
-    from paddle_tpu.inference.serving import BlockPool, _block_digests
-    cfg, net = netm
-    ids = np.arange(12, dtype=np.int32)
-    d_f = _block_digests(ids, 12, 4, salt=b"ptpu-paged-kv/float32")
-    d_q = _block_digests(ids, 12, 4, salt=b"ptpu-paged-kv/int8")
-    assert len(d_f) == len(d_q) == 3
-    assert not set(d_f) & set(d_q)
-    # a pool holding the float engine's published block misses every
-    # int8 probe of the same prefix
-    pool = BlockPool(4, 4)
-    (blk,) = pool.alloc(1)
-    pool.register(blk, d_f[0])
-    assert pool.lookup(d_f[0]) == blk
-    assert all(pool.lookup(dg) is None for dg in d_q)
-    # engines derive the salt from their arena dtype
-    e_f = ServingEngine(net, num_slots=1, prompt_len=P, max_cache_len=C,
-                        compute_dtype="float32")
-    e_q = ServingEngine(net, num_slots=1, prompt_len=P, max_cache_len=C,
-                        compute_dtype="float32", kv_cache_dtype="int8")
-    assert e_f._digest_salt != e_q._digest_salt
-    assert b"int8" in e_q._digest_salt
-
-
 def test_int8_engine_smoke_forced_gate(monkeypatch):
     """The int8 engine end to end with the Pallas gate forced open: the
     v5e cannot DMA scale planes of ``H_kv < 128`` lanes (PR 22), so the
     gate sends the engine's decode dispatches to the dequantizing XLA
-    view under the named reason ``int8_scale_lanes`` — never into the
-    kernel Mosaic refuses, never under ``pallas_unavailable``.  (The
-    dequant-in-kernel variants keep their direct-call parity tests in
-    ``test_pallas_kernels.py``.)"""
+    view under the named reason ``int8_scale_lanes`` — never under
+    ``pallas_unavailable``."""
     from paddle_tpu.observability.metrics import get_registry
     from paddle_tpu.ops.pallas import decode_attention as da
     monkeypatch.setattr(da, "pallas_enabled", lambda: True)
@@ -496,6 +472,42 @@ def test_int8_engine_smoke_forced_gate(monkeypatch):
         assert (r.output >= 0).all() and (r.output < cfg.vocab_size).all()
     assert route.value(decision="xla",
                        reason="int8_scale_lanes") > base
+
+
+def test_prefix_reclaim_and_admission_valve(netm):
+    """Refcount-exhaustion corners on a 4-block pool: (a) a retired
+    request's registered blocks stay mapped (tree LRU) and serve a
+    later submit-time pin; (b) a queue head that cannot allocate while
+    a LATER request's submit-time pin holds a block and NOTHING is
+    active triggers the release valve — without it the scheduler would
+    spin forever and run() would blow max_iters; the head's allocation
+    then reclaims the whole LRU (demoted to the host tier, whose
+    behavior tests/test_prefixcache.py covers) — and outputs still
+    match the oracle throughout."""
+    cfg, net = netm
+    rng = np.random.default_rng(9)
+    shared = rng.integers(0, cfg.vocab_size, (4,)).astype(np.int32)
+    eng = ServingEngine(net, num_slots=2, prompt_len=P, max_cache_len=8,
+                        steps_per_call=2, block_len=2, chunk_len=4,
+                        num_blocks=4, compute_dtype="float32")
+    req_a = eng.submit(shared, max_new_tokens=1)     # 2 blocks, holds 2
+    eng.run(max_iters=100)
+    assert eng.stats()["prefix_cached_blocks"] == 2  # parked, mapped
+    # head X needs all 4 blocks; Y (submitted after) pins a cached one
+    req_x = eng.submit(
+        rng.integers(0, cfg.vocab_size, (6,)).astype(np.int32),
+        max_new_tokens=3)                            # 4 blocks, no match
+    req_y = eng.submit(shared, max_new_tokens=1)
+    assert len(req_y.matched) == 1                   # (a) submit-time hit
+    assert eng._pool.available() == 3                # X cannot allocate
+    done = eng.run(max_iters=300)                    # (b) valve or hang
+    assert {r.request_id for r in done} == {req_x.request_id,
+                                            req_y.request_id}
+    s = eng.stats()
+    assert s["blocks_in_use"] == 0 and eng._pool.check()
+    for req, n, m in ((req_a, 4, 1), (req_x, 6, 3), (req_y, 4, 1)):
+        np.testing.assert_array_equal(
+            req.output, _oracle(net, _pad(req.prompt[:n]), n, m))
 
 
 # ---------------------------------------------------------------------------
@@ -577,31 +589,6 @@ def test_eos_frees_slot_early(netm):
 
 
 @pytest.mark.slow
-def test_static_batching_mode_gang_schedules(netm):
-    """The baseline arm: static_batching only admits into an EMPTY
-    pool, so a short request finishing early cannot be backfilled —
-    but outputs still match the oracle (scheduling never changes
-    per-request math)."""
-    cfg, net = netm
-    rng = np.random.default_rng(4)
-    eng = ServingEngine(net, num_slots=2, prompt_len=P, max_cache_len=C,
-                        steps_per_call=1, compute_dtype="float32",
-                        static_batching=True)
-    reqs = []
-    for max_new in (7, 2, 5):
-        ids = rng.integers(0, cfg.vocab_size, (P,)).astype(np.int32)
-        reqs.append((ids, eng.submit(ids, max_new_tokens=max_new)))
-    assert len(eng.run()) == 3
-    # gang 1 = requests 0+1 decoding together for max(7,2) steps; the
-    # 3rd request only starts after BOTH finish -> occupancy below the
-    # continuous engine's on the same trace
-    assert eng.stats()["mean_slot_occupancy"] < 1.0
-    for ids, req in reqs:
-        np.testing.assert_array_equal(
-            req.output, _oracle(net, ids, P, req.max_new_tokens))
-
-
-@pytest.mark.slow
 def test_paged_fragmentation_stress(netm):
     """Fragmentation + cancel-mid-run over a tight pool: 8 mixed
     requests (some sharing a prefix) through 3 slots and only 14
@@ -642,49 +629,6 @@ def test_paged_fragmentation_stress(netm):
 
 
 @pytest.mark.slow
-def test_prefix_reclaim_and_admission_valve(netm):
-    """Refcount-exhaustion corners on a 4-block pool: (a) a retired
-    request's published blocks stay mapped (LRU) and serve a later
-    submit-time pin; (b) a queue head that cannot allocate while a
-    LATER request's submit-time pin holds a block and NOTHING is
-    active triggers the release valve — without it the scheduler would
-    spin forever and run() would blow max_iters; (c) the head's
-    allocation then reclaims the whole LRU, so the shared prefix
-    re-misses at the sharer's admission — and outputs still match the
-    oracle throughout.  Pinned to the DIGEST cache mode: part (c)'s
-    reclaim-forgets semantics is exactly what the tiered radix mode
-    (the default) replaces — its demote-to-host behavior is covered
-    by tests/test_prefixcache.py."""
-    cfg, net = netm
-    rng = np.random.default_rng(9)
-    shared = rng.integers(0, cfg.vocab_size, (4,)).astype(np.int32)
-    eng = ServingEngine(net, num_slots=2, prompt_len=P, max_cache_len=8,
-                        steps_per_call=2, block_len=2, chunk_len=4,
-                        num_blocks=4, compute_dtype="float32",
-                        prefix_cache_mode="digest")
-    req_a = eng.submit(shared, max_new_tokens=1)     # 2 blocks, publishes 2
-    eng.run(max_iters=100)
-    assert eng.stats()["prefix_cached_blocks"] == 2  # parked, mapped
-    # head X needs all 4 blocks; Y (submitted after) pins a cached one
-    req_x = eng.submit(
-        rng.integers(0, cfg.vocab_size, (6,)).astype(np.int32),
-        max_new_tokens=3)                            # 4 blocks, no match
-    req_y = eng.submit(shared, max_new_tokens=1)
-    assert len(req_y.matched) == 1                   # (a) submit-time hit
-    done = eng.run(max_iters=300)                    # (b) valve or hang
-    assert {r.request_id for r in done} == {req_x.request_id,
-                                            req_y.request_id}
-    s = eng.stats()
-    # (c) the valve released Y's pin and X's alloc unmapped the LRU:
-    # nobody scored an admission-time hit in this engine's lifetime
-    assert s["prefix_hits"] == 0 and s["prefix_misses"] == 4
-    assert s["blocks_in_use"] == 0
-    for req, n, m in ((req_a, 4, 1), (req_x, 6, 3), (req_y, 4, 1)):
-        np.testing.assert_array_equal(
-            req.output, _oracle(net, _pad(req.prompt[:n]), n, m))
-
-
-@pytest.mark.slow
 def test_gpt_paged_serving_parity():
     """The GPT chunk/paged path (learned positions, MHA): engine output
     equals per-request greedy generate() with chunked prefill and
@@ -709,283 +653,3 @@ def test_gpt_paged_serving_parity():
             seq_lens=np.array([seq_len]), max_new_tokens=max_new,
             max_cache_len=C, compute_dtype="float32")._value)[0]
         np.testing.assert_array_equal(req.output, want)
-
-
-@pytest.mark.slow
-def test_bench_llm_serving_section():
-    """The bench.py llm_serving section end to end on CPU (slow: full
-    trace through both arms): emits tokens/s, p50/p99 latency and
-    occupancy for continuous AND static arms, plus the shared-prefix
-    A/B (prefix cache on/off)."""
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench_mod", os.path.join(os.path.dirname(__file__), "..",
-                                  "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    out = bench._bench_serving(False)
-    for k in ("tokens_per_s", "static_tokens_per_s", "p50_latency_ms",
-              "p99_latency_ms", "static_p50_latency_ms",
-              "static_p99_latency_ms", "mean_slot_occupancy",
-              "vs_static", "prefix"):
-        assert k in out, k
-    assert out["tokens_per_s"] > 0
-    assert 0.0 < out["mean_slot_occupancy"] <= 1.0
-    assert out["mean_slot_occupancy"] >= out["static_slot_occupancy"]
-    pfx = out["prefix"]
-    for k in ("tokens_per_s", "no_cache_tokens_per_s", "vs_no_cache",
-              "mean_ttft_ms", "no_cache_mean_ttft_ms",
-              "prefix_hit_rate", "peak_blocks_in_use", "prefill_chunks",
-              "no_cache_prefill_chunks"):
-        assert k in pfx, k
-    assert 0.0 < pfx["prefix_hit_rate"] <= 1.0
-    # hits skip chunks; the cached arm must compute strictly fewer
-    assert pfx["prefill_chunks"] < pfx["no_cache_prefill_chunks"]
-    tiered = out["prefix_tiered"]
-    for k in ("block_len", "hbm_blocks", "system_len", "turns",
-              "conversations", "tiered", "digest", "no_cache",
-              "hit_tokens_vs_digest", "ttft_vs_digest"):
-        assert k in tiered, k
-    for arm in ("tiered", "digest", "no_cache"):
-        for k in ("tokens_per_s", "mean_ttft_ms", "hit_tokens",
-                  "host_hits", "host_swapin_blocks", "swapin_bytes",
-                  "prefill_chunks"):
-            assert k in tiered[arm], (arm, k)
-    # the acceptance gate: the tiered radix cache beats the PR-3
-    # digest cache on the multi-turn trace — strictly more cache
-    # tokens served (host-tier retention), strictly fewer recomputed
-    # chunks, and real host->HBM swap-in traffic
-    assert tiered["tiered"]["hit_tokens"] > tiered["digest"]["hit_tokens"]
-    assert tiered["tiered"]["prefill_chunks"] < \
-        tiered["digest"]["prefill_chunks"]
-    assert tiered["tiered"]["host_swapin_blocks"] > 0
-    assert tiered["tiered"]["swapin_bytes"] > 0
-    assert tiered["digest"]["host_swapin_blocks"] == 0
-    assert tiered["no_cache"]["hit_tokens"] == 0
-    # fewer chunks shows up as lower mean TTFT on a quiet box (~0.93x
-    # measured solo; the deterministic gates above are the primary
-    # result).  The bound is deliberately a STRUCTURAL-regression
-    # gate, not a perf gate: swap-program compiles landing inside the
-    # timed window measured ~2.4x, while 2-core box contention alone
-    # has measured up to ~1.3x on a correct build
-    assert tiered["ttft_vs_digest"] < 2.0
-    kvq = out["kv_int8"]
-    for k in ("baseline_dtype", "tokens_per_s", "baseline_tokens_per_s",
-              "vs_baseline", "achieved_GBps", "baseline_achieved_GBps",
-              "kv_bytes_swept", "baseline_kv_bytes_swept",
-              "token_agreement", "engine_token_agreement",
-              "delta_nll_pct", "gate"):
-        assert k in kvq, k
-    # the whole point: the int8 arm models a fraction of the bytes, and
-    # the teacher-forced quality gate holds
-    assert kvq["kv_bytes_swept"] * 2 < kvq["baseline_kv_bytes_swept"]
-    assert kvq["gate"]["token_agreement_ok"]
-    assert kvq["gate"]["nll_ok"]
-    wq = out["weight_quant"]
-    for k in ("baseline_dtype", "baseline_tokens_per_s",
-              "baseline_achieved_GBps", "baseline_weight_bytes_swept",
-              "forced_tokens", "int8", "int4", "gate"):
-        assert k in wq, k
-    for arm in ("int8", "int4"):
-        for k in ("tokens_per_s", "achieved_GBps",
-                  "weight_bytes_swept", "token_agreement",
-                  "decisive_token_agreement", "engine_token_agreement",
-                  "delta_nll_pct", "token_agreement_ok", "nll_ok"):
-            assert k in wq[arm], (arm, k)
-    # deterministic gates: quality per quantized dtype, strictly
-    # shrinking modeled weight sweep, scheduling identity, and the
-    # forced-enable route proof that both bit widths dispatch Pallas
-    assert wq["gate"]["token_agreement_ok"]
-    assert wq["gate"]["nll_ok"]
-    assert wq["gate"]["bytes_order_ok"]
-    # the decisive-margin filter must not hollow out the token gate
-    assert wq["decisive_frac"] > 0.5
-    assert wq["gate"]["dispatch_parity_ok"]
-    assert wq["gate"]["route_ok"]
-    assert wq["baseline_weight_bytes_swept"] \
-        > wq["int8"]["weight_bytes_swept"] \
-        > wq["int4"]["weight_bytes_swept"] > 0
-    spec = out["spec"]
-    for k in ("k", "tokens_per_s", "no_spec_tokens_per_s", "vs_no_spec",
-              "mean_accepted_len", "acceptance_rate", "drafts_per_token",
-              "draft_hit_rate", "accepted_length_le",
-              "accepted_length_counts"):
-        assert k in spec, k
-    # the repetitive trace really speculates: drafts verify at a mean
-    # accepted length > 1 and the arm beats the non-speculative engine
-    assert spec["mean_accepted_len"] > 1.0
-    assert spec["vs_no_spec"] > 1.0
-    assert 0.0 < spec["acceptance_rate"] <= 1.0
-    # the distribution and the verify counter cover the same window
-    assert sum(spec["accepted_length_counts"]) == spec["verify_steps"]
-    samp = out["sampling"]
-    for k in ("temperature", "top_k", "greedy_tokens_per_s",
-              "sampled_tokens_per_s", "spec_sampled_tokens_per_s",
-              "sampled_vs_greedy", "spec_sampled_vs_sampled",
-              "sampled_tokens", "resamples", "mean_accepted_len",
-              "greedy_spec_mean_accepted_len", "accepted_len_delta",
-              "acceptance_rate"):
-        assert k in samp, k
-    # the sampled arms really sampled (and spec-sampling really hit
-    # the residual-resample branch at least once on this trace)
-    assert samp["sampled_tokens"] > 0
-    assert samp["resamples"] > 0
-    assert samp["sampled_tokens_per_s"] > 0
-    assert samp["spec_sampled_tokens_per_s"] > 0
-    ov = out["overload"]
-    for k in ("p99_ttft_ms", "no_preempt_p99_ttft_ms",
-              "ttft_vs_no_preempt", "preemptions", "swap_blocks_out",
-              "short_delay_slo_ms", "completion_rate",
-              "no_preempt_completion_rate", "slo_timeouts",
-              "no_preempt_slo_timeouts", "shed_demo"):
-        assert k in ov, k
-    # the preempt arm really preempted, and preemption improves BOTH
-    # p99 TTFT and completion rate on the bursty trace
-    assert ov["preemptions"] >= 1 and ov["swap_blocks_out"] > 0
-    assert ov["p99_ttft_ms"] < ov["no_preempt_p99_ttft_ms"]
-    assert ov["completion_rate"] > ov["no_preempt_completion_rate"]
-    assert ov["no_preempt_slo_timeouts"] > ov["slo_timeouts"]
-    assert ov["shed_demo"] == {"rejected": 1, "evicted": 1}
-    # PR 9: goodput sub-objects on the spec + overload arms — gated
-    # ONLY on deterministic token counts (conservation is exact
-    # integer equality; TPOT/SLO wall numbers ride along ungated)
-    for arm_g in (spec["goodput"], ov["goodput"]):
-        for k in ("useful_tokens", "wasted_tokens",
-                  "dispatched_tokens", "wasted_by_reason", "goodput",
-                  "gate"):
-            assert k in arm_g, k
-        assert arm_g["gate"]["conservation_ok"]
-        assert arm_g["useful_tokens"] + arm_g["wasted_tokens"] \
-            == arm_g["dispatched_tokens"] > 0
-        # exact-bytes swap preemption never recomputes (the ledger's
-        # structural-zero claim, bench-checked too)
-        assert arm_g["wasted_by_reason"]["recompute_preempt"] == 0
-    # PR 10: the dispatch-ahead A/B — gated ONLY on deterministic
-    # counters (token-exact outputs, equal dispatch/token counts,
-    # real pipelining, syncs confined to the documented reasons);
-    # tokens/s and the host/overlap second sums ride along ungated
-    aa = out["async"]
-    for k in ("tokens_per_s", "sync_tokens_per_s", "vs_sync",
-              "async_syncs", "async_harvests", "syncs_by_reason",
-              "host_ms", "dispatch_ms", "overlap_ms", "sync_host_ms",
-              "sync_dispatch_ms", "gate"):
-        assert k in aa, k
-    assert aa["gate"]["token_exact"]
-    assert aa["gate"]["dispatch_counts_equal"]
-    assert aa["gate"]["pipelined"]
-    assert aa["gate"]["sync_reasons_documented"]
-    # PR 14: the depth-S finish-bitmap/fused-window A/B — gated ONLY
-    # on deterministic counters (token-exact across all three arms,
-    # admission order identical, event stories byte-identical modulo
-    # step/lag, eos syncs and dispatches strictly lower at depth S,
-    # depth gauge hwm == S); walls ride along ungated
-    ad = out["async_depth"]
-    for k in ("depth", "eos_token_id", "tokens_per_s",
-              "depth1_tokens_per_s", "lockstep_tokens_per_s",
-              "eos_syncs", "block_dispatches", "async_harvests",
-              "depth_hwm", "host_ms", "dispatch_ms", "overlap_ms",
-              "gate"):
-        assert k in ad, k
-    for g in ("token_exact", "eos_syncs_strictly_lower",
-              "dispatches_strictly_lower",
-              "admission_order_identical", "event_stories_identical",
-              "depth_gauge_reaches_s"):
-        assert ad["gate"][g], g
-    assert ad["eos_syncs"]["depthS"] < ad["eos_syncs"]["depth1"]
-    # the spec arm's waste is dominated by rejected draft positions
-    assert spec["goodput"]["wasted_by_reason"]["spec_reject"] > 0
-    assert "no_spec_goodput" in spec
-    assert "mean_tpot_ms" in spec and "no_spec_mean_tpot_ms" in spec
-    # overload SLO attainment (wall-shaped, reported not gated) and
-    # the no-preempt arm's goodput comparison key exist
-    for k in ("slo_attained", "slo_missed", "no_preempt_slo_attained",
-              "no_preempt_slo_missed", "no_preempt_goodput",
-              "mean_tpot_ms"):
-        assert k in ov, k
-    # PR 11: the multi-tenant LoRA arm — deterministic gates only
-    # (K=1 merged-weights parity, gather==dispatch route counts, the
-    # steady tenant strictly improving under fair-share); tokens/s
-    # and p99 TTFT ride along ungated
-    lo = out["lora"]
-    for k in (1, 4, 8):
-        assert lo["adapters"][k]["gate_gather_count"], k
-        assert lo["adapters"][k]["tokens_per_s"] > 0
-    assert lo["adapters"][1]["gate_k1_token_exact"]
-    assert lo["starvation"]["gate_steady_improves"]
-    assert lo["starvation"]["gate_reordered"]
-    assert "k8_vs_k1" in lo
-    # PR 12: the front-door router arm — deterministic gates only
-    # (token-exact outputs across arms, prefix hit tokens strictly
-    # higher and adapter swap-ins strictly lower under affinity);
-    # tokens/s rides along ungated
-    ro = out["router"]
-    for k in ("replicas", "turns", "conversations", "affinity",
-              "round_robin", "hit_tokens_vs_round_robin"):
-        assert k in ro, k
-    for arm in ("affinity", "round_robin"):
-        for k in ("tokens_per_s", "prefix_hit_tokens",
-                  "adapter_swap_ins", "routed_by_reason",
-                  "prefix_affinity_tokens", "adapter_affinity_hits"):
-            assert k in ro[arm], (arm, k)
-    assert ro["gate_token_exact"]
-    assert ro["gate_prefix_hits_higher"]
-    assert ro["gate_swap_ins_lower"]
-    # round-robin never consulted affinity; affinity never cycled
-    assert ro["round_robin"]["prefix_affinity_tokens"] == 0
-    assert ro["affinity"]["routed_by_reason"]["round_robin"] == 0
-    # PR 15: the replica-failover arm — deterministic gates only
-    # (token-exact recovery, completion 1.0 vs < 1.0, exact migrated-
-    # block and retry counts); walls report-only
-    fo = out["failover"]
-    for k in ("replicas", "n_requests", "reference", "on", "off",
-              "affected_requests", "victim_parcel_blocks"):
-        assert k in fo, k
-    for arm in ("reference", "on", "off"):
-        for k in ("completion_rate", "failed", "replica_faults",
-                  "failover_requests", "migrated_blocks", "wall_ms"):
-            assert k in fo[arm], (arm, k)
-    assert fo["gate_on_token_exact"]
-    assert fo["gate_on_completes_all"]
-    assert fo["gate_off_loses_requests"]
-    assert fo["gate_migrated_blocks_exact"]
-    assert fo["gate_retries_exact"]
-    assert fo["reference"]["replica_faults"] == 0
-    # PR 18: the multichip arm — 8-virtual-device child process,
-    # deterministic counter gates only (tp token-exact + dispatch
-    # parity + sharded-route proof, dp token-exact across the
-    # topology change, exact shard-group labels); scaling/occupancy
-    # walls report-only
-    mcp = out["multichip"]
-    assert "error" not in mcp, mcp.get("error")
-    assert mcp["devices"] == 8
-    assert mcp["gate_tp_token_exact"]
-    assert mcp["gate_tp_dispatch_parity"]
-    assert mcp["gate_sharded_route"]
-    assert mcp["gate_dp_token_exact"]
-    assert mcp["gate_shard_groups"]
-    assert mcp["dp"]["shard_groups"] == ["tp2@d0", "tp2@d2"]
-    for k in ("scaling", "tokens_per_s", "per_replica_occupancy"):
-        assert k in mcp["dp"], k
-    # PR 20: the disaggregated prefill/decode arm — deterministic
-    # counter gates only (token-exact vs the monolithic fleet, exact
-    # chunk-final handoff count, parcel-block conservation through
-    # the router stage, zero prefill work on the decode replica,
-    # rerun-identical counters); TTFT/TPOT walls report-only
-    dg = out["disagg"]
-    assert "error" not in dg, dg.get("error")
-    for k in ("replicas", "n_requests", "max_new", "monolithic",
-              "disagg"):
-        assert k in dg, k
-    for arm in ("monolithic", "disagg"):
-        for k in ("roles", "counters", "mean_ttft_steps",
-                  "mean_tpot_steps", "wall_ms"):
-            assert k in dg[arm], (arm, k)
-    assert dg["disagg"]["roles"] == ["prefill", "decode"]
-    assert dg["gate_token_exact"]
-    assert dg["gate_handoffs_exact"]
-    assert dg["gate_parcel_blocks_exact"]
-    assert dg["gate_no_prefill_on_decode"]
-    assert dg["gate_deterministic"]
-    # the monolithic fleet never hands off — roles are pure policy
-    assert sum(dg["monolithic"]["counters"]["handoffs"]) == 0

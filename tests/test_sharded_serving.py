@@ -37,13 +37,13 @@ from paddle_tpu.observability.flightrec import FlightRecorder
 from paddle_tpu.observability.metrics import (MetricsRegistry,
                                               get_registry)
 from paddle_tpu.ops.pallas import decode_attention as da
+from scripted_drafter import ScriptedDrafter
 
 
 @pytest.fixture(scope="module")
 def net2():
     # module-scoped fixtures run BEFORE the autouse _reseed, so seed
-    # explicitly: the spec-decode row's drafted 2-cycle and the prefix
-    # hit depend on these exact weights
+    # explicitly
     paddle.seed(2024)
     cfg = models.LlamaConfig(
         vocab_size=96, hidden_size=64, intermediate_size=96,
@@ -54,26 +54,26 @@ def net2():
     return cfg, net
 
 
-def _mk(net, mesh=None, kv_dtype=None, reg=None, fr=None):
+def _mk(net, mesh=None, kv_dtype=None, reg=None, fr=None, drafter=None):
     return ServingEngine(
         net, num_slots=2, prompt_len=8, max_cache_len=32,
         steps_per_call=2, block_len=4, num_blocks=24, chunk_len=4,
         compute_dtype="float32", kv_cache_dtype=kv_dtype,
         registry=reg if reg is not None else MetricsRegistry(),
-        flight_recorder=fr, mesh=mesh)
+        flight_recorder=fr, mesh=mesh, drafter=drafter)
 
 
-def _combined_trace(eng, prompts):
+def _combined_trace(eng, prompts, spec=2):
     """Prefix hit + chunked prefill + spec verify on one engine: r0
-    seeds the radix tree; r2 rides spec-decode (its greedy stream
-    enters a 2-cycle, so the prompt-lookup drafter really proposes and
+    seeds the radix tree; r2 rides spec-decode (the engine's scripted
+    drafter proposes r2's own stream with wrong tokens planted, and
     max_new=8 leaves k_eff room for the verify to dispatch); r3 shares
     r0's first (block-aligned) 4 tokens and is QUEUED behind the 2
     slots, so its admission lands after r0's blocks hit the radix tree
     — a real prefix hit, not a same-step miss."""
     rs = [eng.submit(prompts[0], max_new_tokens=4),
           eng.submit(prompts[1], max_new_tokens=5),
-          eng.submit(prompts[2], max_new_tokens=8, spec_decode=2),
+          eng.submit(prompts[2], max_new_tokens=8, spec_decode=spec),
           eng.submit(prompts[3], max_new_tokens=4)]
     eng.run()
     return [r.output.tolist() for r in rs]
@@ -111,10 +111,14 @@ def tp_ab(net2):
     tail2 = rng.integers(0, cfg.vocab_size, (3,)).astype(np.int32)
     prompts = [base,
                np.concatenate([base[:4], tail]),
-               # r2's repeated 3-gram drives its greedy stream into a
-               # 2-cycle the prompt-lookup drafter locks onto
                np.concatenate([pat, pat, pat[:1]]),
                np.concatenate([base[:4], tail2])]
+    # r2's own greedy stream, from the same trace without speculation:
+    # what the scripted drafter of both arms proposes from
+    plain = _combined_trace(_mk(net, kv_dtype="int8"), prompts, spec=None)
+
+    def drafter():
+        return ScriptedDrafter([(prompts[2], plain[2])], cfg.vocab_size)
     route = get_registry().counter("pallas.decode_attention.route",
                                    labels=("decision", "reason"))
 
@@ -124,13 +128,15 @@ def tp_ab(net2):
 
     fr1, fr2 = FlightRecorder(), FlightRecorder()
     r1, r2 = MetricsRegistry(), MetricsRegistry()
-    e1 = _mk(net, kv_dtype="int8", reg=r1, fr=fr1)
+    e1 = _mk(net, kv_dtype="int8", reg=r1, fr=fr1, drafter=drafter())
     base_hits = shard_hits()
     out1 = _combined_trace(e1, prompts)
     assert shard_hits() == base_hits        # single-chip: no overlay
     mesh = build_mesh(mp=2, devices=jax.devices()[:2])
-    e2 = _mk(net, mesh=mesh, kv_dtype="int8", reg=r2, fr=fr2)
+    e2 = _mk(net, mesh=mesh, kv_dtype="int8", reg=r2, fr=fr2,
+             drafter=drafter())
     out2 = _combined_trace(e2, prompts)
+    assert out1 == plain                    # speculation changes no token
     return dict(e1=e1, e2=e2, out1=out1, out2=out2, fr1=fr1, fr2=fr2,
                 sharded_hits=shard_hits() - base_hits)
 

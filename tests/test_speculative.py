@@ -19,6 +19,7 @@ from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.inference.speculative import (ModelDrafter, NGramDrafter,
                                               accept_drafts,
                                               build_spec_verify)
+from scripted_drafter import ScriptedDrafter
 
 
 @pytest.fixture(scope="module")
@@ -145,12 +146,14 @@ def test_build_spec_verify_guards(netm):
 # ---------------------------------------------------------------------------
 
 def test_spec_parity_acceptance_rejection_rollback_eos(netm):
-    """The acceptance contract in one trace: a repetitive prompt (the
-    drafter locks on -> real acceptances), a random prompt (drafts
-    mismatch -> rejections + KV rollback), a plain request coexisting
-    in the same iterations, and an EOS cut mid-stream — every output
-    token-for-token identical to per-request greedy ``generate()`` AND
-    to the non-speculative engine on the same requests."""
+    """The acceptance contract in one trace: two speculative requests
+    whose drafts are their own greedy continuation with a wrong token
+    planted at fixed positions (``ScriptedDrafter``: real acceptances,
+    and rejections + KV rollback, whatever the weights), a plain
+    request coexisting in the same iterations, and an EOS cut
+    mid-stream — every output token-for-token identical to per-request
+    greedy ``generate()`` AND to the non-speculative engine on the same
+    requests."""
     cfg, net = netm
     rng = np.random.default_rng(0)
     pat = rng.integers(0, cfg.vocab_size, (3,)).astype(np.int32)
@@ -161,10 +164,14 @@ def test_spec_parity_acceptance_rejection_rollback_eos(netm):
     # tokens before EOS are unaffected by the eos config)
     eos = int(_oracle(net, rep, 12, 14)[3])
 
+    specs = [(rep, 12, 14, 3), (rnd, 10, 14, 3), (plain, 7, 6, None)]
+    drafter = ScriptedDrafter(
+        [(ids, _oracle(net, ids, n, mn, eos=eos))
+         for ids, n, mn, k in specs if k is not None], cfg.vocab_size)
     eng = ServingEngine(net, num_slots=2, prompt_len=P, max_cache_len=C,
                         steps_per_call=2, block_len=4, chunk_len=8,
-                        eos_token_id=eos, compute_dtype="float32")
-    specs = [(rep, 12, 14, 3), (rnd, 10, 14, 3), (plain, 7, 6, None)]
+                        eos_token_id=eos, compute_dtype="float32",
+                        drafter=drafter)
     reqs = [eng.submit(ids, max_new_tokens=mn, spec_decode=k)
             for ids, n, mn, k in specs]
     done = eng.run(max_iters=500)

@@ -22,6 +22,7 @@ from paddle_tpu.inference.llm import (LLMPredictor,
 from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.observability.flightrec import FlightRecorder
 from paddle_tpu.observability.metrics import MetricsRegistry
+from scripted_drafter import ScriptedDrafter
 
 P, C = 6, 32
 
@@ -259,21 +260,28 @@ def test_weight_quant_composes_spec_lora_async(netm):
                          registry=reg)
     store.register(LoraAdapter.random(cfg, "a", rank=2, seed=3,
                                       scale=0.2))
-    # steps_per_call=1 so the n-gram drafter gets a drafting
-    # opportunity every iteration (the spec suite's discipline)
+    prompts, _specs = _trace_prompts(cfg)
+    pat = np.random.default_rng(11).integers(
+        0, cfg.vocab_size, (3,)).astype(np.int32)
+    spec_ids = np.tile(pat, 2)
+    # the spec row's own greedy stream under int8 weights, from a plain
+    # engine: the scripted drafter proposes it with wrong tokens
+    # planted, so the row really drafts, accepts and rolls back
+    ref = _build(net, "int8")
+    r_ref = ref.submit(spec_ids, max_new_tokens=8)
+    ref.run(max_iters=200)
+    # steps_per_call=1 so the drafter gets a drafting opportunity
+    # every iteration (the spec suite's discipline)
     eng = ServingEngine(net, num_slots=2, prompt_len=P, max_cache_len=C,
                         steps_per_call=1, block_len=4, chunk_len=4,
                         compute_dtype="float32", weight_dtype="int8",
                         adapter_store=store, async_depth=2,
-                        registry=reg)
-    prompts, _specs = _trace_prompts(cfg)
-    # the host drafter proposes from repeats: a periodic prompt makes
-    # the spec row really draft (and so really dispatch verifies)
-    pat = np.random.default_rng(11).integers(
-        0, cfg.vocab_size, (3,)).astype(np.int32)
+                        registry=reg,
+                        drafter=ScriptedDrafter([(spec_ids, r_ref.output)],
+                                                cfg.vocab_size))
     r_lora = eng.submit(prompts[0], max_new_tokens=8, arrival_time=0.0,
                         adapter="a")
-    r_spec = eng.submit(np.tile(pat, 2), max_new_tokens=8,
+    r_spec = eng.submit(spec_ids, max_new_tokens=8,
                         arrival_time=0.0, spec_decode=2)
     r_plain = eng.submit(prompts[2], max_new_tokens=8, arrival_time=0.0)
     done = eng.run(max_iters=200)
@@ -282,8 +290,10 @@ def test_weight_quant_composes_spec_lora_async(netm):
     for r in (r_lora, r_spec, r_plain):
         assert r.state == "finished"
         assert len(r.output) == 8
+    np.testing.assert_array_equal(r_spec.output, r_ref.output)
     reg = eng.metrics_registry
     assert reg.get("serving.spec.verify_steps").value() > 0
+    assert reg.get("serving.spec.accepted_tokens").value() > 0
     assert reg.get("serving.lora.gathers").value() > 0
     assert reg.get("serving.weights.bytes_swept").value() > 0
     assert reg.get("serving.weights.quant_dtype").value(dtype="int8") == 1

@@ -46,8 +46,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     "hand-maintained invariants")
     ap.add_argument("paths", nargs="*",
                     help="files/directories to scan (default: "
-                         "paddle_tpu tools bench.py, under the repo "
-                         "root)")
+                         "paddle_tpu tools, under the repo root)")
     ap.add_argument("--root", default=None,
                     help="tree root for path resolution and display "
                          "(default: the repo root)")

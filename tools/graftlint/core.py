@@ -33,8 +33,8 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 # the default scan surface, mirroring tools/check_metrics_names.py:
-# the serving/observability tree, the lint/bench tooling, the bench
-DEFAULT_PATHS = ("paddle_tpu", "tools", "bench.py")
+# the serving/observability tree and the lint/profiling tooling
+DEFAULT_PATHS = ("paddle_tpu", "tools")
 
 _DISABLE_RE = re.compile(r"#\s*graftlint:\s*disable=([a-z0-9_,\-]+)")
 _PLAN_PHASE_RE = re.compile(r"#\s*graftlint:\s*plan-phase\b")

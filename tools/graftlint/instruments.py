@@ -274,14 +274,13 @@ def _tree_registrations(relpath: str, tree: ast.Module):
 def iter_registrations(root: str = REPO_ROOT):
     """Yield (path, lineno, kind, name, labels) for every static
     registration with a literal name over the legacy scan surface
-    (paddle_tpu/, tools/, bench.py — the shim path; the graftlint
+    (paddle_tpu/, tools/ — the shim path; the graftlint
     driver goes through :func:`run_pass` and the shared parse
     instead); ``labels`` is a tuple of label names or None when
     unlabeled/dynamic."""
     scan_dirs = [os.path.join(root, "paddle_tpu"),
                  os.path.join(root, "tools")]
-    scan_files = [os.path.join(root, "bench.py")]
-    paths = list(scan_files)
+    paths = []
     for d in scan_dirs:
         for dirpath, _dirnames, filenames in os.walk(d):
             if "__pycache__" in dirpath:

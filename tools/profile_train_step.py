@@ -17,8 +17,8 @@ cfg = LlamaConfig(vocab_size=32000, hidden_size=2048,
                   intermediate_size=8192, num_hidden_layers=L,
                   num_attention_heads=32, num_key_value_heads=8,
                   max_position_embeddings=2048, recompute=True,
-                  # "headline" = the bench.py configuration: remat dial
-                  # + chunked fused lm_head+CE + bf16 moments
+                  # "headline" = remat dial + chunked fused lm_head+CE
+                  # + bf16 moments
                   recompute_policy="save_attn_mlp" if HEADLINE else None,
                   recompute_policy_alt="save_attn" if HEADLINE else None,
                   recompute_policy_stride=2 if HEADLINE else 1,
