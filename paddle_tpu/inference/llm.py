@@ -503,8 +503,11 @@ def build_fused_decode_window(model, cfg: GenerationConfig,
 
     This is NOT ``steps_per_call=S*n`` at the engine level:
     ``steps_per_call`` is a static engine-wide granularity the
-    scheduler must honor every iteration (and drops to 1 whenever a
-    budget could exhaust mid-block), while a fused window is a
+    scheduler honors every iteration in which some rider is owed a
+    whole block (riders owed less finish inside it and freeze; it
+    drops to 1 only when every rider is inside its last
+    ``steps_per_call - 1`` tokens or a masked row is live, and such
+    a block harvests synchronously), while a fused window is a
     PER-ITERATION choice the plan phase makes only when the window is
     provably eventless (no chunk-final, no mask/penalty rows, no spec,
     no queue, budget headroom > S*n for every rider) — and the harvest

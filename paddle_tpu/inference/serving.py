@@ -46,12 +46,22 @@ prefill) design, restricted to what XLA's static shapes allow:
   time alongside the shared decode block — a long prompt no longer
   stalls in-flight decoding for its full prompt pass, and TTFT of
   queued requests overlaps decode instead of serializing behind it.
-  A ``step()`` runs ONE chunk while no more than ``steps_per_call``
-  slots wait for their prompt, and a ``steps_per_call``-th of the
-  waiting slots' chunks beyond that (``_prefill_chunks``): a backlog
-  of prompts (many slots, or a burst of arrivals) is worked off
-  geometrically instead of one chunk a step, which would hold a wide
-  batch at a fraction of its slots.
+  For each decode step its block will run, a ``step()`` runs ONE
+  chunk while no more than ``steps_per_call`` slots wait for their
+  prompt, and a ``steps_per_call``-th of the waiting slots' chunks
+  beyond that (``_prefill_chunks``): a backlog of prompts (many
+  slots, or a burst of arrivals) is worked off geometrically instead
+  of one chunk a step, which would hold a wide batch at a fraction of
+  its slots, and a slot vacated inside a block rides the next.
+- **Decode blocks**: a ``step()`` dispatches ONE compiled program of
+  ``steps_per_call`` decode steps whenever some rider is owed that
+  many tokens; riders owed fewer finish inside the block on the
+  device (the in-trace finish bitmap freezes them at their budget as
+  at an EOS) and the harvest hands each exactly its tokens.  Only a
+  mix whose every rider is inside its last ``steps_per_call - 1``
+  tokens, or one that holds a mask-constrained row, takes single
+  steps.  The host round trip (plan, table push, dispatch, fetch,
+  harvest) is paid once a block, not once a token.
 - **Paged reads**: decode attention goes through the block table — the
   Pallas flash-decode kernel gained a block-table DMA variant
   (``decode_attention_paged``; gate reasons ``paged_ok`` /
@@ -411,8 +421,10 @@ class _ServingInstruments:
         self.busy_slot_steps = r.counter(
             "serving.busy_slot_steps",
             "decode step x slot cells holding a live PLAIN-decode "
-            "request (spec-mode slots progress via verify forwards, "
-            "not decode steps, and are excluded — see serving.spec.*)")
+            "request: the cells each rider of a block is owed, so the "
+            "cells a row spends frozen behind its budget are not in it "
+            "(spec-mode slots progress via verify forwards, not decode "
+            "steps, and are excluded — see serving.spec.*)")
         self.block_dispatches = r.counter(
             "serving.block_dispatches", "compiled decode block calls")
         self.moe_expert_tokens = r.counter(
@@ -426,9 +438,10 @@ class _ServingInstruments:
             "row, summed over the (expert layer, decode step) pairs")
         self.tokens_emitted = r.counter(
             "serving.tokens_emitted", "tokens emitted to requests "
-            "(prefill first-tokens + decode-block harvest; "
-            "block-granular, so a request hitting EOS mid-block counts "
-            "its pad tail — exact only at steps_per_call=1)")
+            "(prefill first-tokens + the cells each rider of a decode "
+            "block is owed, counted at dispatch; exact to the budget, "
+            "while a request hitting EOS mid-block still counts the "
+            "cells behind it)")
         self.requests_submitted = r.counter(
             "serving.requests_submitted", "requests accepted into the queue")
         self.requests_finished = r.counter(
@@ -1809,10 +1822,11 @@ class ServingEngine:
         the same dispatch do DMA their (trash-routed) frontier, but
         that waste traffic is excluded so the counter reads as useful
         KV bytes, a conservative roofline basis."""
-        rows = sum(min(int(ix) // self.block_len + 1, self.max_blocks)
-                   * self.block_len
-                   for ix in last_indices)
-        self._m.kv_bytes_swept.inc(rows * self._kv_row_bytes)
+        blocks = np.minimum(
+            np.asarray(last_indices, np.int64) // self.block_len + 1,
+            self.max_blocks)
+        self._m.kv_bytes_swept.inc(
+            int(blocks.sum()) * self.block_len * self._kv_row_bytes)
 
     def _count_weight_sweep(self, forwards: int):
         """Modeled weight-streaming traffic: every dispatched forward
@@ -1951,7 +1965,9 @@ class ServingEngine:
         rider's token BUDGET can exhaust inside the dispatch (the plan
         knows budgets exactly — ``lag`` corrects host truth for steps
         still in flight — so budget finishes always harvest sync and
-        retire on the lockstep schedule).  EOS is depth-dependent: the
+        retire on the lockstep schedule; a ``steps_per_call`` block
+        under load nearly always holds one, since the plan dispatches
+        it while ANY rider needs all of it).  EOS is depth-dependent: the
         depth-1 pipeline keeps PR 10's contract (scheduling identity
         with lockstep ⇒ every EOS-configured iteration syncs), while
         async_depth >= 2 engines read EOS from the in-trace finish
@@ -2132,45 +2148,50 @@ class ServingEngine:
         earlier iteration of THIS dispatch.  Skipped cells follow the
         ``_count_kv_sweep`` convention (frozen rows excluded), which
         keeps the ledger and sweep counters exactly what a lockstep
-        engine would have charged."""
+        engine would have charged.
+
+        A rider may also finish INSIDE its segment, at its budget (the
+        plan dispatches the whole block while any rider needs all of
+        it) or at an EOS: one freeze protocol.  It takes exactly the
+        tokens up to its finish and retires there; the cells behind
+        held a frozen row, so they are pad in the ledger, absent from
+        the KV-sweep model, and no token of the request."""
         per, active = p.per_iter, p.active
         self._tok = tok
         self._lens = lens
         eos = self.cfg.eos_token_id
         t = self._clock()
         lag = self._step_idx - p.step_idx
-        sweep: List[int] = []
+        attrs = {"lag": lag} if lag else {}
+        steps = np.arange(per)
+        sweep: List[np.ndarray] = []
         for j in range(p.iters):
             gp: dict = {}      # tenant -> [useful, pad] this iteration
             for idx, i in enumerate(active):
                 req = p.reqs[idx]
                 if req.state != "decode":
                     continue           # ghost / finished-earlier rider
-                row = toks[i, j * per:(j + 1) * per]
+                # the cells this rider was live in: its budget may end
+                # inside the segment, and an EOS may end it sooner; the
+                # cells behind either held a frozen row
+                owed = min(per, req.remaining)
+                row = toks[i, j * per:j * per + owed]
+                at_eos = (np.flatnonzero(row == eos) if eos is not None
+                          else ())
+                hit_eos = len(at_eos) > 0
+                took = int(at_eos[0]) + 1 if hit_eos else owed
                 # per-step frontier, not the final lens: scanned step
                 # s scatters at index pre_lens+s and attends up to it
-                # — clamped to the row's final lens, where an EOS
-                # froze it mid-flight
-                base = int(p.pre_lens[i]) + j * per
-                sweep.extend(min(base + s, int(lens[i]))
-                             for s in range(per))
-                # tokens up to (and including) an EOS are useful, the
-                # frozen tail behind it is pad (empty at per == 1)
-                hit_eos = eos is not None and eos in row
-                useful_i = (int(np.flatnonzero(row == eos)[0]) + 1
-                            if hit_eos else per)
+                sweep.append(int(p.pre_lens[i]) + j * per + steps[:took])
                 cell = gp.setdefault(req.tenant, [0, 0])
-                cell[0] += useful_i
-                cell[1] += per - useful_i
-                attrs = {"steps": per}
-                if lag:
-                    # deterministic (a step delta, never wall): parity
-                    # comparisons against a sync engine strip it
-                    attrs["lag"] = lag
+                cell[0] += took
+                cell[1] += per - took
+                # ``lag`` is deterministic (a step delta, never wall):
+                # parity comparisons against a sync engine strip it
                 self._fr.emit("decode_block", req.request_id,
-                              p.step_idx, **attrs)
-                req.tokens.extend(int(x) for x in row)
-                req.remaining -= per
+                              p.step_idx, steps=took, **attrs)
+                req.tokens.extend(row[:took].tolist())
+                req.remaining -= took
                 if hit_eos or req.remaining == 0:
                     # the finish bitmap observed host-side: EOS in
                     # this iteration's segment, or the budget ran out
@@ -2190,7 +2211,8 @@ class ServingEngine:
                     self._finish(req, t, out, lag=lag)
             for tenant, (u, pad) in gp.items():
                 self._ledger(u, tenant=tenant, pad=pad)
-        self._count_kv_sweep(sweep)
+        if sweep:
+            self._count_kv_sweep(np.concatenate(sweep))
         # every scanned decode step streamed the whole weight set once
         self._count_weight_sweep(per * p.iters)
         self._done = done
@@ -2977,8 +2999,8 @@ class ServingEngine:
             self._m.latency.observe(req.latency)
         # per-output-token latency (TPOT), one observation per request
         # with >= 2 tokens: the decode-rate SLO metric TTFT cannot see
-        # (block-granular like tokens_emitted — a steps_per_call>1
-        # final block's pad tail is inside len(req.tokens) here)
+        # (len(req.tokens) is exact here: a rider that froze inside its
+        # final block took only the tokens up to its finish)
         n_out = len(req.tokens)
         req.n_emitted = n_out
         if req.first_token_time is not None and n_out >= 2:
@@ -3859,21 +3881,52 @@ class ServingEngine:
         mp.advance(int(req.tokens[-1]))
         return not np.asarray(mp.allowed(), bool).any()
 
+    def _block_steps(self, riders: List[int], lag: int):
+        """``(n, masked)``: the decode steps the block of ``riders`` runs,
+        read from their budgets and masks alone.  ``steps_per_call`` when
+        some rider is owed that many tokens or more (``remaining`` less
+        the ``lag`` steps still in flight), so the block is never longer
+        than work that exists: riders owed fewer finish inside it, on the
+        device.  One step when every rider is inside its last
+        ``steps_per_call - 1`` tokens, or when a mask-constrained row
+        (``masked``) must show the host each token before the next."""
+        masked = any(self._slots[i].sampling is not None and
+                     self._slots[i].sampling.mask_processor is not None
+                     for i in riders)
+        if masked or not riders:
+            return 1, masked
+        most = max(self._slots[i].remaining for i in riders) - lag
+        return (self.steps_per_call if most >= self.steps_per_call
+                else 1), masked
+
     # graftlint: plan-phase
     def _prefill_chunks(self, out: List[Request]):
-        """This step's prompt chunks: one, and where more than
+        """This step's prompt chunks, paced by the decode steps its block
+        will run: for each of those steps one chunk, and where more than
         ``steps_per_call`` slots wait for their prompt, one for every
         ``steps_per_call`` of them (FIFO, so the head of the line may
-        take several).  One chunk a step admits about one request a
-        step whatever the slots: a batch of hundreds of slots whose
-        requests live a hundred steps never fills, and every decode
-        step pays its whole weight sweep for a fraction of the rows.  A
-        prompt now waits for a chunk no longer than a decoded token
-        waits for its harvest, ``steps_per_call`` steps, and the live
-        rows' stall a step is bounded by that share of the backlog."""
-        for _ in range(max(1, -(-len(self._prefilling)
-                                // self.steps_per_call))):
-            self._prefill_chunk(out)
+        take several), until no slot waits.  The block's length is read
+        from the riders that are there before the chunks run
+        (``_block_steps``); the prompts that finish here only lengthen
+        it.  One chunk a step would admit about one request a step
+        whatever the slots: a batch of hundreds of slots whose requests
+        live a hundred decode steps never fills, and every decode step
+        pays its whole weight sweep for a fraction of the rows.  So a
+        prompt waits for its chunks no longer than a decoded token waits
+        for its harvest, one block: a slot vacated inside a block is
+        admitted, prefilled and riding by the next.  The live rows'
+        stall a decoded token stays that share of the backlog, whatever
+        the block's length."""
+        riders = [i for i, r in enumerate(self._slots)
+                  if r is not None and r.state == "decode"
+                  and r.spec_k is None]
+        n, _ = self._block_steps(riders, sum(p.n for p in self._pend_q))
+        for _ in range(n):
+            for _ in range(max(1, -(-len(self._prefilling)
+                                    // self.steps_per_call))):
+                self._prefill_chunk(out)
+            if not self._prefilling:
+                break
 
     # graftlint: plan-phase
     def _prefill_chunk(self, out: List[Request]):
@@ -4456,18 +4509,22 @@ class ServingEngine:
             return finished
         with _span("serving.plan", active=len(active),
                    queued=len(self._queue)):
-            # a full block only when no active request can finish inside it
-            # (a block never overshoots a budget or a block table); otherwise
-            # drop to exact iteration-level single steps.  Mask-constrained
-            # rows clamp the mix to single steps too: their bias plane is
-            # valid for exactly ONE emitted token — the host state machine
-            # must observe it before the next bias can be built.  The clamp
-            # prices ALL co-resident rows at one dispatch per token while a
-            # masked row is live (deliberate: masked workloads are latency-
-            # shaped and the alternative — freezing masked rows out of the
-            # n-step block via the done plane and feeding them a second
-            # 1-step dispatch per iteration — doubles dispatches and
-            # accounting paths for a mix this engine rarely sees)
+            # the whole block whenever some rider needs all of it (see
+            # ``_block_steps``): riders whose budget ends inside it finish
+            # on the device, freeze there, and the harvest hands each
+            # exactly the tokens it was owed.  Only a mix whose every
+            # rider is inside its last ``steps_per_call - 1`` tokens (a
+            # draining engine, a lone request's tail) takes exact single
+            # steps.  Mask-constrained rows clamp the mix to single steps
+            # too: their bias plane is valid for exactly ONE emitted
+            # token — the host state machine must observe it before the
+            # next bias can be built.  The clamp prices ALL co-resident
+            # rows at one dispatch per token while a masked row is live
+            # (deliberate: masked workloads are latency-shaped and the
+            # alternative — freezing masked rows out of the n-step block
+            # via the done plane and feeding them a second 1-step
+            # dispatch per iteration — doubles dispatches and accounting
+            # paths for a mix this engine rarely sees)
             pend = self._pend_q[-1] if self._pend_q else None
             if pend is not None:
                 # structurally impossible either way (new decode entrants
@@ -4497,11 +4554,7 @@ class ServingEngine:
             # harvest)
             lag = sum(p.n for p in self._pend_q)
             min_budget = min(self._slots[i].remaining for i in active) - lag
-            masked = any(self._slots[i].sampling is not None and
-                         self._slots[i].sampling.mask_processor is not None
-                         for i in active)
-            n = 1 if (min_budget < self.steps_per_call or masked) \
-                else self.steps_per_call
+            n, masked = self._block_steps(active, lag)
             # fused multi-iteration window (async_depth >= 2): when the
             # next S iterations are PROVABLY eventless — nothing queued or
             # swapped to admit, no chunk to ride, the dispatch itself
@@ -4581,17 +4634,23 @@ class ServingEngine:
         # plan-known accounting lands at DISPATCH (same step as the
         # lockstep engine); output-dependent accounting (KV sweep,
         # ledger, token streams, flight-recorder events) lands at
-        # harvest inside _absorb_block.  At async_depth >= 2 a rider
-        # that already finished on device still counts its cells here
-        # (the plan cannot know without the sync this protocol
-        # removes) — these block-granular counters are documented
+        # harvest inside _absorb_block.  A rider counts the cells it is
+        # owed, which the plan knows exactly (budget less the steps in
+        # flight): the cells a row spends frozen behind its budget are
+        # device steps wasted, not busy slot-steps.  Where an EOS can
+        # end a rider early (or already did, unobserved, at
+        # async_depth >= 2) the plan cannot know without the sync this
+        # protocol removes — there these counters are documented
         # approximate; the harvest-side ledger stays exact.
+        owed = [(self._slots[i],
+                 min(n_total, max(self._slots[i].remaining - lag, 0)))
+                for i in active]
+        live_cells = sum(k for _, k in owed)
         self._m.decode_steps.inc(n_total)
-        self._m.busy_slot_steps.inc(n_total * len(active))
+        self._m.busy_slot_steps.inc(live_cells)
         self._m.block_dispatches.inc()
-        self._m.tokens_emitted.inc(n_total * len(active))
-        self._count_sample_route(
-            [(self._slots[i], n_total) for i in active])
+        self._m.tokens_emitted.inc(live_cells)
+        self._count_sample_route(owed)
         new_pend = _PendingBlock(
             step_idx=self._step_idx, n=n_total, per_iter=n,
             iters=iters, active=list(active),

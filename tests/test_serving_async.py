@@ -195,6 +195,54 @@ def test_async_lockstep_parity(arms, netm):
         assert e._pending is None          # run ended flushed
 
 
+def _drive_block_arm(net, cfg, async_dispatch):
+    """More requests than slots at ``steps_per_call=8``, budgets ending at
+    mixed offsets of a block: nearly every block holds a budget finish."""
+    reg, rec = MetricsRegistry(), FlightRecorder()
+    eng = ServingEngine(
+        net, num_slots=3, prompt_len=P, max_cache_len=C, steps_per_call=8,
+        block_len=BL, chunk_len=4, compute_dtype="float32", registry=reg,
+        flight_recorder=rec, async_dispatch=async_dispatch)
+    rng = np.random.default_rng(36)
+    reqs = [eng.submit(rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32),
+                       max_new_tokens=m, arrival_time=0.0)
+            for n, m in [(7, 12), (3, 30), (8, 17), (5, 9), (6, 21), (2, 14),
+                         (8, 26), (4, 11), (7, 19)]]
+    while any(r.state != "finished" for r in reqs):
+        eng.step(now=0.0)
+        eng._pool.check()
+    return eng, rec, reqs
+
+
+def test_block_through_budget_finishes_async_lockstep_parity(netm):
+    """The plan is shared by both arms: with the block running through
+    budget finishes they still agree byte for byte (tokens, counters, the
+    flight recorder's sequence modulo the harvest lag), the async arm
+    defers the blocks in which no rider ends, and a block in which one
+    ends harvests synchronously under ``budget``."""
+    cfg, net = netm
+    (ea, reca, qa), (es, recs, qs) = (_drive_block_arm(net, cfg, on)
+                                      for on in (True, False))
+    for a, s in zip(qa, qs):
+        assert len(a.output) == a.max_new_tokens
+        np.testing.assert_array_equal(a.output, s.output)
+    np.testing.assert_array_equal(
+        qa[3].output, _gen_ref(net, qa[3].prompt[:qa[3].seq_len], 9))
+    sa, ss = ea.stats(), es.stats()
+    for k in ("decode_steps", "busy_slot_steps", "block_dispatches",
+              "prefills", "prefill_chunks", "kv_bytes_swept",
+              "useful_tokens", "wasted_tokens", "dispatched_tokens",
+              "wasted_by_reason", "finished"):
+        assert sa[k] == ss[k], k
+    assert sa["decode_steps"] / sa["block_dispatches"] >= 6
+    assert sa["busy_slot_steps"] == sum(r.max_new_tokens - 1 for r in qa)
+    assert _norm_events(reca) == _norm_events(recs)
+    assert sa["async_syncs_by_reason"]["budget"] > 0
+    assert sa["async_harvests"] > 0 and ss["async_harvests"] == 0
+    for e in (ea, es):
+        assert e._pending is None
+
+
 def test_async_overlap_and_sync_reasons(arms):
     (ea, rga, reca, _qa), (es, rgs, recs, _qs) = arms
     sa, ss = ea.stats(), es.stats()

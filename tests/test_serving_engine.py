@@ -12,12 +12,16 @@ reuse and full-pool admission in a single trace; the wider scenario
 matrix (per-scenario engines, EOS configs, gang mode, the bench path)
 is ``slow``-marked and runs on demand / on chip."""
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import models
 from paddle_tpu.inference.serving import ServingEngine
+from paddle_tpu.observability import MetricsRegistry
+from paddle_tpu.observability.flightrec import FlightRecorder
 
 
 @pytest.fixture(scope="module")
@@ -306,6 +310,197 @@ def test_backlog_of_prompts_is_worked_off_by_its_share_a_step(
     eng.run()
     for p, r in zip(prompts, reqs):
         assert list(r.output) == list(_oracle(net, _pad(p), 5, 3))
+
+
+# max_new_tokens of the saturated trace below: 20 requests over 4 slots, the
+# tokens owed after the first (max_new - 1) end at every offset 1..8 of an
+# 8-step block, twice or more
+BLOCK_NEWS = [12, 19, 10, 26, 15, 11, 21, 13, 18, 24, 14, 16, 20, 7, 23, 17,
+              25, 22, 9, 27]
+
+
+def _drive_blocks(net, cfg, spc):
+    reg, rec = MetricsRegistry(), FlightRecorder()
+    eng = ServingEngine(net, num_slots=4, prompt_len=P, max_cache_len=C,
+                        steps_per_call=spc, compute_dtype="float32",
+                        registry=reg, flight_recorder=rec)
+    rng = np.random.default_rng(36)
+    prompts = [rng.integers(0, cfg.vocab_size, (int(rng.integers(2, P + 1)),))
+               .astype(np.int32) for _ in BLOCK_NEWS]
+    reqs = [eng.submit(p, max_new_tokens=m)
+            for p, m in zip(prompts, BLOCK_NEWS)]
+    saturated = None
+    while any(r.state != "finished" for r in reqs):
+        eng.step()
+        eng._pool.check()
+        if saturated is None and not eng._queue:
+            saturated = eng.stats()     # up to here a request always waited
+    return SimpleNamespace(eng=eng, reg=reg, rec=rec, prompts=prompts,
+                           reqs=reqs, stats=eng.stats(), saturated=saturated)
+
+
+@pytest.fixture(scope="module")
+def block_arms(netm):
+    cfg, net = netm
+    return SimpleNamespace(block=_drive_blocks(net, cfg, 8),
+                           single=_drive_blocks(net, cfg, 1))
+
+
+def _blocks_by_step(rec):
+    """{dispatch step: [steps each rider took]} from the decode_block events."""
+    by_step = {}
+    for e in rec.events():
+        if e.kind == "decode_block":
+            by_step.setdefault(e.step, []).append(e.attrs["steps"])
+    return by_step
+
+
+def test_block_runs_through_budget_finishes_token_for_token(block_arms, netm):
+    """``steps_per_call=8`` under a saturated mix whose budgets end at every
+    offset of a block: each request gets exactly ``max_new_tokens`` tokens,
+    the ids of the ``steps_per_call=1`` engine and of ``generate()``."""
+    _, net = netm
+    blk, one = block_arms.block, block_arms.single
+    assert sorted({(m - 1) % 8 for m in BLOCK_NEWS}) == list(range(8))
+    for m, rb, r1 in zip(BLOCK_NEWS, blk.reqs, one.reqs):
+        assert len(rb.output) == rb.n_emitted == m
+        np.testing.assert_array_equal(rb.output, r1.output)
+    for k in (13, 18):           # max_new 7 and 9: budgets ending mid-block
+        want = _oracle(net, _pad(blk.prompts[k]), len(blk.prompts[k]),
+                       BLOCK_NEWS[k])
+        np.testing.assert_array_equal(blk.reqs[k].output, want)
+
+
+def test_saturated_mix_dispatches_the_block_and_a_lone_tail_single_steps(
+        block_arms, netm):
+    """While a request waits for a slot some rider is always owed a whole
+    block, so a dispatch is the 8-step program; the block is never longer
+    than work that exists (some rider takes all of it); and a lone request
+    inside its last seven tokens still takes the one-step program."""
+    _, net = netm
+    blk = block_arms.block
+    sat = blk.saturated
+    assert sat["decode_steps"] / sat["block_dispatches"] >= 6
+    assert blk.stats["decode_steps"] / blk.stats["block_dispatches"] >= 6
+    one = block_arms.single.stats
+    assert one["decode_steps"] == one["block_dispatches"]
+    by_step = _blocks_by_step(blk.rec)
+    assert {max(took) for took in by_step.values()} == {1, 8}
+    assert any(min(took) < max(took) for took in by_step.values())
+    rec = FlightRecorder()
+    eng = ServingEngine(net, num_slots=4, prompt_len=P, max_cache_len=C,
+                        steps_per_call=8, compute_dtype="float32",
+                        registry=MetricsRegistry(), flight_recorder=rec)
+    lone = eng.submit(blk.prompts[0], max_new_tokens=12)
+    eng.run()
+    assert [e.attrs["steps"] for e in rec.events()
+            if e.kind == "decode_block"] == [8, 1, 1, 1]
+    st = eng.stats()
+    assert (st["decode_steps"], st["block_dispatches"]) == (11, 4)
+    np.testing.assert_array_equal(lone.output, blk.reqs[0].output)
+
+
+def test_busy_slot_steps_count_live_cells_and_the_ledger_pads_the_frozen(
+        block_arms):
+    """A cell a row spends frozen behind its budget is no busy slot-step
+    and no swept KV: ``busy_slot_steps`` is the tokens decoded, the KV
+    sweep is the single-step engine's, and the ledger's pad is the frozen
+    cells beside the prompt chunks' tails."""
+    blk, one = block_arms.block, block_arms.single
+    decoded = sum(m - 1 for m in BLOCK_NEWS)
+    for arm in (blk, one):
+        assert arm.stats["busy_slot_steps"] == decoded
+        assert arm.reg.get("serving.tokens_emitted").value() == \
+            decoded + len(BLOCK_NEWS)
+        assert arm.stats["useful_tokens"] == decoded + sum(
+            len(p) for p in arm.prompts)
+    assert blk.stats["kv_bytes_swept"] == one.stats["kv_bytes_swept"]
+    frozen = sum(max(took) - k for took in _blocks_by_step(blk.rec).values()
+                 for k in took)
+    chunk_tails = sum(blk.eng.chunk_len - e.attrs["tokens"]
+                      for e in blk.rec.events() if e.kind == "prefill_chunk")
+    assert frozen > 0
+    assert blk.stats["wasted_by_reason"]["pad"] == frozen + chunk_tails
+    assert one.stats["wasted_by_reason"]["pad"] == chunk_tails
+    # occupancy reads live cells only: the frozen cells are not in it
+    assert blk.stats["mean_slot_occupancy"] == pytest.approx(
+        decoded / (blk.stats["decode_steps"] * 4))
+
+
+def test_a_slot_vacated_inside_a_block_rides_the_next(netm):
+    """Two riders end inside one block at different offsets while a long
+    rider keeps the block whole and two requests wait: the next step admits
+    both, runs both prompts' chunks (a chunk for each decode step of the
+    block it paces, until no slot waits) and both ride its block."""
+    cfg, net = netm
+    rng = np.random.default_rng(7)
+    rec = FlightRecorder()
+    eng = ServingEngine(net, num_slots=3, prompt_len=P, max_cache_len=C,
+                        steps_per_call=8, compute_dtype="float32",
+                        registry=MetricsRegistry(), flight_recorder=rec,
+                        prefix_cache_mode="none")
+    news = [27, 4, 6, 9, 10]
+    reqs = [eng.submit(rng.integers(0, cfg.vocab_size, (4,)).astype(np.int32),
+                       max_new_tokens=m) for m in news]
+    long_, a, b, c, d = reqs
+    while a.state != "finished" or b.state != "finished":
+        eng.step()
+    assert c.state == d.state == "queued" and long_.state == "decode"
+    ended = {e.request: e.step for e in rec.events() if e.kind == "finish"}
+    assert ended[a.request_id] == ended[b.request_id]    # one block, both
+    chunks = eng.stats()["prefill_chunks"]
+    eng.step()
+    assert eng.stats()["prefill_chunks"] == chunks + 2
+    # c's budget ends with the block, so its harvest is not deferred
+    assert (c.state, d.state) == ("finished", "decode")
+    assert len(c.tokens) == len(d.tokens) == 1 + 8
+    rode = {e.request for e in rec.events() if e.kind == "decode_block"
+            and e.step == ended[a.request_id] + 1}
+    assert rode == {long_.request_id, c.request_id, d.request_id}
+    eng.run()
+    assert [len(r.output) for r in reqs] == news
+
+
+def _spy_block_forms(eng):
+    """Record each decode dispatch as (steps, fed from the device): the two
+    compiled forms of each decode program."""
+    forms, build = [], eng._block_fn
+
+    def spy(n, flags, lora_on, iters=1):
+        forms.append((n, bool(eng._pend_q)))
+        return build(n, flags, lora_on, iters=iters)
+    eng._block_fn = spy
+    return forms
+
+
+def test_the_benchmarks_warm_up_dispatches_every_form_a_window_can(netm):
+    """The benchmark's warm-up (a file this engine may not edit) has to
+    have dispatched every program form a measured window dispatches, or the
+    window compiles: walked through the plan, its two waves run the 8-step
+    and the one-step program, each fed from the host and from the previous
+    dispatch's device outputs, and a saturated closed loop asks for no
+    other."""
+    from benchmarks.harness.serving import warm_up
+    cfg, net = netm
+    geometry = {"steps_per_call": 8, "chunk_len": 4}
+    eng = ServingEngine(net, num_slots=4, prompt_len=P, max_cache_len=48,
+                        steps_per_call=8, chunk_len=4, block_len=4,
+                        compute_dtype="float32", registry=MetricsRegistry())
+    forms = _spy_block_forms(eng)
+    warm_up(eng, cfg.vocab_size, geometry)
+    warmed = set(forms)
+    assert warmed == {(8, False), (8, True), (1, False), (1, True)}
+    del forms[:]
+    rng = np.random.default_rng(3)
+    live = []
+    for _ in range(60):               # six clients over four slots
+        live = [r for r in live if r.state != "finished"]
+        while len(live) < 6:
+            live.append(eng.submit(
+                rng.integers(0, cfg.vocab_size, (int(rng.integers(1, 7)),))
+                .astype(np.int32), max_new_tokens=int(rng.integers(3, 40))))
+        eng.step()
+    assert set(forms) <= warmed and (8, False) in forms
 
 
 def test_stats_before_any_finish_returns_nones(netm):

@@ -5,9 +5,9 @@ in the ``.xplane.pb`` beside where the device's line would be and in
 at the spans' boundaries; tracing observes and reorders nothing.
 
 Tier-1 budget: ONE module-scoped pair of tiny engines (1-layer llama,
-float32, ``steps_per_call=2`` so that a budget's end drops the mix to
-the one-step program), the same trace replayed with a profiler session
-live and without.
+float32, ``steps_per_call=2``: budgets end inside blocks, and the last
+rider's odd last token takes the one-step program), the same trace
+replayed with a profiler session live and without.
 """
 
 import glob
@@ -27,7 +27,7 @@ from paddle_tpu.observability import MetricsRegistry, spans
 from paddle_tpu.observability.flightrec import FlightRecorder
 
 P, C = 6, 32
-SPECS = [(4, 7), (3, 4), (5, 9), (6, 3)]           # (seq_len, max_new)
+SPECS = [(4, 7), (3, 4), (5, 10), (6, 3)]          # (seq_len, max_new)
 CHILDREN = ("serving.admit", "serving.prefill", "serving.plan",
             "serving.decode_block", "serving.harvest")
 # reasons of a harvest that is the step's own synchronous tail (its wait
