@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generation import (GenerationConfig,
+from ..models.generation import (GenerationConfig, LatentCacheSpec,
                                  beam_scan_body, decode_scan_body,
                                  init_kv_cache, model_arrays, sample_token,
                                  slot_state_spec, swap_call,
@@ -335,12 +335,19 @@ def _constrain_arenas(flat, shard):
     return [jax.lax.with_sharding_constraint(a, shard.kv) for a in flat]
 
 
-def _pack_paged_kvs(flat_arenas, tables, kv_int8):
+def _kv_latent(model):
+    """Does ``model`` keep a latent cache (``LatentCacheSpec``): one arena a
+    cached layer, not keys and values?"""
+    return isinstance(model.kv_cache_spec(), LatentCacheSpec)
+
+
+def _pack_paged_kvs(flat_arenas, tables, kv_int8, latent=False):
     """Per-layer kv entries from the engine's flat arena list: the
-    (k, v, tables) triple of the float cache, or the
+    (k, v, tables) triple of the float cache, the
     (k_codes, v_codes, k_scales, v_scales, tables) 5-tuple of the int8
-    cache (4 donated arrays per layer instead of 2)."""
-    stride = 4 if kv_int8 else 2
+    cache (4 donated arrays per layer instead of 2), or the
+    (arena, tables) pair of a ``latent`` cache."""
+    stride = 1 if latent else 4 if kv_int8 else 2
     return [tuple(flat_arenas[i:i + stride]) + (tables,)
             for i in range(0, len(flat_arenas), stride)]
 
@@ -377,6 +384,18 @@ def _poison_rows(arena, finished):
     b = finished.shape[0]
     rows = finished.reshape((b,) + (1,) * (arena.ndim - 1))
     return arena.at[:b].set(jnp.where(rows, jnp.nan, arena[:b]))
+
+
+def _poison_state(model, arenas, finished):
+    """Every state arena poisoned for the slots that ``finished``: whole
+    rows (``_poison_rows``), unless the model says which part of a slot's
+    row is enough to turn everything read from it into NaN
+    (``poison_slot_state``: a matrix state of megabytes a slot is poisoned
+    in one key row a head, not rewritten whole once a block)."""
+    own = getattr(model, "poison_slot_state", None)
+    if own is not None:
+        return own(arenas, finished)
+    return [_poison_rows(a, finished) for a in arenas]
 
 
 def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
@@ -440,7 +459,7 @@ def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
     def _scan(tok, lens, done, budget, samp, tables, flat_arenas):
         flat_kv, state = _split_slot_state(model, flat_arenas)
         kvs = _pack_paged_kvs(_constrain_arenas(flat_kv, shard),
-                              tables, kv_int8)
+                              tables, kv_int8, _kv_latent(model))
         if state:
             # the second kind of state rides the scan carry as one more
             # entry of ``kvs``; the scan body never looks inside.
@@ -460,8 +479,8 @@ def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
         tail = ()
         if state:
             slot_state = kvs_f.pop()
-            tail = tuple(_poison_rows(a, done_f & ~done)
-                         for a in slot_state["state"]) \
+            tail = tuple(_poison_state(model, slot_state["state"],
+                                       done_f & ~done)) \
                 + (slot_state["counters"],)
         return ((toks.T.astype(jnp.int32), tok_f, lens_f, done_f,
                  budget_f) + tuple(_constrain_arenas(
@@ -611,7 +630,7 @@ def build_chunk_prefill(model, cfg: GenerationConfig, kv_int8=False,
     def _chunk(ids, start, n_valid, tables, samp, flat_arenas):
         flat_kv, state = _split_slot_state(model, flat_arenas)
         kvs = _pack_paged_kvs(_constrain_arenas(flat_kv, shard),
-                              tables, kv_int8)
+                              tables, kv_int8, _kv_latent(model))
         if state:
             # ``tables`` carries the slot index behind the slot's blocks
             # ([1, max_blocks + 1]): the chunk reads and writes that row of

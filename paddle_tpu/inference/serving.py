@@ -237,8 +237,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.generation import (GenerationConfig, SlotStateError,
-                                 init_paged_kv_arena, init_slot_state,
+from ..models.generation import (GenerationConfig, LatentCacheSpec,
+                                 SlotStateError, init_paged_kv_arena,
+                                 init_paged_latent_arena, init_slot_state,
                                  model_arrays, slot_state_spec)
 from ..observability import metrics as obs_metrics
 from ..observability.flightrec import ENGINE_EVENT, FlightRecorder
@@ -1373,7 +1374,7 @@ class ServingEngine:
                 "%s keeps per-slot state (%s) beside its paged KV: serving "
                 "it with prefix_cache_mode='none' (asked: %r)",
                 type(model).__name__,
-                ", ".join(n for n, _ in self._state_spec), mode)
+                ", ".join(e[0] for e in self._state_spec), mode)
             mode = "none"
         if self._state_spec:
             if drafter is not None:
@@ -1474,7 +1475,12 @@ class ServingEngine:
             wbytes += self._wq.bytes_swept()
         self._weight_sweep_bytes = wbytes
 
-        n_layers, hkv, d = model.kv_cache_spec()
+        # a latent cache keeps one row a token a layer, shared by every
+        # query head: one arena a layer, one "KV head" as wide as the row
+        spec = model.kv_cache_spec()
+        self._kv_latent = isinstance(spec, LatentCacheSpec)
+        n_layers, hkv, d = (spec.layers, 1, spec.row) if self._kv_latent \
+            else spec
         # kv_cache_dtype overrides the arena dtype only; "int8" selects
         # the QUANTIZED cache — int8 code arenas + parallel f32 absmax
         # scale arenas, quantize-on-append in every writer and
@@ -1507,8 +1513,17 @@ class ServingEngine:
         self.kv_cache_dtype = str(jnp.dtype(cdt).name)
         self._kv_int8 = cdt == jnp.dtype(jnp.int8)
         self._n_layers = n_layers
-        arenas = init_paged_kv_arena(n_layers, self.num_blocks,
-                                     self.block_len, hkv, d, cdt)
+        if self._kv_latent:
+            if self._kv_int8:
+                raise ValueError(
+                    "kv_cache_dtype='int8': a latent cache has no "
+                    "quantized form (its row is one vector, not heads "
+                    "with a scale each)")
+            arenas = init_paged_latent_arena(n_layers, self.num_blocks,
+                                             self.block_len, d, cdt)
+        else:
+            arenas = init_paged_kv_arena(n_layers, self.num_blocks,
+                                         self.block_len, hkv, d, cdt)
         self._arenas: List = []
         for entry in arenas:
             self._arenas += list(entry)
@@ -1578,8 +1593,11 @@ class ServingEngine:
             }
         # modeled per-row KV sweep bytes across all layers, at the
         # Pallas kernels' block-DMA granularity (serving.kv.bytes_swept)
-        row_bytes = 2 * hkv * d * (1 if self._kv_int8
-                                   else jnp.dtype(cdt).itemsize)
+        if self._kv_latent:     # the row as it rests, lane padding included
+            row_bytes = arenas[0][0].shape[-1] * jnp.dtype(cdt).itemsize
+        else:
+            row_bytes = 2 * hkv * d * (1 if self._kv_int8
+                                       else jnp.dtype(cdt).itemsize)
         if self._kv_int8:
             row_bytes += 2 * hkv * 4       # f32 scale planes
         self._kv_row_bytes = row_bytes * n_layers
@@ -4952,6 +4970,7 @@ class ServingEngine:
             # of the per-slot state arenas, and whether the prefix cache
             # that was asked for is off because a hit cannot carry them
             "slot_state_bytes": self._slot_state_bytes,
+            "kv_arena_bytes": sum(int(a.nbytes) for a in self._arenas),
             "prefix_cache_disabled": self.prefix_cache_disabled,
             "mean_tpot_s": (sum(tpots) / len(tpots)) if tpots else None,
             "slo_attained": int(
@@ -5058,6 +5077,8 @@ class ServingEngine:
             "weight_dtype": self.weight_dtype,
             "pad_token_id": int(self.cfg.pad_token_id),
             "kv_row_bytes": int(self._kv_row_bytes),
+            "kv_layout": "latent" if self._kv_latent else "kv",
+            "slot_state_bytes": int(self._slot_state_bytes),
             "adapters": (None if self._adapters is None
                          else list(self._adapters.names())),
             "shard_group": self.shard_group,

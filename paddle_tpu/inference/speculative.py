@@ -285,7 +285,7 @@ def build_spec_verify(model, cfg, steps: int, kv_int8: bool = False,
         raise ValueError(
             "token-mask constrained decoding cannot ride a verify "
             "forward (mask state is host-side and per emitted token)")
-    from .llm import (_constrain_arenas, _flatten_paged_kvs,
+    from .llm import (_constrain_arenas, _flatten_paged_kvs, _kv_latent,
                       _pack_paged_kvs, _param_swapper, _shard_scope)
     from .sampling import spec_greedy_rows, spec_sampling_draws
     from ..models.lora import gather_lora, lora_context
@@ -295,7 +295,7 @@ def build_spec_verify(model, cfg, steps: int, kv_int8: bool = False,
 
     def _verify(toks, lens, n_valid, tables, samp, flat_arenas):
         kvs = _pack_paged_kvs(_constrain_arenas(flat_arenas, shard),
-                              tables, kv_int8)
+                              tables, kv_int8, _kv_latent(model))
         with _shard_scope(shard):
             logits, kvs_f = model.verify_step(toks, lens, n_valid, kvs)
         pres = samp["presence"] if penalty else None

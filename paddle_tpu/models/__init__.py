@@ -17,6 +17,8 @@ from .gpt import (GPTConfig, GPTModel, GPTForCausalLM,
                   gpt3_13b_config, tiny_gpt_config)
 from .lfm2 import (Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel,
                    tiny_lfm2_config)
+from .kimi_linear import (KimiLinearConfig, KimiLinearForCausalLM,
+                          KimiLinearModel, tiny_kimi_linear_config)
 from .ocr import (DBNet, DBNetConfig, DBLoss, DBFPN, DBHead, db_postprocess,
                   CRNN, CRNNConfig, CTCHeadLoss, ctc_greedy_decode,
                   PPOCRSystem)
@@ -36,6 +38,8 @@ __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM",
            "gpt3_13b_config", "tiny_gpt_config",
            "Lfm2MoeConfig", "Lfm2MoeModel", "Lfm2MoeForCausalLM",
            "tiny_lfm2_config",
+           "KimiLinearConfig", "KimiLinearModel", "KimiLinearForCausalLM",
+           "tiny_kimi_linear_config",
            "DBNet", "DBNetConfig", "DBLoss", "DBFPN", "DBHead",
            "db_postprocess", "CRNN", "CRNNConfig", "CTCHeadLoss",
            "ctc_greedy_decode", "PPOCRSystem"]

@@ -39,7 +39,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -149,6 +149,25 @@ def init_paged_kv_arena(num_layers, num_blocks, block_len, num_kv_heads,
             for _ in range(num_layers)]
 
 
+class LatentCacheSpec(NamedTuple):
+    """What ``kv_cache_spec()`` answers for a model whose cached layers keep
+    ONE row of ``row`` values a token (a compressed KV row with the shared
+    positional key behind it), not keys and values of ``H_kv x D``: one
+    arena a layer, and a layer's paged entry is ``(arena, tables)``."""
+    layers: int
+    row: int
+
+
+def init_paged_latent_arena(num_layers, num_blocks, block_len, row, dtype):
+    """Per-layer ``(arena,)`` of a latent paged cache: one
+    ``[num_blocks + 1, block_len, row padded to lanes]`` pool a layer
+    (``ops/pallas/decode_attention.paged_latent_shape``), trash block last
+    and zero-initialised as ``init_paged_kv_arena``'s are."""
+    from ..ops.pallas.decode_attention import paged_latent_shape
+    shape = paged_latent_shape(num_blocks + 1, block_len, row)
+    return [(jnp.zeros(shape, dtype),) for _ in range(num_layers)]
+
+
 class SlotStateError(NotImplementedError):
     """A feature that shares, moves or rewinds KV blocks was asked of a model
     that keeps per-slot state beside them (``slot_state_spec``), which the
@@ -156,7 +175,7 @@ class SlotStateError(NotImplementedError):
     state."""
 
     def __init__(self, model, feature):
-        names = ", ".join(name for name, _ in slot_state_spec(model))
+        names = ", ".join(entry[0] for entry in slot_state_spec(model))
         super().__init__(
             f"{feature} cannot carry the per-slot state ({names}) that "
             f"{type(model).__name__} keeps beside its paged KV")
@@ -164,7 +183,8 @@ class SlotStateError(NotImplementedError):
 
 def slot_state_spec(model):
     """``[(name, shape a slot)]`` of what a slot of ``model`` keeps beside
-    its blocks; empty for a model whose only state is keys and values."""
+    its blocks, an entry that is not of the compute dtype as ``(name, shape,
+    dtype)``; empty for a model whose only state is keys and values."""
     spec = getattr(model, "slot_state_spec", None)
     return list(spec()) if spec is not None else []
 
@@ -172,9 +192,35 @@ def slot_state_spec(model):
 def init_slot_state(spec, num_slots, dtype):
     """One arena a spec entry, ``[num_slots + 1, *shape]``: row ``s`` is slot
     ``s``'s state, and the extra last row takes the masked writes of vacant
-    and frozen rows, as the trash block does for keys and values."""
-    return [jnp.zeros((num_slots + 1,) + tuple(shape), dtype)
-            for _, shape in spec]
+    and frozen rows, as the trash block does for keys and values.  An entry
+    is of ``dtype`` unless it names its own (a float32 recurrent state
+    beside bfloat16 convolution tails)."""
+    return [jnp.zeros((num_slots + 1,) + tuple(entry[1]),
+                      entry[2] if len(entry) > 2 else dtype)
+            for entry in spec]
+
+
+def generate_by_forward(forward, input_ids, seq_lens=None, max_new_tokens=32):
+    """Greedy tokens [B, max_new_tokens] after the (right-padded) prompts, by
+    a whole-sequence ``forward(ids [B, S]) -> logits [B, S, V]`` over a buffer
+    that grows a token a step: no cache and no state to carry, so it is the
+    plain answer an engine's tokens are compared with, at a toy size.  The
+    operators must be causal: what lies past a row's end cannot reach the
+    logits of its last position."""
+    ids = jnp.asarray(getattr(input_ids, "_value", input_ids), jnp.int32)
+    b, s = ids.shape
+    lens = jnp.full((b,), s, jnp.int32) if seq_lens is None else \
+        jnp.asarray(getattr(seq_lens, "_value", seq_lens), jnp.int32)
+    buf = jnp.concatenate(
+        [ids, jnp.zeros((b, int(max_new_tokens)), jnp.int32)], axis=1)
+    rows = jnp.arange(b)
+    step = jax.jit(forward)
+    out = []
+    for i in range(int(max_new_tokens)):
+        nxt = jnp.argmax(step(buf)[rows, lens + i - 1], axis=-1)
+        out.append(nxt.astype(jnp.int32))
+        buf = buf.at[rows, lens + i].set(out[-1])
+    return Tensor(jnp.stack(out, axis=1))
 
 
 def quantize_kv_heads(kv):

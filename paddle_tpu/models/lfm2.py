@@ -35,7 +35,8 @@ from ..core.tensor import Tensor
 from ..incubate.nn.functional import llama_rope, swiglu
 from ..nn import functional as F
 from ..nn.initializer import Normal
-from .generation import SlotStateError, paged_verify_scatter
+from .generation import (SlotStateError, generate_by_forward,
+                         paged_verify_scatter)
 
 LAYER_KINDS = ("conv", "full_attention")
 
@@ -334,25 +335,10 @@ class Lfm2MoeForCausalLM(nn.Layer):
 
     def generate(self, input_ids, seq_lens=None, max_new_tokens=32):
         """Greedy tokens [B, max_new_tokens] after the (right-padded)
-        prompts, by the whole-sequence ``forward`` over a buffer that grows a
-        token a step: no cache and no state to carry, so it is the plain
-        answer the engine's tokens are compared with, at a toy size."""
-        ids = jnp.asarray(getattr(input_ids, "_value", input_ids), jnp.int32)
-        b, s = ids.shape
-        lens = jnp.full((b,), s, jnp.int32) if seq_lens is None else \
-            jnp.asarray(getattr(seq_lens, "_value", seq_lens), jnp.int32)
-        buf = jnp.concatenate(
-            [ids, jnp.zeros((b, int(max_new_tokens)), jnp.int32)], axis=1)
-        rows = jnp.arange(b)
-        step = jax.jit(lambda buf: self.forward(buf)._value)
-        out = []
-        for i in range(int(max_new_tokens)):
-            # the operators are causal: what lies past a row's end cannot
-            # reach the logits of its last position
-            nxt = jnp.argmax(step(buf)[rows, lens + i - 1], axis=-1)
-            out.append(nxt.astype(jnp.int32))
-            buf = buf.at[rows, lens + i].set(out[-1])
-        return Tensor(jnp.stack(out, axis=1))
+        prompts, by the whole-sequence ``forward`` (``generate_by_forward``):
+        the plain answer the engine's tokens are compared with."""
+        return generate_by_forward(lambda buf: self.forward(buf)._value,
+                                   input_ids, seq_lens, max_new_tokens)
 
     # -- the engine's entry points (inference/llm.py) ---------------------------------
     def decode_step(self, tokens, lens, kvs):

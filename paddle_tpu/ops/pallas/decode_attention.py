@@ -72,7 +72,8 @@ from ._common import (on_tpu, pallas_enabled, partitioned_scope,
 # program traced with its kv-head shard geometry accepted
 # (``sharded_ok``) or fell back to replicated arenas (``mesh_geom``).
 DECODE_ROUTE_REASONS = (
-    "ok", "paged_ok", "paged_multi_ok", "sharded_ok", "mesh_geom",
+    "ok", "paged_ok", "paged_multi_ok", "latent_ok", "sharded_ok",
+    "mesh_geom",
     "flag_disabled", "pallas_unavailable", "gspmd_partitioned",
     "unpacked_cache", "dtype_mismatch", "scales_mismatch", "geometry",
     "int8_scale_lanes", "group_too_wide", "seq_align",
@@ -236,6 +237,15 @@ def paged_scale_shape(num_blocks, num_kv_heads, block_len):
     (``models.generation.quantize_kv_heads``).  4/D of the code arena's
     bytes — the price of exact, pure-scatter quantize-on-append."""
     return (num_blocks, block_len, num_kv_heads)
+
+
+def paged_latent_shape(num_blocks, block_len, row):
+    """At-rest shape of a LATENT paged arena: one row of ``row`` values a
+    token (a compressed KV row and the shared positional key behind it),
+    not keys and values of ``H_kv x D``.  The row is padded with zeros
+    to whole 128-lane tiles (576 values rest as 640): a block is staged
+    by DMA, and Mosaic slices an HBM plane only in whole lane tiles."""
+    return (num_blocks, block_len, -(-row // _LANES) * _LANES)
 
 
 def paged_gather_view(arena, tables):
@@ -596,7 +606,13 @@ def _paged_stream_kernel(lens_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
     stale-but-real V is finite and safe under (a)).  Both, and the
     cross-program prefetch, depend on the grid executing SEQUENTIALLY
     (``dimension_semantics=("arbitrary",)``): a 'parallel' batch
-    dimension would race programs on the shared stages."""
+    dimension would race programs on the shared stages.
+
+    A LATENT arena (``_latent_stream_kernel``) is this body with
+    ``v_hbm``, ``vbuf`` and ``vsem`` None: one row a token, shared by
+    every query head (``hkv`` 1), whose first ``d`` lanes are also its
+    value.  Only K is staged, PV reads those lanes of the K stage, and
+    the K stages are what is zeroed once."""
     bi = pl.program_id(0)
     last_prog = pl.num_programs(0) - 1
     rows = bpg * block_len
@@ -617,12 +633,16 @@ def _paged_stream_kernel(lens_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
                 dst = pl.ds(c * block_len, block_len)
                 act(pltpu.make_async_copy(
                     k_hbm.at[blk], kbuf.at[stage, dst, :], ksem.at[stage]))
-                act(pltpu.make_async_copy(
-                    v_hbm.at[blk], vbuf.at[stage, dst, :], vsem.at[stage]))
+                if v_hbm is not None:
+                    act(pltpu.make_async_copy(
+                        v_hbm.at[blk], vbuf.at[stage, dst, :],
+                        vsem.at[stage]))
+
+    values = kbuf if v_hbm is None else vbuf
 
     @pl.when(bi == 0)
     def _():
-        vbuf[...] = jnp.zeros_like(vbuf)
+        values[...] = jnp.zeros_like(values)
         stage_ref[0] = 0
         for_group(0, 0, 0, group_blocks(0, 0), lambda cp: cp.start())
 
@@ -661,8 +681,12 @@ def _paged_stream_kernel(lens_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         p_ = jnp.exp(lg - m_new)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p_, axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p_.astype(vbuf.dtype), vbuf[stage], (((1,), (0,)), ((), ())),
+        scaled, weights, vals = alpha * acc_ref[...], \
+            p_.astype(values.dtype), values[stage]
+        if v_hbm is None:       # a latent row's values: its own first lanes
+            vals = vals[:, :acc_ref.shape[1]]
+        acc_ref[...] = scaled + jax.lax.dot_general(
+            weights, vals, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)              # [P, W]
         m_ref[...] = m_new
         return carry
@@ -837,6 +861,113 @@ def _decode_attention_pallas_paged_multi(q5, k_arena, v_arena, tables,
     out = _paged_stream(q5, k_arena, v_arena, tables, lens)
     # head-major rows c*g+gi back to [B, C, H_kv, G, D]
     return jnp.transpose(out.reshape(b, hkv, cq, g, d), (0, 2, 1, 3, 4))
+
+
+def _latent_stream_kernel(lens_ref, tbl_ref, q_ref, c_hbm, o_ref, cbuf,
+                          m_ref, l_ref, acc_ref, stage_ref, csem, **static):
+    """``_paged_stream_kernel`` over one latent arena: no V operand."""
+    _paged_stream_kernel(lens_ref, tbl_ref, q_ref, c_hbm, None, o_ref, cbuf,
+                         None, m_ref, l_ref, acc_ref, stage_ref, csem, None,
+                         **static)
+
+
+def _route_decision_latent(q, arena, tables, dv):
+    """(use_pallas, reason) for the latent decode gate.  ``q`` [B, G, W]:
+    every query head of a slot against the slot's one row a token, so the
+    kernel's q block is the ``G`` rows as they are and ``group_too_wide``
+    (the block-diagonal q of the KV kernels) does not arise; what is
+    staged is two stages of rows ``W`` wide and an accumulator ``dv``
+    wide."""
+    from ...core.flags import flag
+    if not flag("use_decode_attention_kernel"):
+        return False, "flag_disabled"
+    if not pallas_enabled():
+        if refused_for_partitioning():
+            return False, "gspmd_partitioned"
+        return False, "pallas_unavailable"
+    if jnp.dtype(q.dtype) != jnp.dtype(arena.dtype):
+        return False, "dtype_mismatch"
+    w = arena.shape[2]
+    if arena.ndim != 3 or q.shape[2] != w or w % _LANES or dv % _LANES \
+            or dv > w:
+        return False, "geometry"
+    ok, reason = _paged_table_rule(arena)
+    if not ok:
+        return False, reason
+    rows = _stage_blocks(arena, tables) * arena.shape[1]
+    p = _stream_rows(1, 1, q.shape[1])
+    vmem = (2 * rows * w * jnp.dtype(arena.dtype).itemsize + p * rows * 4
+            + p * (dv * 4 + 2 * w * jnp.dtype(q.dtype).itemsize))
+    if vmem > _VMEM_BUDGET:
+        return False, "vmem_budget"
+    return True, "latent_ok"
+
+
+def _latent_stream(q, arena, tables, lens, dv, scale):
+    """Dispatch ``_latent_stream_kernel``.  q [B, G, W]; arena
+    [NB+1, L, W] (last row = trash block); returns [B, G, dv]."""
+    _guard_replicated_tables(tables)
+    b, g, w = q.shape
+    blk_len = arena.shape[1]
+    bpg = _stage_blocks(arena, tables)
+    rows = bpg * blk_len
+    n_rows = _stream_rows(1, 1, g)
+    qall = jnp.pad(q, ((0, 0), (0, n_rows - g), (0, 0)))
+    kernel = functools.partial(
+        _latent_stream_kernel, block_len=blk_len, bpg=bpg,
+        n_blocks_max=tables.shape[1], cq=1, g=g, hkv=1, d=dv, scale=scale,
+        out_dtype=q.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, n_rows, w),
+                               lambda bi, lens_p, tbl_p: (bi, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, n_rows, dv),
+                               lambda bi, lens_p, tbl_p: (bi, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, rows, w), arena.dtype),      # row stages
+            pltpu.VMEM((n_rows, 1), jnp.float32),       # running max
+            pltpu.VMEM((n_rows, 1), jnp.float32),       # running sum
+            pltpu.VMEM((n_rows, dv), jnp.float32),      # accumulator
+            pltpu.SMEM((1,), jnp.int32),                # live stage
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n_rows, dv), q.dtype),
+        compiler_params=_STREAM_COMPILER_PARAMS,
+        name="decode_attention_latent",
+        interpret=not on_tpu(),
+    )(lens.astype(jnp.int32), tables.astype(jnp.int32), qall, arena)
+    return out[:, :g]
+
+
+def decode_attention_latent(q, arena, tables, lens, dv, scale):
+    """One-token attention of ``G`` query heads over a LATENT paged
+    cache: every head scores the same row a token (``paged_latent_shape``)
+    and sums the rows' first ``dv`` lanes under its weights, which is
+    latent attention with the up-projections absorbed into the query and
+    the output.
+
+    q: [B, G, W], the absorbed query beside its positional part, zero in
+    the row's pad lanes; arena [NB+1, L, W]; tables [B, max_blocks]; lens
+    [B] = index of the last valid slot; ``scale`` multiplies the logits.
+    On the chip the streaming kernel walks the table (no dense copy of
+    it); elsewhere the rows are gathered.  Returns [B, G, dv] in q.dtype."""
+    use, reason = _route_decision_latent(q, arena, tables, dv)
+    _count_route("pallas" if use else "xla", reason)
+    if use:
+        return _latent_stream(q, arena, tables, lens, dv, scale)
+    rows = paged_gather_view(arena, tables)                  # [B, S, W]
+    logits = jnp.einsum("bgw,bsw->bgs", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(rows.shape[1])[None, :] <= lens[:, None]
+    logits = jnp.where(valid[:, None, :], logits, -jnp.inf)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgs,bsv->bgv", probs, rows[..., :dv])
 
 
 def _decode_attention_xla(q4, k_cache, v_cache, lens):

@@ -267,7 +267,8 @@ def one_chip():
 
 
 @pytest.mark.parametrize("m,k,n", [(1024, 2048, 1536), (1024, 1536, 2048),
-                                   (1000, 2048, 1536)])
+                                   (1000, 2048, 1536), (2048, 2304, 1024),
+                                   (2048, 1024, 2304)])
 def test_compiles_for_the_v5e_at_the_cell_widths(one_chip, m, k, n):
     """Mosaic takes the kernel at the serving cell's shapes and the tiles
     the module's rule gives them (interpret mode cannot show a refused
@@ -293,4 +294,62 @@ def test_compiles_for_the_v5e_at_the_cell_widths(one_chip, m, k, n):
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _compile_for_the_chip(fn, *specs, donate=()):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return jax.jit(fn, donate_argnums=donate).lower(*specs).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def test_kda_decode_kernel_compiles_for_the_v5e_in_place(one_chip,
+                                                         monkeypatch):
+    """Mosaic takes the delta-rule decode kernel at the served widths (256
+    rows, 32 heads of 128 x 128, a four-layer arena of 257 slots), and the
+    arena goes in and comes out as one buffer: no second 2.2 GB copy."""
+    from paddle_tpu.ops.pallas import kda
+    monkeypatch.setattr(kda, "on_tpu", lambda: True)
+    b, h, d = 256, 32, 128
+    spec = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+
+    def step(arena, rows, live, q, k, v, g, beta):
+        return kda._decode_pallas(arena, 2, rows, live,
+                                  *kda._decode_operands(q, k, v, g, beta))
+
+    arena = spec((257, 4, h, d, d))
+    compiled = _compile_for_the_chip(
+        step, arena, spec((b,), jnp.int32), spec((b,), jnp.bool_),
+        *[spec((b, h, d))] * 4, spec((b, h)), donate=0)
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    nbytes = 257 * 4 * h * d * d * 4
+    assert memory.alias_size_in_bytes >= nbytes
+    assert memory.temp_size_in_bytes < nbytes // 8
+
+
+def test_latent_decode_kernel_compiles_for_the_v5e(one_chip, monkeypatch):
+    """Mosaic takes the streaming kernel over the latent arena at the served
+    widths: 32 query heads over rows of 576 values resting as 640, values
+    the first 512."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "on_tpu", lambda: True)
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    w = da.paged_latent_shape(1, 16, 576)[-1]
+
+    def step(q, arena, tables, lens):
+        return da._latent_stream(q, arena, tables, lens, 512, 192 ** -0.5)
+
+    compiled = _compile_for_the_chip(
+        step, spec((256, 32, w), jnp.bfloat16),
+        spec((32769, 16, w), jnp.bfloat16), spec((256, 128), jnp.int32),
+        spec((256,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()

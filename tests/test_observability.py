@@ -674,6 +674,7 @@ def test_metrics_name_lint_clean():
              "serving.handoff.", "serving.role",
              "pallas.decode_attention.route",
              "pallas.moe_experts.route",
+             "pallas.kda_decode.route",
              "serving.tpot_seconds")), n
         assert n in names, n
     kinds = {r[3]: r[2] for r in regs}

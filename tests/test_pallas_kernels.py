@@ -1471,3 +1471,129 @@ def test_paged_gate_table_width_rule(monkeypatch, route):
         arena = jnp.zeros((9, blk_len, hkv * d), jnp.bfloat16)
         tables = jnp.zeros((2, width), jnp.int32)
         assert decide(q, arena, tables) == (True, ok)
+
+
+# -- the latent paged cache and the matrix state (PR 37) ----------------------
+
+_LATENT_EDGES = ("one_row", "group_less_one", "group", "group_plus_one",
+                 "several_groups", "full_width")
+
+
+@pytest.mark.parametrize("which", _LATENT_EDGES)
+def test_latent_stream_kernel_parity(monkeypatch, which):
+    """The streaming kernel over ONE latent arena (every query head over a
+    slot's one row a token, values the row's first lanes; interpret mode)
+    against the gathered rows, with valid lengths at every edge of the
+    group walk."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "_STAGE_BYTES", 16 << 10)
+    rng = np.random.default_rng(37)
+    b, g, w, dv, blk_len, mb = len(_LATENT_EDGES), 8, 256, 128, 8, 12
+    nb = b * mb + 3
+    arena = jnp.asarray(rng.standard_normal((nb + 1, blk_len, w)),
+                        jnp.float32)
+    tables = jnp.asarray(rng.permutation(nb)[:b * mb].reshape(b, mb),
+                         jnp.int32)
+    rows = da._stage_blocks(arena, tables) * blk_len
+    assert 1 < mb * blk_len // rows
+    valid = [1, rows - 1, rows, rows + 1, 2 * rows + 5, mb * blk_len]
+    lens = jnp.asarray(valid, jnp.int32) - 1
+    q = jnp.asarray(rng.standard_normal((b, g, w)), jnp.float32)
+    i = _LATENT_EDGES.index(which)
+    out = da._latent_stream(q, arena, tables, lens, dv, 0.1)
+    ref = da.decode_attention_latent(q, arena, tables, lens, dv, 0.1)
+    np.testing.assert_allclose(np.asarray(out)[i], np.asarray(ref)[i],
+                               atol=1e-5)
+
+
+def test_latent_stream_kernel_ignores_stale_rows(monkeypatch):
+    """Rows past a slot's length, and another slot's blocks, hold huge
+    values: masked before ``exp``, they weigh nothing."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "_STAGE_BYTES", 16 << 10)
+    rng = np.random.default_rng(38)
+    arena = jnp.asarray(rng.standard_normal((9, 8, 128)), jnp.float32)
+    tables = jnp.asarray([[0, 1, 2, 8], [3, 4, 8, 8]], jnp.int32)
+    lens = jnp.asarray([12, 9], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((2, 8, 128)), jnp.float32)
+    out = da._latent_stream(q, arena, tables, lens, 128, 0.2)
+    dirty = arena.at[1, 5:].set(1e6).at[2].set(-1e6).at[4, 2:].set(1e6) \
+        .at[8].set(1e6)
+    out2 = da._latent_stream(q, dirty, tables, lens, 128, 0.2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out2), atol=1e-5)
+
+
+def test_latent_gate_and_arena_shape():
+    from paddle_tpu.ops.pallas import decode_attention as da
+    assert da.paged_latent_shape(33, 16, 576) == (33, 16, 640)
+    assert da.paged_latent_shape(33, 16, 512) == (33, 16, 512)
+    assert "latent_ok" in da.DECODE_ROUTE_REASONS
+    q = jnp.zeros((2, 32, 640), jnp.bfloat16)
+    arena = jnp.zeros((5, 16, 640), jnp.bfloat16)
+    tables = jnp.zeros((2, 4), jnp.int32)
+    use, reason = da._route_decision_latent(q, arena, tables, 512)
+    assert not use and reason == "pallas_unavailable"       # the CPU
+
+
+def _kda_step_case(seed=0, b=5, h=8, d=128, slots=6, layers=2):
+    from paddle_tpu.ops.pallas import kda
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    arena = jax.random.normal(ks[0], (slots + 1, layers, h, d, d))
+    args = (jax.random.normal(ks[1], (b, h, d)) * d ** -0.5,
+            jax.random.normal(ks[2], (b, h, d)) * d ** -0.5,
+            jax.random.normal(ks[3], (b, h, d)),
+            -jnp.exp(jax.random.normal(ks[4], (b, h, d)) - 3.0),
+            jax.nn.sigmoid(jax.random.normal(ks[5], (b, h))))
+    return kda, arena, args
+
+
+@pytest.mark.parametrize("rows,live", [
+    ([0, 1, 2, 3, 4], [1, 1, 1, 1, 1]),
+    ([4, 6, 0, 6, 2], [1, 0, 1, 0, 1]),
+    ([6, 6, 6, 6, 6], [0, 0, 0, 0, 0])],
+    ids=["all_live", "vacant_and_stale_to_the_last_row", "none_live"])
+def test_kda_decode_kernel_is_its_jnp_body(rows, live):
+    """``kda_decode_step``'s kernel (interpret mode) against its ``jnp``
+    body: live rows update their own slot's state in place, rows that are
+    not live start from zeros and write the arena's last row, and every
+    other row, and the other layer, is untouched."""
+    kda, arena, args = _kda_step_case()
+    rows, live = jnp.asarray(rows, jnp.int32), jnp.asarray(live, bool)
+    o_x, a_x = kda.kda_decode_step(arena, 1, rows, live, *args)     # the CPU
+    o_p, a_p = kda._decode_pallas(arena, 1, rows, live,
+                                  *kda._decode_operands(*args))
+    np.testing.assert_allclose(np.asarray(o_p), np.asarray(o_x), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(a_p[:6]), np.asarray(a_x[:6]),
+                               atol=1e-5)
+    touched = set(np.asarray(rows)[np.asarray(live)].tolist())
+    for s in range(6):
+        if s not in touched:
+            np.testing.assert_array_equal(np.asarray(a_p[s]),
+                                          np.asarray(arena[s]))
+    np.testing.assert_array_equal(np.asarray(a_p[:, 0]),
+                                  np.asarray(arena[:, 0]))
+    # a row that is not live computes from zeros, whatever its block held
+    if not bool(live[1]):
+        zero = kda.kda_decode_step(jnp.zeros_like(arena), 1, rows, live,
+                                   *args)[0]
+        np.testing.assert_allclose(np.asarray(o_p[1]), np.asarray(zero[1]),
+                                   atol=1e-5)
+
+
+def test_kda_decode_kernel_never_reads_a_poisoned_row():
+    kda, arena, args = _kda_step_case(seed=1)
+    arena = arena.at[3].set(jnp.nan)
+    rows = jnp.asarray([0, 6, 2, 1, 4], jnp.int32)
+    live = jnp.asarray([1, 0, 1, 1, 1], bool)
+    o, a = kda._decode_pallas(arena, 0, rows, live,
+                              *kda._decode_operands(*args))
+    assert np.isfinite(np.asarray(o)).all()
+    assert np.isfinite(np.asarray(a[jnp.asarray([0, 1, 2, 4, 5, 6])])).all()
+
+
+def test_kda_route_reasons_are_a_closed_vocabulary():
+    from paddle_tpu.ops.pallas import kda
+    assert len(set(kda.KDA_ROUTE_REASONS)) == len(kda.KDA_ROUTE_REASONS)
+    arena = jnp.zeros((3, 1, 8, 128, 128))
+    assert kda._kda_route_reason(arena, 8) == "pallas_unavailable"
+    assert not kda.should_use_pallas(arena, 8)

@@ -198,6 +198,11 @@ REQUIRED_INSTRUMENTS = {
     # the gating reason (MOE_ROUTE_REASONS); chip_smoke.py's moe_experts
     # arm and the serving harness's route check key on it
     "pallas.moe_experts.route": ("counter", ("decision", "reason")),
+    # the gated delta-rule decode step (PR 37, ops/pallas/kda.py): the
+    # kernel in place in the state arena vs its jnp body, with the gating
+    # reason (KDA_ROUTE_REASONS); the serving harness's route check keys
+    # on it
+    "pallas.kda_decode.route": ("counter", ("decision", "reason")),
     # wire transport (PR 19, inference/transport.py
     # _TransportInstruments): frames moved per kind (the determinism
     # surface the bench multiproc arm gates on), encoded byte totals

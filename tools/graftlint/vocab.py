@@ -95,6 +95,10 @@ VOCABS: Tuple[VocabSpec, ...] = (
     # the _moe_route_reason / _geometry_reason producers
     VocabSpec("MOE_ROUTE_REASONS",
               producers=("_moe_route_reason", "_geometry_reason")),
+    # routing reasons of the gated delta-rule decode step (PR 37,
+    # ops/pallas/kda.py): every label the pallas.kda_decode.route counter
+    # can carry is a literal return of the _kda_route_reason producer
+    VocabSpec("KDA_ROUTE_REASONS", producers=("_kda_route_reason",)),
     # wire-transport frame kinds (PR 19, inference/transport.py):
     # every request kind has a literal transport.rpc("<kind>", ...)
     # site (RemoteReplica and friends), every reply kind a literal
