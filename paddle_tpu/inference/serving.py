@@ -52,7 +52,12 @@ prefill) design, restricted to what XLA's static shapes allow:
   beyond that (``_prefill_chunks``): a backlog of prompts (many
   slots, or a burst of arrivals) is worked off geometrically instead
   of one chunk a step, which would hold a wide batch at a fraction of
-  its slots, and a slot vacated inside a block rides the next.
+  its slots, and a slot vacated inside a block rides the next.  A
+  step's chunks are enqueued back to back, and the first tokens of the
+  prompts that finish among them LAND TOGETHER after the last chunk is
+  enqueued (``_land_first_tokens``: one fetch, then each request's
+  first-token bookkeeping in enqueue order), so the chip empties once
+  a step and not after every prompt's final chunk.
 - **Decode blocks**: a ``step()`` dispatches ONE compiled program of
   ``steps_per_call`` decode steps whenever some rider is owed that
   many tokens; riders owed fewer finish inside the block on the
@@ -162,6 +167,16 @@ planes, ledger) ran SERIALLY with device compute.  This engine splits
   its scheduling (admissions, dispatch counts, flight-recorder event
   sequence modulo wall and harvest lag) is byte-identical to the
   ``async_dispatch=False`` kill-switch arm BY CONSTRUCTION.
+- a prompt's first token is such a place, once a step: the final
+  chunk's sampled token stays a device array while the step's further
+  chunks are enqueued, and all of a step's first tokens become host
+  truth together before the verify and the plan read them (one
+  ``chunk_final`` flush before the first of them, one fetch after the
+  last).  The kill-switch arm lands each behind its own chunk through
+  the same function, so between the arms the events a landing emits
+  (a finish at the first token, a handoff) move behind the step's
+  later ``prefill_chunk`` events and nothing else moves: every
+  request's events, the finished order and the counters are equal.
 - the tiered prefix cache's demote gather rides the same pipeline:
   reclaim ENQUEUES the at-rest-bytes gather during plan and the host
   copies reconcile lazily at the next harvest point (the PR-8
@@ -333,7 +348,10 @@ ASYNC_SYNC_REASONS = (
     "mask",         # a token-mask row's host state machine needs the token
     "penalty",      # a repetition-penalty presence plane is host-built
     "spec",         # speculative accept/rollback is a host decision
-    "chunk_final",  # a prompt's final chunk samples the first token
+    "chunk_final",  # a prompt's final chunk samples the first token:
+    #                 flushed before a step's first final chunk; the
+    #                 step's first tokens land together after its last
+    #                 chunk is enqueued (``_land_first_tokens``)
     "resume",       # a swap-in rewrites the slot's host carries
     "preempt",      # a swap-out reads the slot's host carries
     "cancel",       # cancel() must know which tokens already exist
@@ -412,6 +430,12 @@ class _ServingInstruments:
         self.prefills = r.counter(
             "serving.prefills", "prompt prefills completed (requests "
             "that reached their first token)")
+        self.first_token_fetches = r.counter(
+            "serving.first_token_fetches", "host fetches of first "
+            "tokens: one for all the prompts whose final chunk a step "
+            "enqueued (a dispatch-ahead engine), one a prompt on the "
+            "lockstep arm; serving.prefills over this is the first "
+            "tokens one wait on the device buys")
         self.prefill_chunks = r.counter(
             "serving.prefill_chunks", "prompt chunks computed (chunked-"
             "prefill dispatches; prefix-cached blocks never become "
@@ -568,12 +592,17 @@ class _ServingInstruments:
             "request latency, arrival -> last token")
         self.ttft = r.histogram(
             "serving.ttft_seconds",
-            "time to first token, arrival -> last prefill chunk")
+            "time to first token, arrival -> the token's landing on "
+            "the host (after the last chunk of the step that ran the "
+            "prompt's final chunk)")
         self.chunk_latency = r.histogram(
             "serving.prefill_chunk_seconds",
-            "wall time of one chunked-prefill dispatch (a dispatch-"
-            "ahead engine's non-final chunks are pure enqueues, so "
-            "only final chunks include compute+materialization there)")
+            "wall time of one chunked-prefill dispatch: a pure "
+            "enqueue on a dispatch-ahead engine, for a final chunk as "
+            "for any other (a step's first tokens are fetched together "
+            "after its last chunk, under serving.prefill.wait); "
+            "enqueue, compute and materialization on the lockstep arm's "
+            "non-final chunks")
         self.spec_verifies = r.counter(
             "serving.spec.verify_steps", "speculative verify forwards "
             "dispatched (one K+1-position target forward per scheduler "
@@ -780,7 +809,8 @@ class _ServingInstruments:
             "priority/EDF/FIFO order would have inflicted on the "
             "chosen tenant")
         self._base = {}
-        for c in (self.prefills, self.prefill_chunks, self.decode_steps,
+        for c in (self.prefills, self.first_token_fetches,
+                  self.prefill_chunks, self.decode_steps,
                   self.busy_slot_steps, self.block_dispatches,
                   self.requests_finished, self.requests_cancelled,
                   self.prefix_hits, self.prefix_misses,
@@ -1712,6 +1742,10 @@ class ServingEngine:
         self._slots: List[Optional[Request]] = [None] * self.num_slots
         self._queue: deque = deque()
         self._prefilling: deque = deque()
+        # (request, device token) of the prompts whose final chunk is
+        # enqueued and whose first token has not landed yet: filled and
+        # emptied inside one step (``_land_first_tokens``)
+        self._first_owed: list = []
         self._swapped: List[Request] = []   # preempted, host-RAM KV
         self._swap_out_fn = None            # lazy: engines that never
         self._swap_in_fn = None             # swap compile neither
@@ -3934,24 +3968,39 @@ class ServingEngine:
         for its harvest, one block: a slot vacated inside a block is
         admitted, prefilled and riding by the next.  The live rows'
         stall a decoded token stays that share of the backlog, whatever
-        the block's length."""
+        the block's length.
+
+        The chunks are enqueued back to back and the first tokens of the
+        prompts that finished among them land once, after the last
+        (``_land_first_tokens``): nothing between two prompts' chunks
+        needs the first one's token, so the chip runs chunk after chunk
+        while the host prepares the next."""
         riders = [i for i, r in enumerate(self._slots)
                   if r is not None and r.state == "decode"
                   and r.spec_k is None]
         n, _ = self._block_steps(riders, sum(p.n for p in self._pend_q))
-        for _ in range(n):
-            for _ in range(max(1, -(-len(self._prefilling)
-                                    // self.steps_per_call))):
-                self._prefill_chunk(out)
-            if not self._prefilling:
-                break
+        try:
+            for _ in range(n):
+                for _ in range(max(1, -(-len(self._prefilling)
+                                        // self.steps_per_call))):
+                    self._prefill_chunk(out)
+                if not self._prefilling:
+                    break
+        finally:
+            # a chunk that raises leaves the prompts enqueued before it
+            # landed, as it did when each landed behind its own chunk
+            self._land_first_tokens(out)
 
     # graftlint: plan-phase
     def _prefill_chunk(self, out: List[Request]):
-        """Run at most ONE prompt chunk (FIFO over admissions).  The
-        final chunk of a prompt samples the request's first token and
-        flips it into the decode mix; completed full blocks are
-        published to the prefix cache as soon as they are written."""
+        """Enqueue at most ONE prompt chunk (FIFO over admissions), with
+        everything of it the plan knows: counters, the goodput ledger, the
+        flight recorder's event, the prefix cache (completed full blocks
+        are published as soon as they are written).  A prompt's final
+        chunk samples the request's first token: it stays on the device,
+        owed to the request (``_first_owed``), and the request leaves the
+        line so that the next call takes the next prompt.  The lockstep
+        arm reads every token at once, so there the token lands here."""
         if not self._prefilling:
             return
         req = self._prefilling[0]
@@ -3960,7 +4009,9 @@ class ServingEngine:
         if is_final:
             # the final chunk samples the request's first token, which
             # becomes host truth THIS step (EOS check, decode-mix
-            # entry, the slot's tok/lens carries) — the pipeline syncs
+            # entry, the slot's tok/lens carries) — the pipeline syncs.
+            # No block can become pending between two chunks, so of a
+            # step's final chunks only the first finds one to flush.
             self._flush_async("chunk_final", out)
         with _span("serving.prefill", request=req.request_id,
                    slot=req.slot, start=start):
@@ -3976,13 +4027,13 @@ class ServingEngine:
                     jnp.asarray(self._chunk_tables(req.slot)), samp,
                     *lora_args, *self._arenas, *self._slot_state)
                 self._adopt_arenas(outp[1:])
-                # a non-final chunk's sampled token is meaningless (the
-                # engine never advances decode state from it): the
-                # dispatch-ahead engine leaves it un-forced, so the chunk
-                # computes under the NEXT iterations' host work; the
-                # final chunk's token is host truth and materializes here
-                tok0 = (int(np.asarray(outp[0])[0])
-                        if is_final or not self.async_dispatch else None)
+                if not (is_final or self.async_dispatch):
+                    # a non-final chunk's sampled token is meaningless
+                    # (the engine never advances decode state from it):
+                    # the dispatch-ahead engine leaves it un-forced, so
+                    # the chunk computes under the NEXT iterations' host
+                    # work; the lockstep arm waits for every dispatch
+                    outp[0].block_until_ready()
             self._m.prefill_chunks.inc()
             self._m.chunk_latency.observe(ph.seconds)
             self._disp_s += ph.seconds
@@ -4010,53 +4061,82 @@ class ServingEngine:
                     self._radix.insert(req.prompt, req.blocks, full,
                                        start_block=req.registered)
                     req.registered = full
-            if req.pf_pos < req.seq_len:
-                return                        # more chunks to go
-            # final chunk: tok0 is the request's first generated token
-            self._prefilling.popleft()
-            self._m.prefills.inc()
-            self._m.tokens_emitted.inc()
-            t = self._clock()
-            req.first_token_time = t
-            if req.ttft is not None:
-                self._m.ttft.observe(req.ttft)
-            req.tokens.append(tok0)
-            req.remaining = req.max_new_tokens - 1
-            self._count_sample_route([(req, 1)])
-            slot = req.slot
-            if (self.cfg.eos_token_id is not None and
-                    tok0 == self.cfg.eos_token_id) or req.remaining == 0:
-                # finished at the first token: never enters the decode mix
-                self._slots[slot] = None
-                self._done[slot] = True
-                self._release_blocks(req)
-                self._finish(req, t, out)
-                return
-            if req.sampling is not None and \
-                    req.sampling.mask_processor is not None and \
-                    self._mask_dead_end(req):
-                self._slots[slot] = None
-                self._done[slot] = True
-                self._release_blocks(req)
-                self._finish(req, t, out)
-                return
-            if self.role == "prefill":
-                # the disaggregation point (ROADMAP item 2): a prefill-
-                # role replica never decodes in place — gather the
-                # request's KV parcel at exact at-rest bytes and stage it
-                # for router pickup; the chosen decode replica resumes
-                # token-exact through the unchanged migrate_in/_try_resume
-                # path (tok0 travels in the parcel's tok carry)
-                self._handoff_out(req, tok0, slot)
-                return
-            req.state = "decode"
-            self._tok[slot] = tok0
-            self._lens[slot] = req.seq_len
-            # spec-mode rows never ride the plain decode block: their row
-            # stays done=True there (frozen lens, trash-routed writes, pad
-            # emits) and all progress happens in the verify dispatch, which
-            # reads its own host-side truth (req.tokens / self._lens)
-            self._done[slot] = req.spec_k is not None
+            if is_final:
+                self._prefilling.popleft()
+                self._first_owed.append((req, outp[0]))
+        if not self.async_dispatch:
+            self._land_first_tokens(out)
+
+    # graftlint: plan-phase
+    def _land_first_tokens(self, out: List[Request]):
+        """Make the first tokens owed (``_first_owed``: the prompts whose
+        final chunk is enqueued and whose token is still a device array)
+        host truth: ONE ``jax.device_get`` over all of them, so the copies
+        start together and the host waits once, for the last chunk; then
+        the requests in the order their chunks were enqueued, so the
+        finished list, the slot releases and the handoffs come out in the
+        order they did when each prompt landed behind its own chunk.
+        Called once a step after its last chunk is enqueued (before the
+        verify and the plan, which read the carries written here), and
+        after every final chunk on the lockstep arm."""
+        if not self._first_owed:
+            return
+        owed, self._first_owed = self._first_owed, []
+        with _span("serving.prefill", landed=len(owed)):
+            with self._phase("serving.prefill.wait") as wait:
+                # sync: chunk_final
+                toks = jax.device_get([tok_d for _, tok_d in owed])
+            # the materialization is part of the chunks' dispatch, as the
+            # sync tail's is of the block's
+            self._disp_s += wait.seconds
+            self._m.first_token_fetches.inc()
+            for (req, _), tok in zip(owed, toks):
+                self._land_first_token(req, int(tok[0]), out)
+
+    def _land_first_token(self, req: Request, tok0: int,
+                          out: List[Request]):
+        """``tok0`` is ``req``'s first generated token: stamp it, then
+        finish the request there (budget of one, EOS, a grammar with no
+        continuation), hand it to a decode replica (prefill role) or flip
+        it into the decode mix."""
+        self._m.prefills.inc()
+        self._m.tokens_emitted.inc()
+        t = self._clock()
+        req.first_token_time = t
+        if req.ttft is not None:
+            self._m.ttft.observe(req.ttft)
+        req.tokens.append(tok0)
+        req.remaining = req.max_new_tokens - 1
+        self._count_sample_route([(req, 1)])
+        slot = req.slot
+        if (self.cfg.eos_token_id is not None and
+                tok0 == self.cfg.eos_token_id) or req.remaining == 0 or (
+                req.sampling is not None and
+                req.sampling.mask_processor is not None and
+                self._mask_dead_end(req)):
+            # finished at the first token: never enters the decode mix
+            self._slots[slot] = None
+            self._done[slot] = True
+            self._release_blocks(req)
+            self._finish(req, t, out)
+            return
+        if self.role == "prefill":
+            # the disaggregation point (ROADMAP item 2): a prefill-
+            # role replica never decodes in place — gather the
+            # request's KV parcel at exact at-rest bytes and stage it
+            # for router pickup; the chosen decode replica resumes
+            # token-exact through the unchanged migrate_in/_try_resume
+            # path (tok0 travels in the parcel's tok carry)
+            self._handoff_out(req, tok0, slot)
+            return
+        req.state = "decode"
+        self._tok[slot] = tok0
+        self._lens[slot] = req.seq_len
+        # spec-mode rows never ride the plain decode block: their row
+        # stays done=True there (frozen lens, trash-routed writes, pad
+        # emits) and all progress happens in the verify dispatch, which
+        # reads its own host-side truth (req.tokens / self._lens)
+        self._done[slot] = req.spec_k is not None
 
     def _handoff_out(self, req: Request, tok0: int, slot: int):
         """Chunk-final handoff swap-out (prefill-role engines only):
@@ -4386,7 +4466,8 @@ class ServingEngine:
     def step(self, now: Optional[float] = None) -> List[Request]:
         """One scheduler iteration: sweep queue-delay timeouts and
         admit/resume into vacant slots (preempting strictly-worse
-        victims under block pressure), run at most one prefill chunk,
+        victims under block pressure), run the step's prefill chunks
+        and land their first tokens (``_prefill_chunks``),
         then one speculative verify forward over the spec-mode slots
         and one decode block over the plain-decode mix — the phases
         coexist in the same iteration.  Returns the requests that
@@ -4408,7 +4489,8 @@ class ServingEngine:
         Each phase is delimited ONCE (``_phase``): the same boundaries
         open and close a span, so under a profiler session the
         iteration reads ``serving.step`` > ``serving.admit``,
-        ``serving.prefill`` (> ``.dispatch``), ``serving.spec_verify``,
+        ``serving.prefill`` (a chunk, > ``.dispatch``; the step's first
+        tokens landing, > ``.wait``), ``serving.spec_verify``,
         ``serving.plan``, ``serving.decode_block``, ``serving.harvest``
         (> ``.wait``) on the device trace's clock."""
         self._step_idx += 1
@@ -4886,6 +4968,8 @@ class ServingEngine:
             "block_dispatches": int(
                 self._m.since_init(self._m.block_dispatches)),
             "prefills": int(self._m.since_init(self._m.prefills)),
+            "first_token_fetches": int(
+                self._m.since_init(self._m.first_token_fetches)),
             "prefill_chunks": int(
                 self._m.since_init(self._m.prefill_chunks)),
             "mean_slot_occupancy": occ,

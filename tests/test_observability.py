@@ -535,7 +535,10 @@ def test_serving_lifecycle_spans_recorded(served):
     # name, not one per request/dispatch
     from paddle_tpu.profiler import SummaryView
     rows = {r["name"]: r for r in SummaryView(served.host_events).rows()}
-    assert rows["serving.prefill"]["calls"] == len(SPECS)
+    # (a chunk a prompt here, and a landing of first tokens for every
+    # step that ran a final chunk)
+    assert rows["serving.prefill"]["calls"] == \
+        len(SPECS) + served.stats["first_token_fetches"]
     assert not any(";" in n for n in rows)
 
 

@@ -20,6 +20,7 @@ the next step's admissions chronologically."""
 
 import importlib.util
 import os
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -348,3 +349,191 @@ def test_drain_flushes_inflight_harvest_before_stall_raise(netm):
         done[b.request_id].output, _gen_ref(net, ids_b, 3))
     assert eng.stats()["async_harvests"] > 0
     eng._pool.check()
+
+
+# -- a step's first tokens land together (PR 39) ------------------------------
+
+LAND_MIX = [(7, 5), (3, 9), (8, 4), (5, 12), (6, 3), (2, 7), (8, 6), (4, 10),
+            (7, 2)]                          # (prompt rows, max_new_tokens)
+LAND_KEYS = ("decode_steps", "busy_slot_steps", "block_dispatches",
+             "prefills", "prefill_chunks", "prefix_hits", "kv_bytes_swept",
+             "useful_tokens", "wasted_tokens", "dispatched_tokens",
+             "wasted_by_reason", "spec_verify_steps", "spec_accepted_tokens",
+             "masked_tokens", "finished")
+
+
+def _dead_end_table(vocab):
+    # one legal first token, then no legal continuation: the grammar is
+    # complete at the request's first token
+    table = np.full((2, vocab), -1, np.int32)
+    table[0, 5] = 1
+    return table
+
+
+def _land_arm(net, vocab, variant, async_dispatch, eos=None):
+    """Six slots whose prompts are one chunk each at ``steps_per_call`` 2:
+    the first step enqueues three final chunks, later steps two or three.
+    Returns what the two arms have to agree on, and the per-step deltas."""
+    reg, rec = MetricsRegistry(), FlightRecorder()
+    geo = dict(num_slots=6, prompt_len=P, max_cache_len=C, steps_per_call=2,
+               block_len=BL, chunk_len=P, compute_dtype="float32")
+    mix = list(LAND_MIX)
+    if variant == "slot_state":
+        # the geometry of tests/test_lfm2_moe.py, whose programs are cached
+        geo = dict(num_slots=3, prompt_len=48, max_cache_len=80, block_len=8,
+                   num_blocks=40, chunk_len=16, steps_per_call=4,
+                   compute_dtype="float32", host_cache_blocks=0)
+        mix = [(9, 6), (16, 9), (4, 5), (12, 1), (7, 8), (15, 4)]
+    if variant == "budget_one":
+        mix[1], mix[4] = (3, 1), (6, 1)
+    eng = ServingEngine(
+        net, registry=reg, flight_recorder=rec, eos_token_id=eos,
+        async_dispatch=async_dispatch,
+        drafter=_AlwaysDraft() if variant == "spec" else None,
+        role="prefill" if variant == "prefill_role" else "both", **geo)
+    rng = np.random.default_rng(39)
+    reqs = []
+    for k, (n, m) in enumerate(mix):
+        kw = {}
+        if variant == "mask_dead_end" and k in (1, 2):
+            kw["sampling"] = SamplingParams(
+                temperature=0.0,
+                mask_processor=DfaTokenMask(_dead_end_table(vocab)))
+        if variant == "spec" and k == 2:
+            kw["spec_decode"] = 2
+        reqs.append(eng.submit(
+            rng.integers(0, vocab, (n,)).astype(np.int32),
+            max_new_tokens=m, arrival_time=0.0, **kw))
+    order, handoffs, deltas = [], [], []
+    before = eng.stats()
+    while any(r.state not in TERMINAL + ("swapped",) for r in reqs):
+        order += [r.request_id for r in eng.step(now=0.0)]
+        for r in eng.take_handoffs():
+            # (the router would carry the parcel to a decode replica)
+            handoffs.append(r.request_id)
+            eng._host_tier.drop(r.swap.host_key)
+        eng._pool.check()
+        after = eng.stats()
+        deltas.append((
+            after["prefills"] - before["prefills"],
+            after["first_token_fetches"] - before["first_token_fetches"],
+            after["async_syncs_by_reason"]["chunk_final"]
+            - before["async_syncs_by_reason"]["chunk_final"]))
+        before = after
+        assert len(deltas) < 200, "trace did not drain"
+    assert not eng._first_owed and eng._pending is None
+    by_request = {r.request_id: [] for r in reqs}
+    for e in rec.events():
+        if e.request in by_request:
+            by_request[e.request].append(
+                (e.step, e.kind, tuple(sorted(
+                    (k, str(v)) for k, v in e.attrs.items() if k != "lag"))))
+    return SimpleNamespace(eng=eng, reqs=reqs, order=order, handoffs=handoffs,
+                           deltas=deltas, stats=eng.stats(),
+                           events=by_request)
+
+
+@pytest.fixture(scope="module")
+def lfm2m():
+    model = models.Lfm2MoeForCausalLM(models.tiny_lfm2_config())
+    model.eval()
+    return model
+
+
+@pytest.mark.parametrize("variant", [
+    "plain", "budget_one", "eos_first", "mask_dead_end", "spec",
+    "prefill_role", "slot_state"])
+def test_a_steps_first_tokens_land_together_and_as_lockstep_lands_them(
+        netm, lfm2m, variant):
+    """The dispatch-ahead arm enqueues a step's chunks back to back and
+    fetches the first tokens of the prompts that finished among them once;
+    the lockstep arm lands each behind its own chunk, through the same
+    function.  Tokens, the finished order, the handoffs' order, the counters
+    and every request's own events are the same."""
+    cfg, net = netm
+    vocab = cfg.vocab_size
+    if variant == "slot_state":
+        net, vocab = lfm2m, 256
+    eos = None
+    if variant == "eos_first":
+        # the first token the fourth prompt is served becomes the EOS
+        rng = np.random.default_rng(39)
+        fourth = [rng.integers(0, vocab, (n,)).astype(np.int32)
+                  for n, _ in LAND_MIX[:4]][3]
+        eos = int(_gen_ref(net, fourth, 1)[0])
+    a, s = (_land_arm(net, vocab, variant, on, eos=eos) for on in (True, False))
+    for ra, rs in zip(a.reqs, s.reqs):
+        assert ra.state == rs.state
+        np.testing.assert_array_equal(ra.tokens, rs.tokens)
+    assert a.order == s.order and a.handoffs == s.handoffs
+    for k in LAND_KEYS:
+        assert a.stats[k] == s.stats[k], k
+    assert a.events == s.events
+    # one fetch a step that finished n >= 1 prompts, and prefills is n; the
+    # pipeline is flushed for them at most once a step
+    assert [f for _, f, _ in a.deltas] == [int(n > 0) for n, _, _ in a.deltas]
+    assert max(n for n, _, _ in a.deltas) >= 2
+    assert all(c <= 1 for _, _, c in a.deltas)
+    assert a.stats["first_token_fetches"] < a.stats["prefills"] == len(a.reqs)
+    assert s.stats["first_token_fetches"] == s.stats["prefills"]
+    if variant == "budget_one":
+        assert [len(r.output) for r in a.reqs][1] == 1
+    if variant == "eos_first":
+        assert a.reqs[3].tokens[0] == eos and a.reqs[3].remaining == \
+            a.reqs[3].max_new_tokens - 1
+    if variant == "mask_dead_end":
+        assert [a.reqs[k].tokens[0] for k in (1, 2)] == [5, 5]
+        assert a.reqs[1].state == "finished" and a.reqs[1].n_emitted == 1
+    if variant == "spec":
+        assert a.stats["spec_verify_steps"] > 0
+    if variant == "prefill_role":
+        # handed over in the order the final chunks were enqueued: FIFO
+        assert a.handoffs == [r.request_id for r in a.reqs
+                              if r.state == "swapped"]
+        assert len(a.handoffs) == len(a.reqs)
+    if variant == "slot_state":
+        assert a.stats["slot_state_bytes"] > 0
+
+
+def test_a_chunk_that_raises_leaves_the_prompts_before_it_landed(
+        netm, monkeypatch):
+    """The first token owed when a later chunk's enqueue raises still lands
+    in that step, as it did when each landed behind its own chunk: no
+    request is left between the line and the decode mix, and the engine
+    serves on, token-exact, once the fault is gone."""
+    cfg, net = netm
+    reg = MetricsRegistry()
+    eng = ServingEngine(net, num_slots=6, prompt_len=P, max_cache_len=C,
+                        steps_per_call=2, block_len=BL, chunk_len=P,
+                        compute_dtype="float32", registry=reg)
+    rng = np.random.default_rng(7)
+    ids = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+           for n in (5, 8, 3, 6)]
+    reqs = [eng.submit(i, max_new_tokens=4, arrival_time=0.0) for i in ids]
+    chunk_fn, calls = eng._chunk_fn, []
+
+    def second_raises(flags, lora_on=False):
+        calls.append(flags)
+        if len(calls) == 2:
+            raise RuntimeError("no second chunk")
+        return chunk_fn(flags, lora_on)
+    monkeypatch.setattr(eng, "_chunk_fn", second_raises)
+    with pytest.raises(RuntimeError, match="no second chunk"):
+        eng.step(now=0.0)                   # two chunks this step
+    assert [r.state for r in reqs] == ["decode"] + 3 * ["prefill"]
+    assert len(reqs[0].tokens) == 1 and not eng._first_owed
+    assert list(eng._prefilling) == reqs[1:]
+    monkeypatch.setattr(eng, "_chunk_fn", chunk_fn)
+    while any(r.state != "finished" for r in reqs):
+        eng.step(now=0.0)
+        eng._pool.check()
+    for r, i in zip(reqs, ids):
+        np.testing.assert_array_equal(r.output, _gen_ref(net, i, 4))
+    # an engine's stats() count its own fetches on a registry it shares
+    fetched = eng.stats()["first_token_fetches"]
+    assert 2 <= fetched < eng.stats()["prefills"] == 4
+    twin = ServingEngine(net, num_slots=6, prompt_len=P, max_cache_len=C,
+                         steps_per_call=2, block_len=BL, chunk_len=P,
+                         compute_dtype="float32", registry=reg)
+    assert twin.stats()["first_token_fetches"] == 0
+    assert reg.get("serving.first_token_fetches").value() == fetched

@@ -119,10 +119,17 @@ def test_spans_sit_in_the_profilers_trace_and_in_recorded(runs):
     seen = {n for v in kids.values() for n, _, _ in v}
     assert seen == set(CHILDREN)
     # attributes arrive as stats, not in the name
+    # one serving.prefill a chunk (attr request) and one a landing of the
+    # step's first tokens (attr landed), its wait a child of its own
     pre = [st for n, _, _, st in ev if n == "serving.prefill"]
-    assert len(pre) == len(SPECS)
-    assert sorted(int(st["request"]) for st in pre) == \
+    chunks = [st for st in pre if "request" in st]
+    landed = [int(st["landed"]) for st in pre if "landed" in st]
+    assert len(chunks) + len(landed) == len(pre)
+    assert sorted(int(st["request"]) for st in chunks) == \
         sorted(r.request_id for r in runs.live.reqs)
+    assert sum(landed) == len(SPECS)
+    assert len(landed) == runs.live.stats["first_token_fetches"] == \
+        sum(n == "serving.prefill.wait" for n, *_ in ev)
     blk = [st for n, _, _, st in ev if n == "serving.decode_block"]
     assert blk and {int(st["steps"]) for st in blk} == {1, 2}
     assert all(";" not in n for n in names)
@@ -170,8 +177,9 @@ def test_children_cover_the_step_and_boundaries_feed_the_histograms(runs):
     # live spans' own entries and exits are a tenth; what no child covers
     # is a few lines, so by the median (one preempted step proves nothing)
     assert sorted(bare)[len(bare) // 2] < 1e-3, bare
-    # dispatch seconds: prefill + decode-block enqueue + the sync tail's
-    # wait; overlap seconds: every other harvest's wait.  One boundary,
+    # dispatch seconds: prefill (the chunks' enqueues and the first
+    # tokens' one wait) + decode-block enqueue + the sync tail's wait;
+    # overlap seconds: every other harvest's wait.  One boundary,
     # two sinks, so they agree to what lies between two adjacent reads
     # of two clocks.
     dur = defaultdict(float)
@@ -186,6 +194,7 @@ def test_children_cover_the_step_and_boundaries_feed_the_histograms(runs):
     disp = reg.get("serving.step.dispatch_seconds").summary()["sum"]
     over = reg.get("serving.step.overlap_seconds").summary()["sum"]
     want_disp = (dur["serving.prefill.dispatch"]
+                 + dur["serving.prefill.wait"]
                  + dur["serving.decode_block"]
                  + dur["serving.harvest.wait.sync"])
     assert disp == pytest.approx(want_disp, rel=0.01, abs=1e-3)
