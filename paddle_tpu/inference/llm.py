@@ -456,6 +456,8 @@ def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
     _with_params = _param_swapper(model, cfg, wq=wq)
     sampled, _filtered, penalty, _bias = samp_flags
 
+    counts = getattr(model, "init_block_counters", None)
+
     def _scan(tok, lens, done, budget, samp, tables, flat_arenas):
         flat_kv, state = _split_slot_state(model, flat_arenas)
         kvs = _pack_paged_kvs(_constrain_arenas(flat_kv, shard),
@@ -465,9 +467,11 @@ def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
             # entry of ``kvs``; the scan body never looks inside.
             # ``stale``: the rows that were done when the block began,
             # whose state rows hold nothing to read (vacant, prefilling,
-            # or poisoned below by the block they finished in)
-            kvs.append({"state": state, "stale": done,
-                        "counters": model.init_block_counters()})
+            # or poisoned below by the block they finished in).  A model
+            # that counts (its experts' load) adds its counters to it
+            kvs.append({"state": state, "stale": done})
+            if counts is not None:
+                kvs[-1]["counters"] = counts()
         pos0 = samp["pos"] if sampled else jnp.zeros_like(lens)
         pres0 = samp["presence"] if penalty else None
         with _shard_scope(shard):
@@ -480,8 +484,9 @@ def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
         if state:
             slot_state = kvs_f.pop()
             tail = tuple(_poison_state(model, slot_state["state"],
-                                       done_f & ~done)) \
-                + (slot_state["counters"],)
+                                       done_f & ~done))
+            if counts is not None:
+                tail += (slot_state["counters"],)
         return ((toks.T.astype(jnp.int32), tok_f, lens_f, done_f,
                  budget_f) + tuple(_constrain_arenas(
                      _flatten_paged_kvs(kvs_f), shard)) + tail)

@@ -19,6 +19,8 @@ from .lfm2 import (Lfm2MoeConfig, Lfm2MoeForCausalLM, Lfm2MoeModel,
                    tiny_lfm2_config)
 from .kimi_linear import (KimiLinearConfig, KimiLinearForCausalLM,
                           KimiLinearModel, tiny_kimi_linear_config)
+from .falcon_h1 import (FalconH1Config, FalconH1ForCausalLM, FalconH1Model,
+                        tiny_falcon_h1_config)
 from .ocr import (DBNet, DBNetConfig, DBLoss, DBFPN, DBHead, db_postprocess,
                   CRNN, CRNNConfig, CTCHeadLoss, ctc_greedy_decode,
                   PPOCRSystem)
@@ -40,6 +42,8 @@ __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM",
            "tiny_lfm2_config",
            "KimiLinearConfig", "KimiLinearModel", "KimiLinearForCausalLM",
            "tiny_kimi_linear_config",
+           "FalconH1Config", "FalconH1Model", "FalconH1ForCausalLM",
+           "tiny_falcon_h1_config",
            "DBNet", "DBNetConfig", "DBLoss", "DBFPN", "DBHead",
            "db_postprocess", "CRNN", "CRNNConfig", "CTCHeadLoss",
            "ctc_greedy_decode", "PPOCRSystem"]

@@ -200,6 +200,32 @@ def init_slot_state(spec, num_slots, dtype):
             for entry in spec]
 
 
+class StateRows(NamedTuple):
+    """Where a decode step finds its rows' matrix states in place: row ``i``
+    is ``arena[rows[i], layer]``, from zeros where not ``live`` (the arena of
+    a recurrent model's ``slot_state_spec`` entry, ``[slots + 1, layers,
+    ...]`` float32; a row that is not live is sent to its last row)."""
+    arena: jax.Array
+    layer: int
+    rows: jax.Array         # [B] int32
+    live: jax.Array         # [B] bool
+
+
+def poison_first_rows(arenas, finished):
+    """``poison_slot_state`` of a recurrent model whose slot keeps ``[tails,
+    states]``, the states a matrix a head (``[slots + 1, layers, heads, rows,
+    lanes]``, float32): the tails whole, the states in their first row a
+    head, which the next read of the matrix carries into every output.  A
+    whole state is megabytes a slot; a row is its lanes a head."""
+    from ..inference.llm import _poison_rows
+    tails, states = arenas
+    b = finished.shape[0]
+    first = states[:b, :, :, 0]
+    return [_poison_rows(tails, finished),
+            states.at[:b, :, :, 0].set(jnp.where(
+                finished[:, None, None, None], jnp.nan, first))]
+
+
 def generate_by_forward(forward, input_ids, seq_lens=None, max_new_tokens=32):
     """Greedy tokens [B, max_new_tokens] after the (right-padded) prompts, by
     a whole-sequence ``forward(ids [B, S]) -> logits [B, S, V]`` over a buffer

@@ -37,7 +37,7 @@ prefill (one row, ``C = chunk_len``) and the decode step (a row a slot, ``C =
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,8 +47,9 @@ from ..core.tensor import Tensor
 from ..incubate.nn.functional import swiglu
 from ..nn.initializer import Constant, Normal
 from ..ops.pallas.kda import kda_chunk, kda_decode_step
-from .generation import (LatentCacheSpec, SlotStateError,
-                         generate_by_forward, paged_verify_scatter)
+from .generation import (LatentCacheSpec, SlotStateError, StateRows,
+                         generate_by_forward, paged_verify_scatter,
+                         poison_first_rows)
 
 
 @dataclass
@@ -161,15 +162,6 @@ def tiny_kimi_linear_config(**kw):
 def _linear(n_in, n_out):
     return nn.Linear(n_in, n_out, bias_attr=False,
                      weight_attr=nn.ParamAttr(initializer=Normal(0.0, 0.02)))
-
-
-class StateRows(NamedTuple):
-    """Where a decode step finds its rows' matrix states: row ``i`` is
-    ``arena[rows[i], layer]``, from zeros where not ``live``."""
-    arena: jax.Array        # [slots + 1, kda layers, H, dk, dv] float32
-    layer: int
-    rows: jax.Array         # [B] int32
-    live: jax.Array         # [B] bool
 
 
 class KimiDeltaAttention(nn.Layer):
@@ -475,19 +467,10 @@ class KimiLinearForCausalLM(nn.Layer):
         experts that got a row summed over those pairs."""
         return jnp.zeros((self.config.num_experts + 2,), jnp.int32)
 
-    @staticmethod
-    def poison_slot_state(arenas, finished):
-        """The state arenas with the slots that ``finished`` poisoned: the
-        tails whole, the matrix states in their first key row a head, from
-        which a step's ``S'^T k`` carries NaN into every value channel.  A
-        whole row is 8 MB a slot; this is 2 KB a head."""
-        from ..inference.llm import _poison_rows
-        tails, states = arenas
-        b = finished.shape[0]
-        first = states[:b, :, :, 0]
-        return [_poison_rows(tails, finished),
-                states.at[:b, :, :, 0].set(jnp.where(
-                    finished[:, None, None, None], jnp.nan, first))]
+    # the slots that finished are poisoned in their tails and in the first
+    # key row a head of their states, from which a step's ``S'^T k`` carries
+    # NaN into every value channel: 2 KB a head where a state is 8 MB a slot
+    poison_slot_state = staticmethod(poison_first_rows)
 
     # -- the one step ----------------------------------------------------------------
     def _run(self, ids, pos0, n_valid, kvs, tails, states, live=None):

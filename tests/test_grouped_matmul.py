@@ -353,3 +353,29 @@ def test_latent_decode_kernel_compiles_for_the_v5e(one_chip, monkeypatch):
         spec((32769, 16, w), jnp.bfloat16), spec((256, 128), jnp.int32),
         spec((256,), jnp.int32))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssd_decode_kernel_compiles_for_the_v5e_in_place(one_chip,
+                                                         monkeypatch):
+    """Mosaic takes the state-space decode kernel at the served widths (128
+    rows, 32 heads of 128 x 256 in 2 groups, a four-layer arena of 129
+    slots), and the arena goes in and comes out as one buffer: no second
+    2.2 GB copy."""
+    from paddle_tpu.ops.pallas import ssd
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+    b, h, p, g, n = 128, 32, 128, 2, 256
+    spec = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+
+    def step(arena, rows, live, xa, bc):
+        return ssd._decode_pallas(arena, 2, rows, live, xa, bc)
+
+    compiled = _compile_for_the_chip(
+        step, spec((129, 4, h, p, n)), spec((b,), jnp.int32),
+        spec((b,), jnp.bool_), spec((b, 2, h, p)), spec((b, g, 2, n)),
+        donate=0)
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    nbytes = 129 * 4 * h * p * n * 4
+    assert memory.alias_size_in_bytes >= nbytes
+    assert memory.temp_size_in_bytes < nbytes // 8
