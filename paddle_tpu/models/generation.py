@@ -295,10 +295,11 @@ def paged_cache_scatter(arena, tables, lens, new_kv):
     the dense engine's "done rows overwrite their own dead row"
     contract.  Same O(B*H_kv*D) batched-scatter cost as
     ``cache_scatter``."""
-    blk, off = _paged_decode_route(arena, tables, lens)
-    if arena.ndim == 3:
-        new_kv = new_kv.reshape(tables.shape[0], -1)
-    return arena.at[blk, off].set(new_kv.astype(arena.dtype))
+    with jax.named_scope("kv_append"):
+        blk, off = _paged_decode_route(arena, tables, lens)
+        if arena.ndim == 3:
+            new_kv = new_kv.reshape(tables.shape[0], -1)
+        return arena.at[blk, off].set(new_kv.astype(arena.dtype))
 
 
 def paged_cache_scatter_q(arena, scales, tables, lens, new_kv):
@@ -308,107 +309,148 @@ def paged_cache_scatter_q(arena, scales, tables, lens, new_kv):
     the block table with the SAME trash-routing discipline (vacant/
     frozen rows carry all-trash tables, so both planes of a masked
     write land in the trash row).  Returns ``(arena, scales)``."""
-    codes, s = quantize_kv_heads(new_kv)
-    arena = paged_cache_scatter(arena, tables, lens, codes)
-    blk, off = _paged_decode_route(arena, tables, lens)
-    return arena, scales.at[blk, off].set(s)
+    with jax.named_scope("kv_append"):
+        codes, s = quantize_kv_heads(new_kv)
+        arena = paged_cache_scatter(arena, tables, lens, codes)
+        blk, off = _paged_decode_route(arena, tables, lens)
+        return arena, scales.at[blk, off].set(s)
 
 
-def _paged_chunk_route(arena, tables, start, n_valid, c):
-    """(blk, off) coordinates for a batch-1 chunk of ``c`` consecutive
-    positions ``start .. start+c-1`` through ``tables`` ([1,
-    max_blocks]); positions ``>= n_valid`` route to the trash row.  The
-    SINGLE source of the chunk trash-routing index math, shared by the
-    code-arena scatter and its ``_q`` scale-plane twin."""
+def _paged_span_route(arena, tables, start, stop, c):
+    """(blk, off) [B, c] arena coordinates of each row's ``c`` consecutive
+    positions ``start[b] .. start[b]+c-1`` through its table; a position
+    at or past ``stop[b]`` routes to the trash row.  The SINGLE source of
+    the row form's trash-routing index math, shared by the code arenas
+    and the ``_q`` scale planes."""
     block_len = arena.shape[1]
     trash = arena.shape[0] - 1
-    pos = start + jnp.arange(c, dtype=jnp.int32)
+    pos = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
     idx = jnp.minimum(pos // block_len, tables.shape[1] - 1)
-    blk = jnp.where(pos < n_valid, tables[0, idx], trash)
-    off = pos % block_len
-    return blk, off
+    blk = jnp.where(pos < stop[:, None],
+                    jnp.take_along_axis(tables, idx, axis=1), trash)
+    return blk, pos % block_len
+
+
+def _paged_span_blocks(arena, tables, start, stop, new):
+    """The block form of a span write: ``(blk [B * nb], slabs [B * nb, L,
+    ...])``, each row's ``nb = C // L + 1`` destination blocks from the one
+    holding ``start[b]`` on, and what each is to hold whole.  A slab holds
+    the new rows at ``[start, min(start + C, stop))`` and the block's own
+    rows elsewhere (the rows before ``start`` in the first block, those at
+    or past ``stop`` in the last one written): only those two blocks are
+    read, every one between is new rows throughout.  A block that gets no
+    new row, or whose table index lies past the table (clamped, so it
+    would repeat a block), is sent to the trash block: no two slabs name
+    one live block."""
+    b, c = new.shape[:2]
+    block_len, trash = arena.shape[1], arena.shape[0] - 1
+    nb, rest = c // block_len + 1, arena.shape[2:]
+    first = start // block_len
+    j = first[:, None] + jnp.arange(nb, dtype=jnp.int32)[None, :]
+    pos = j[:, :, None] * block_len + jnp.arange(block_len, dtype=jnp.int32)
+    hi = jnp.minimum(start + c, stop)
+    fresh = (pos >= start[:, None, None]) & (pos < hi[:, None, None])
+    live = fresh.any(axis=-1) & (j < tables.shape[1])
+    blk = jnp.where(live, jnp.take_along_axis(
+        tables, jnp.minimum(j, tables.shape[1] - 1), axis=1), trash)
+    # the new rows laid on block boundaries: row r of slab k is position
+    # (first + k) * L + r, new row k * L + r - (start - first * L); a slice
+    # of the rows padded by a block each side, which fuses with the merge
+    padded = jnp.pad(new, ((0, 0), (block_len, block_len))
+                     + ((0, 0),) * len(rest))
+    at = block_len - start + first * block_len
+    lay = jax.vmap(lambda row, i: jax.lax.dynamic_slice_in_dim(
+        row, i, nb * block_len))(padded, at)
+    lay = lay.reshape((b, nb, block_len) + rest)
+    last = jnp.clip((hi - 1) // block_len - first, 0, nb - 1)
+    edge = arena[jnp.stack([blk[:, 0], blk[jnp.arange(b), last]], axis=1)]
+    tail = (1,) * len(rest)
+    old = jnp.where((jnp.arange(nb) == 0).reshape((1, nb, 1) + tail),
+                    edge[:, :1], edge[:, 1:])
+    slabs = jnp.where(fresh.reshape(fresh.shape + tail), lay, old)
+    return blk.reshape(-1), slabs.reshape((b * nb, block_len) + rest)
+
+
+def paged_span_write(arena, tables, start, stop, new):
+    """Write ``new`` [B, C, ...] (trailing dims reshaped to the arena's
+    row) at each row's positions ``start[b] .. start[b]+C-1`` through its
+    table; positions at or past ``stop[b]`` are left as they are (the pad
+    tail of a prompt's last chunk, a verify row's draft pad).
+
+    Where the span covers whole blocks' worth of positions (``C`` a
+    multiple of the block length) the write goes a BLOCK at a time
+    (``_paged_span_blocks``, then one scatter of whole slabs): a block is
+    whole tiles in one contiguous run, while a row of a wide arena fills
+    one sublane of each of its tiles, and a row scatter pays a partial
+    write a tile.  Otherwise (a decode or verify step's few columns) a
+    row scatter, positions past ``stop`` sent to the trash row.  Every
+    block but the trash block ends the same either way.  The shape is
+    the only selector."""
+    b, c = new.shape[:2]
+    new = new.reshape((b, c) + arena.shape[2:]).astype(arena.dtype)
+    if c % arena.shape[1] == 0:
+        blk, slabs = _paged_span_blocks(arena, tables, start, stop, new)
+        return arena.at[blk].set(slabs)
+    blk, off = _paged_span_route(arena, tables, start, stop, c)
+    return arena.at[blk, off].set(new)
 
 
 def paged_chunk_scatter(arena, tables, start, n_valid, new_kv):
     """Write a batch-1 prefill chunk's K/V planes ([C, H_kv, D]) at
     global positions ``start .. start+C-1`` through the slot's block
     table (``tables`` is [1, max_blocks]).  Positions ``>= n_valid``
-    (the pad tail of the prompt's last chunk) write to the trash row:
-    the chunk shape is static, so the scatter always issues C writes
-    and masking is done by redirecting the target, never by shrinking
-    the shape."""
-    c = new_kv.shape[0]
-    blk, off = _paged_chunk_route(arena, tables, start, n_valid, c)
-    if arena.ndim == 3:
-        new_kv = new_kv.reshape(c, -1)
-    return arena.at[blk, off].set(new_kv.astype(arena.dtype))
+    (the pad tail of the prompt's last chunk) are not written: the chunk
+    shape is static, and masking is done by redirecting or merging,
+    never by shrinking the shape (``paged_span_write``)."""
+    with jax.named_scope("kv_append"):
+        return paged_span_write(arena, tables, jnp.reshape(start, (1,)),
+                                jnp.reshape(n_valid, (1,)), new_kv[None])
 
 
 def paged_chunk_scatter_q(arena, scales, tables, start, n_valid, new_kv):
     """Quantize-on-append twin of ``paged_chunk_scatter``: the chunk's
     [C, H_kv, D] planes quantize per position per kv head and both
-    codes and scales scatter through the table, pad-tail positions
-    (``>= n_valid``) trash-routed in BOTH arenas.  Returns
+    codes and scales are written through the table by the same rule,
+    pad-tail positions (``>= n_valid``) left out of BOTH arenas.  Returns
     ``(arena, scales)``."""
-    codes, s = quantize_kv_heads(new_kv)
-    arena = paged_chunk_scatter(arena, tables, start, n_valid, codes)
-    blk, off = _paged_chunk_route(arena, tables, start, n_valid,
-                                  new_kv.shape[0])
-    return arena, scales.at[blk, off].set(s)
+    with jax.named_scope("kv_append"):
+        codes, s = quantize_kv_heads(new_kv)
+        start, stop = jnp.reshape(start, (1,)), jnp.reshape(n_valid, (1,))
+        return (paged_span_write(arena, tables, start, stop, codes[None]),
+                paged_span_write(scales, tables, start, stop, s[None]))
 
 
 def paged_verify_scatter(arena, tables, lens, n_valid, new_kv):
     """Write a speculative verify forward's K/V planes ([B, C, H_kv, D])
     at per-row global positions ``lens[b] .. lens[b]+C-1`` through each
     row's block table — the batched generalization of
-    ``paged_chunk_scatter``'s multi-position machinery (that one is
-    batch-1 with a shared start; this one is per-row starts over the
-    decode mix).  Columns ``>= n_valid[b]`` (draft-pad tail, rows not
-    in spec mode this step) write to the trash row: the C shape is
-    static, so the scatter always issues B*C writes and masking is done
-    by redirecting the target.  The ``n_valid`` mask is also the
-    rollback guarantee's other half: a draft position can only ever
-    land inside its own row's blocks at a slot ``> lens`` that the row
-    itself overwrites before its ``lens`` advances past it, so a
+    ``paged_chunk_scatter`` (that one is batch-1 with a shared start;
+    this one is per-row starts over the decode mix; the recurrent
+    families' chunk and decode steps write through it too).  Columns
+    ``>= n_valid[b]`` (draft-pad tail, rows not in spec mode this step)
+    are not written (``paged_span_write``).  The ``n_valid`` mask is
+    also the rollback guarantee's other half: a draft position can only
+    ever land inside its own row's blocks at a slot ``> lens`` that the
+    row itself overwrites before its ``lens`` advances past it, so a
     rejected draft's K/V is finite garbage behind the ``lens`` mask,
     never another sequence's data."""
-    b, c = new_kv.shape[0], new_kv.shape[1]
-    blk, off = _paged_verify_route(arena, tables, lens, n_valid, c)
-    if arena.ndim == 3:
-        new_kv = new_kv.reshape(b, c, -1)
-    return arena.at[blk, off].set(new_kv.astype(arena.dtype))
-
-
-def _paged_verify_route(arena, tables, lens, n_valid, c):
-    """(blk, off) coordinates for a verify forward's per-row spans
-    ``lens[b] .. lens[b]+c-1`` through each row's table; columns
-    ``>= n_valid[b]`` route to the trash row.  The SINGLE source of the
-    verify trash-routing index math, shared by the code-arena scatter
-    and its ``_q`` scale-plane twin."""
-    block_len = arena.shape[1]
-    trash = arena.shape[0] - 1
-    pos = lens[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-    idx = jnp.minimum(pos // block_len, tables.shape[1] - 1)
-    blk = jnp.where(jnp.arange(c, dtype=jnp.int32)[None, :]
-                    < n_valid[:, None],
-                    jnp.take_along_axis(tables, idx, axis=1), trash)
-    off = pos % block_len
-    return blk, off
+    with jax.named_scope("kv_append"):
+        return paged_span_write(arena, tables, lens, lens + n_valid, new_kv)
 
 
 def paged_verify_scatter_q(arena, scales, tables, lens, n_valid, new_kv):
     """Quantize-on-append twin of ``paged_verify_scatter``: the verify
     forward's [B, C, H_kv, D] planes quantize per position per kv head;
-    codes and scales scatter with the same per-row trash mask (columns
+    codes and scales are written with the same per-row mask (columns
     ``>= n_valid[b]``), so the rollback guarantee carries over to both
     planes — a rejected draft's codes AND its scales are finite garbage
     behind the ``lens`` mask, overwritten before lens reaches them.
     Returns ``(arena, scales)``."""
-    codes, s = quantize_kv_heads(new_kv)
-    arena = paged_verify_scatter(arena, tables, lens, n_valid, codes)
-    blk, off = _paged_verify_route(arena, tables, lens, n_valid,
-                                   new_kv.shape[1])
-    return arena, scales.at[blk, off].set(s)
+    with jax.named_scope("kv_append"):
+        codes, s = quantize_kv_heads(new_kv)
+        stop = lens + n_valid
+        return (paged_span_write(arena, tables, lens, stop, codes),
+                paged_span_write(scales, tables, lens, stop, s))
 
 
 def cache_prefill_write(cache, kv_bshd):

@@ -536,7 +536,13 @@ def test_a_migration_without_a_parcel_recomputes(model, weights):
 # commit's after six), and rows frozen behind their budget write their own
 # dead block or the trash block, so the arenas' absolute sums are this
 # schedule's; floats to 1e-6 so that another host's sum order does not fail
-# a byte-identical program
+# a byte-identical program.  The sums leave the trash block out: what lands
+# there is nobody's, and since a prompt chunk is written a block at a time
+# its pad rows no longer go there.  These four were taken on commit 586706d,
+# the last with the row scatter, from this engine run to its end (with the
+# trash block it summed 2983.379067473463, 2937.021637606551,
+# 2973.299260141095 and 3118.6366175461735), so the other blocks are the
+# same as there
 PARENT_DENSE_TRACE = {
     "tokens": [[7, 208, 145, 85, 129, 251, 44, 223, 78],
                [226, 166, 166, 166, 166],
@@ -545,8 +551,8 @@ PARENT_DENSE_TRACE = {
                [249, 200, 216, 74, 246, 218, 126, 184, 151, 89],
                [188, 75, 69], [12, 188, 183, 88, 199, 185]],
     "tables_mid": [[4, 3, 2, 12, 12], [9, 10, 12, 12, 12], [5, 6, 7, 8, 12]],
-    "arena_abs": [2983.379067473463, 2937.021637606551, 2973.299260141095,
-                  3118.6366175461735],
+    "arena_abs": [2742.323221500381, 2708.0530247335555, 2730.7472093754914,
+                  2837.6427060763526],
     "donate": [[6, 7, 8, 9], [7, 8, 9, 10]]}
 
 
@@ -571,7 +577,7 @@ def test_a_dense_engine_is_what_it_was_on_the_parent():
     assert [r.output.tolist() for r in reqs] == want["tokens"]
     assert [list(eng._donate), list(eng._donate_blk)] == want["donate"]
     assert eng._slot_state == [] and len(eng._arenas) == 4
-    sums = [float(np.abs(np.asarray(a, np.float64)).sum())
+    sums = [float(np.abs(np.asarray(a, np.float64)[:-1]).sum())
             for a in eng._arenas]
     np.testing.assert_allclose(sums, want["arena_abs"], rtol=1e-6)
 
