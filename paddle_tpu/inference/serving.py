@@ -256,6 +256,7 @@ from ..models.generation import (GenerationConfig, LatentCacheSpec,
                                  SlotStateError, init_paged_kv_arena,
                                  init_paged_latent_arena, init_slot_state,
                                  model_arrays, slot_state_spec)
+from ..observability import collector as obs_collector
 from ..observability import metrics as obs_metrics
 from ..observability.flightrec import ENGINE_EVENT, FlightRecorder
 from ..observability.spans import instant as _span_instant
@@ -547,8 +548,6 @@ class _ServingInstruments:
             "queued requests finished with status 'timeout' because "
             "their wait exceeded max_queue_delay_s — shed-by-deadline "
             "instead of served-late")
-        self.evictions = r.counter(
-            "serving.slot_evictions", "slot frees at request retirement")
         self.prefix_hits = r.counter(
             "serving.prefix_hits", "prompt blocks mapped from the prefix "
             "cache at admission instead of being recomputed")
@@ -728,6 +727,15 @@ class _ServingInstruments:
             "gap a dispatch-ahead pipeline must hide under device "
             "time (observed only for steps that dispatched work)",
             buckets=_STEP_BUCKETS)
+        self.step_offcpu = r.histogram(
+            "serving.step.offcpu_seconds",
+            "time one step() was off the CPU outside a device wait: "
+            "the step's wall less its device waits (serving.harvest."
+            "wait, serving.prefill.wait) and injected fault stalls, "
+            "less the thread's CPU time over the same stretch, at 0 "
+            "at the least — a descheduled host, a blocking enqueue, a "
+            "lock (observed only for steps that dispatched work)",
+            buckets=_STEP_BUCKETS)
         self.step_dispatch = r.histogram(
             "serving.step.dispatch_seconds",
             "time one step() spent inside compiled dispatches (chunk "
@@ -887,6 +895,33 @@ class _Phase(_span):
     def __exit__(self, *exc):
         self.stop()
         return False
+
+
+class _Wait(_Phase):
+    """A phase in which the step's thread waits for the device (or
+    sleeps an injected stall): its wall time and the thread's CPU time
+    over it are left out of the step's off-CPU account
+    (``serving.step.offcpu_seconds``)."""
+
+    __slots__ = ("_eng", "_cpu0")
+
+    def __init__(self, eng, name: str, **attrs):
+        super().__init__(eng._clock, name, **attrs)
+        self._eng = eng
+
+    def __enter__(self):
+        super().__enter__()
+        self._cpu0 = time.thread_time_ns()
+        return self
+
+    def stop(self) -> float:
+        if self.seconds is None:
+            cpu = time.thread_time_ns() - self._cpu0
+            super().stop()
+            if self._eng._in_step:
+                self._eng._wait_s += self.seconds
+                self._eng._wait_cpu_ns += cpu
+        return self.seconds
 
 
 def _call_quiet(fn, *args):
@@ -1759,6 +1794,8 @@ class ServingEngine:
         # disabled mid-run
         self._m = _ServingInstruments(
             registry if registry is not None else obs_metrics.get_registry())
+        # the collector's pauses, process-wide (runtime.gc.pause_seconds)
+        obs_collector.install()
         self._m.slots_total.set(self.num_slots)
         self._m.kv_quant_dtype.set(1, dtype=self.kv_cache_dtype)
         self._m.weights_quant_dtype.set(1, dtype=self.weight_dtype)
@@ -1835,6 +1872,8 @@ class ServingEngine:
         self._pend_q: deque = deque()
         self._overlap_s = 0.0
         self._stall_s = 0.0
+        self._wait_s = 0.0           # this step's device waits and stalls
+        self._wait_cpu_ns = 0        # the thread's CPU time inside them
         self._in_step = False
         self._lazy_parcels: List[int] = []   # tier keys awaiting rows
         # finishes discovered by a flush OUTSIDE a step (cancel()
@@ -1997,6 +2036,9 @@ class ServingEngine:
     def _phase(self, name: str, **attrs) -> _Phase:
         return _Phase(self._clock, name, **attrs)
 
+    def _wait(self, name: str, **attrs) -> _Wait:
+        return _Wait(self, name, **attrs)
+
     # -- dispatch-ahead pipeline (plan / harvest) --
     def _charge_overlap(self, dt: float):
         """Account time spent blocking on a PREVIOUS iteration's
@@ -2072,16 +2114,17 @@ class ServingEngine:
         with _span("serving.harvest", reason=reason):
             p = self._pend_q.popleft()
             self._m.async_depth.set(len(self._pend_q))
-            with self._phase("serving.harvest.wait") as wait:
+            with self._wait("serving.harvest.wait") as wait:
                 toks = np.asarray(p.toks_d)
                 tok = np.array(p.tok_d)   # np.array: writable host copies
                 lens = np.array(p.lens_d)
                 done = np.array(p.done_d)     # the finish bitmap
                 self._count_experts(p)
                 self._charge_overlap(wait.stop())
-            toks = self._checked_harvest(toks)
             n_before = len(out)
-            self._absorb_block(p, toks, tok, lens, done, out)
+            with _span("serving.harvest.absorb"):
+                toks = self._checked_harvest(toks)
+                self._absorb_block(p, toks, tok, lens, done, out)
             if self.async_depth == 1 and len(out) > n_before:
                 # the PR-10 contract at depth 1: deferral is legal
                 # ONLY when no rider can finish inside the block (EOS
@@ -3043,8 +3086,6 @@ class ServingEngine:
                 lag: int = 0):
         req.finish_time = t
         req.state = "finished"
-        if req.slot is not None:
-            self._m.evictions.inc()
         req.slot = None
         self._m.requests_finished.inc()
         if req.latency is not None:
@@ -4083,7 +4124,7 @@ class ServingEngine:
             return
         owed, self._first_owed = self._first_owed, []
         with _span("serving.prefill", landed=len(owed)):
-            with self._phase("serving.prefill.wait") as wait:
+            with self._wait("serving.prefill.wait") as wait:
                 # sync: chunk_final
                 toks = jax.device_get([tok_d for _, tok_d in owed])
             # the materialization is part of the chunks' dispatch, as the
@@ -4482,9 +4523,12 @@ class ServingEngine:
         overlap_seconds``, injected fault stalls into ``serving.fault.
         stall_seconds``, and the remainder is ``serving.step.
         host_seconds`` — the pure host-scheduler slice the
-        dispatch-ahead pipeline hides under device time.  Steps that
-        dispatched nothing (idle admission polls) observe neither
-        host nor dispatch.
+        dispatch-ahead pipeline hides under device time.  The thread's
+        CPU time, read at the step's and its device waits' boundaries,
+        gives ``serving.step.offcpu_seconds``: the step's wall outside
+        its waits and stalls that the thread spent off the CPU.  Steps
+        that dispatched nothing (idle admission polls) observe neither
+        host nor dispatch nor off-CPU time.
 
         Each phase is delimited ONCE (``_phase``): the same boundaries
         open and close a span, so under a profiler session the
@@ -4492,14 +4536,18 @@ class ServingEngine:
         ``serving.prefill`` (a chunk, > ``.dispatch``; the step's first
         tokens landing, > ``.wait``), ``serving.spec_verify``,
         ``serving.plan``, ``serving.decode_block``, ``serving.harvest``
-        (> ``.wait``) on the device trace's clock."""
+        (> ``.wait``, then ``.absorb``: ``_checked_harvest`` and
+        ``_absorb_block``) on the device trace's clock."""
         self._step_idx += 1
         self._disp_s = 0.0
         self._overlap_s = 0.0
         self._stall_s = 0.0
+        self._wait_s = 0.0
+        self._wait_cpu_ns = 0
         self._in_step = True
         with self._phase("serving.step", step=self._step_idx,
                          queued=len(self._queue)) as whole:
+            cpu0 = time.thread_time_ns()
             try:
                 out = self._step_inner(now)
                 # reconcile any demote gathers this step enqueued so
@@ -4508,12 +4556,16 @@ class ServingEngine:
                 self._reconcile_host_tier()
             finally:
                 self._in_step = False
+                cpu = time.thread_time_ns() - cpu0
         disp = self._disp_s
         if disp > 0.0:
             self._m.step_dispatch.observe(disp)
             self._m.step_host.observe(
                 max(whole.seconds - disp - self._overlap_s
                     - self._stall_s, 0.0))
+            self._m.step_offcpu.observe(max(
+                whole.seconds - self._wait_s
+                - (cpu - self._wait_cpu_ns) * 1e-9, 0.0))
         return out
 
     # graftlint: plan-phase
@@ -4552,8 +4604,8 @@ class ServingEngine:
                         f"restart)")
                 stall = self._fault.take_stall()
                 if stall:
-                    with self._phase("serving.fault.stall",
-                                     seconds=stall) as ph:
+                    with self._wait("serving.fault.stall",
+                                    seconds=stall) as ph:
                         time.sleep(stall)
                     dt = ph.seconds
                     # charge the injected sleep to its OWN histogram and
@@ -4786,7 +4838,7 @@ class ServingEngine:
             self._pend_q.pop()
             self._m.async_depth.set(0)
             with _span("serving.harvest", reason=reason):
-                with self._phase("serving.harvest.wait") as wait:
+                with self._wait("serving.harvest.wait") as wait:
                     toks = np.asarray(new_pend.toks_d)      # [B, n]
                     tok = np.array(new_pend.tok_d)  # writable copies
                     lens = np.array(new_pend.lens_d)
@@ -4796,9 +4848,10 @@ class ServingEngine:
                 # part of the dispatch, exactly the lockstep engine's
                 # attribution
                 self._disp_s += wait.seconds
-                toks = self._checked_harvest(toks)
-                self._absorb_block(new_pend, toks, tok, lens, done,
-                                   finished)
+                with _span("serving.harvest.absorb"):
+                    toks = self._checked_harvest(toks)
+                    self._absorb_block(new_pend, toks, tok, lens, done,
+                                       finished)
         return finished
 
     def _stall_diagnosis(self, wall_timeout_s: float) -> str:

@@ -14,7 +14,12 @@ Two cooperating layers (see the module docstrings for design notes):
   written into the profiler's own trace (one clock with the device)
   and into ``runtime.HostTracer``'s buffer (``recorded()``);
   ``merge_chrome_traces`` writes host lanes into one Perfetto-loadable
-  file.
+  file.  ``recorded()`` reads the one buffer on one clock, the one the
+  profiler stamps its host events with (the wall clock).
+- :mod:`~paddle_tpu.observability.collector` — ``install()`` hooks the
+  cyclic garbage collector: generation 1 and 2 pauses into
+  ``runtime.gc.pause_seconds{generation}``, and a ``runtime.gc`` span
+  each while a record window is live.
 - :mod:`~paddle_tpu.observability.flightrec` — the per-request
   ``FlightRecorder``: a bounded ring of structured lifecycle events
   the serving engine emits, with ``timeline()``/``explain()`` queries,
@@ -46,6 +51,7 @@ from .spans import (  # noqa: F401
     format_span_name, instant, merge_chrome_traces, parse_span_name,
     recorded, span,
 )
+from . import collector  # noqa: F401
 from .flightrec import (  # noqa: F401
     EVENT_KINDS, FlightEvent, FlightRecord, FlightRecorder,
     explain_events, load_flight_record,
@@ -60,7 +66,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "DEFAULT_BUCKETS",
     "NAME_RE", "diff_snapshots", "get_registry",
     "span", "instant", "format_span_name", "parse_span_name",
-    "merge_chrome_traces", "recorded",
+    "merge_chrome_traces", "recorded", "collector",
     "EVENT_KINDS", "FlightEvent", "FlightRecord", "FlightRecorder",
     "explain_events", "load_flight_record",
     "ALERT_KINDS", "SLOBurnRateMonitor", "StitchedEvent",

@@ -244,12 +244,23 @@ class Histogram(_Instrument):
         self._vals: Dict[Tuple, list] = {}
 
     def observe(self, value: float, **labels):
+        self._record(value, labels, True)
+
+    def try_observe(self, value: float, **labels) -> bool:
+        """``observe``, unless the lock is held: then nothing, and
+        False.  For a caller that may run inside a reader's critical
+        section on the reader's own thread (a ``gc`` callback)."""
+        return self._record(value, labels, False)
+
+    def _record(self, value, labels, wait) -> bool:
         if not self._reg._enabled:
-            return
+            return True
         key = self._resolve_labels(labels) if (labels or self.label_names) \
             else _NO_LABELS
         i = bisect.bisect_left(self.bounds, value)
-        with self._lock:
+        if not self._lock.acquire(wait):
+            return False
+        try:
             cell = self._vals.get(key)
             if cell is None:
                 cell = [[0] * (len(self.bounds) + 1), 0, 0.0]
@@ -257,6 +268,9 @@ class Histogram(_Instrument):
             cell[0][i] += 1
             cell[1] += 1
             cell[2] += value
+        finally:
+            self._lock.release()
+        return True
 
     def summary(self, **labels) -> dict:
         key = self._resolve_labels(labels) if (labels or self.label_names) \

@@ -9,12 +9,20 @@ TensorBoard capture) or while ``runtime.HostTracer`` is enabled (the
   in the profiler's own ``.xplane.pb`` / Perfetto file on the host's
   ``python`` line, on ONE clock with the device's ``XLA Ops`` by
   construction, its attributes as event stats;
-- it is recorded in the ONE in-memory buffer, ``HostTracer``'s, on that
-  tracer's monotonic clock.  The tracer's native event tuple has no
-  args field (native C++ and Python fallback share the
-  ``(kind, t0, t1, tid, value, name)`` schema), so attributes are
-  encoded into the event name with ``;k=v`` suffixes; ``recorded()``
-  and the chrome-trace writer decode them back.
+- it is recorded in the ONE in-memory buffer, ``HostTracer``'s.  The
+  tracer stamps on its monotonic clock (ordering and durations);
+  ``recorded()`` gives the times on the clock the profiler stamps its
+  host events with, the wall clock (``CLOCK_REALTIME``, what
+  ``time.time_ns()`` reads): an anchor between the two clocks is taken
+  when a record window starts and again when ``recorded()`` is read,
+  and the times between are interpolated, so a slewed wall clock costs
+  nothing.  A profiler file counts its times from its session's start
+  (the ``profile_start_time`` stat of its ``Task Environment`` plane);
+  adding that start to a file's time gives ``recorded()``'s clock.  The
+  tracer's native event tuple has no args field (native C++ and Python
+  fallback share the ``(kind, t0, t1, tid, value, name)`` schema), so
+  attributes are encoded into the event name with ``;k=v`` suffixes;
+  ``recorded()`` and the chrome-trace writer decode them back.
 
 Going live because of a profiler session alone starts a new trace
 generation and clears the buffer, exactly as ``Profiler`` does at a
@@ -36,6 +44,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 
 from jax.profiler import TraceAnnotation as _Annotation
 
@@ -52,10 +61,27 @@ _trace_gen = 0
 # alone (no Profiler enabled it): the one state ``_live`` has to undo
 _session_owned = False
 _window_mu = threading.Lock()
+# (tracer clock, wall clock) in ns at the current window's start
+_window_anchor = None
+_ANCHOR_SAMPLES = 5
 
 
 def current_trace_generation() -> int:
     return _trace_gen
+
+
+def _anchor():
+    """One ``(tracer ns, wall ns)`` pair: of a few reads of the wall
+    clock, each between two reads of the tracer's clock, the one read
+    between the closest two, paired with their midpoint."""
+    best = None
+    for _ in range(_ANCHOR_SAMPLES):
+        a = rt.now_ns()
+        w = time.time_ns()
+        b = rt.now_ns()
+        if best is None or b - a < best[0]:
+            best = (b - a, (a + b) // 2, w)
+    return best[1], best[2]
 
 
 def start_recording(for_session: bool = False):
@@ -65,13 +91,14 @@ def start_recording(for_session: bool = False):
     at a window's start; ``_live`` calls it ``for_session`` when a
     span finds a profiler session live (and undoes it when the session
     has ended)."""
-    global _trace_gen, _session_owned
+    global _trace_gen, _session_owned, _window_anchor
     with _window_mu:
         if for_session and rt.HostTracer.enabled:
             return              # another thread's span went live first
         rt.HostTracer.clear()
         _trace_gen += 1
         _session_owned = for_session
+        _window_anchor = _anchor()
         rt.HostTracer.enable()
 
 
@@ -91,6 +118,15 @@ def _live() -> bool:
         start_recording(for_session=True)
         return True
     return False
+
+
+def recording() -> bool:
+    """Whether the buffer records right now, as ``_live`` would answer
+    but without its transitions: no lock is taken and nothing is made,
+    so a caller that may run inside the window's own critical section
+    (a ``gc`` callback) can ask."""
+    return rt.HostTracer.enabled and (
+        not _session_owned or _Annotation.is_enabled())
 
 
 def _esc_attr(v) -> str:
@@ -145,11 +181,17 @@ class span:
 
     def __enter__(self):
         if _live():
-            self._gen = _trace_gen
-            self._ann = _Annotation(self._name, **self._attrs)
-            self._ann.__enter__()
-            rt.HostTracer.begin(format_span_name(self._name, self._attrs))
+            self.open()
         return self
+
+    def open(self):
+        """Open the range whatever ``_live`` says; for a caller that has
+        asked ``recording()`` itself.  Closed by ``__exit__``."""
+        self._gen = _trace_gen
+        encoded = format_span_name(self._name, self._attrs)
+        self._ann = _Annotation(self._name, **self._attrs)
+        self._ann.__enter__()
+        rt.HostTracer.begin(encoded)     # next to the profiler's stamp
 
     def __exit__(self, *exc):
         ann = self._ann
@@ -172,13 +214,19 @@ def instant(name: str, **attrs):
 
 def recorded() -> list:
     """The closed spans of the current trace generation as
-    ``(name, t0_ns, t1_ns, thread, attrs)``, times on the tracer's
-    monotonic clock: what ``HostTracer.events()`` holds, decoded."""
+    ``(name, t0_ns, t1_ns, thread, attrs)``, times in ns on the clock
+    the profiler stamps its host events with (the wall clock): what
+    ``HostTracer.events()`` holds, decoded, its monotonic times laid
+    between the window's anchor and one taken now."""
+    m1, w1 = _anchor()
+    m0, w0 = _window_anchor or (m1, w1)
+    rate = (w1 - w0) / (m1 - m0) if m1 > m0 else 1.0
     out = []
     for kind, t0, t1, tid, _value, raw in rt.HostTracer.events():
         if kind == 0:
             name, attrs = parse_span_name(raw)
-            out.append((name, t0, t1, tid, attrs))
+            out.append((name, w0 + round((t0 - m0) * rate),
+                        w0 + round((t1 - m0) * rate), tid, attrs))
     return out
 
 

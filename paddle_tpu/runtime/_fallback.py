@@ -67,7 +67,8 @@ class _TracerState(threading.local):
 
 _trace_enabled = False
 _trace_events: list = []
-_trace_mu = threading.Lock()
+# reentrant: a gc callback may record a span while this thread holds it
+_trace_mu = threading.RLock()
 _trace_tls = _TracerState()
 
 

@@ -1,4 +1,4 @@
-"""The engine's step on the profiler's clock (PR 28): under a bare
+"""The engine's step on the profiler's clock: under a bare
 ``jax.profiler.start_trace`` the spans of ``ServingEngine.step()`` sit
 in the ``.xplane.pb`` beside where the device's line would be and in
 ``observability.spans.recorded()``; the step's accumulators are taken
@@ -7,11 +7,14 @@ at the spans' boundaries; tracing observes and reorders nothing.
 Tier-1 budget: ONE module-scoped pair of tiny engines (1-layer llama,
 float32, ``steps_per_call=2``: budgets end inside blocks, and the last
 rider's odd last token takes the one-step program), the same trace
-replayed with a profiler session live and without.
+replayed with a profiler session live and without; one engine more,
+warmed on the same trace, for the off-CPU account.
 """
 
+import gc
 import glob
 import os
+import time
 from collections import defaultdict
 from types import SimpleNamespace
 
@@ -22,8 +25,9 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import models
 from paddle_tpu import runtime as rt
+from paddle_tpu.inference.faultinject import FaultInjector
 from paddle_tpu.inference.serving import ServingEngine
-from paddle_tpu.observability import MetricsRegistry, spans
+from paddle_tpu.observability import MetricsRegistry, collector, spans
 from paddle_tpu.observability.flightrec import FlightRecorder
 
 P, C = 6, 32
@@ -35,18 +39,27 @@ CHILDREN = ("serving.admit", "serving.prefill", "serving.plan",
 SYNC_TAIL = ("budget", "eos", "off", "mask", "penalty", "spec")
 
 
-def _run(net, cfg, session_dir):
-    reg, rec = MetricsRegistry(), FlightRecorder()
-    eng = ServingEngine(net, num_slots=2, prompt_len=P, max_cache_len=C,
-                        steps_per_call=2, compute_dtype="float32",
-                        registry=reg, flight_recorder=rec)
+def _engine(net, **kw):
+    reg = MetricsRegistry()
+    return reg, ServingEngine(
+        net, num_slots=2, prompt_len=P, max_cache_len=C, steps_per_call=2,
+        compute_dtype="float32", registry=reg, **kw)
+
+
+def _submit(eng, cfg):
     rng = np.random.default_rng(11)
+    return [eng.submit(
+        rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32),
+        max_new_tokens=m) for n, m in SPECS]
+
+
+def _run(net, cfg, session_dir):
+    rec = FlightRecorder()
+    reg, eng = _engine(net, flight_recorder=rec)
     if session_dir:
         jax.profiler.start_trace(session_dir)
     try:
-        reqs = [eng.submit(
-            rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32),
-            max_new_tokens=m) for n, m in SPECS]
+        reqs = _submit(eng, cfg)
         eng.run()
         got = spans.recorded() if session_dir else None
     finally:
@@ -57,7 +70,7 @@ def _run(net, cfg, session_dir):
 
 
 @pytest.fixture(scope="module")
-def runs(tmp_path_factory):
+def tiny():
     paddle.seed(2028)
     cfg = models.LlamaConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -65,6 +78,12 @@ def runs(tmp_path_factory):
         max_position_embeddings=64)
     net = models.LlamaForCausalLM(cfg)
     net.eval()
+    return net, cfg
+
+
+@pytest.fixture(scope="module")
+def runs(tmp_path_factory, tiny):
+    net, cfg = tiny
     trace_dir = str(tmp_path_factory.mktemp("xplane"))
     live = _run(net, cfg, trace_dir)
     # the session has ended: a further step records nothing
@@ -76,6 +95,22 @@ def runs(tmp_path_factory):
     xplane = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
     return SimpleNamespace(live=live, quiet=quiet, xplane=xplane)
+
+
+def _harvests(rec):
+    """[(harvest, its .wait spans, its .absorb spans)] of a recorded run."""
+    out = []
+    for n, t0, t1, _tid, a in rec:
+        if n == "serving.harvest":
+            inside = [(m, s0, s1) for m, s0, s1, _, _ in rec
+                      if m.startswith("serving.harvest.")
+                      and t0 <= s0 and s1 <= t1]
+            out.append(((t0, t1, a["reason"]),
+                        [(s0, s1) for m, s0, s1 in inside
+                         if m == "serving.harvest.wait"],
+                        [(s0, s1) for m, s0, s1 in inside
+                         if m == "serving.harvest.absorb"]))
+    return out
 
 
 def _host_events(xplane):
@@ -149,6 +184,29 @@ def test_spans_sit_in_the_profilers_trace_and_in_recorded(runs):
         assert by_name[n] == sum(e[0] == n for e in ev), n
     assert {a["reason"] for n, *_, a in rec if n == "serving.harvest"} \
         == reasons
+
+
+def test_the_harvest_splits_into_its_fetch_and_its_absorb(runs):
+    rec = runs.live.recorded
+    harvests = _harvests(rec)
+    assert harvests
+    for (h0, h1, _), waits, absorbs in harvests:
+        # one fetch, then the host's bookkeeping, inside the harvest
+        assert len(waits) == len(absorbs) == 1
+        (w0, w1), (a0, a1) = waits[0], absorbs[0]
+        assert w1 <= a0
+        assert (w1 - w0) + (a1 - a0) <= h1 - h0
+    # both sites: the pipeline's own overlap point and a synchronous tail
+    reasons = {r for (_, _, r), _, _ in harvests}
+    assert "deferred" in reasons and reasons & set(SYNC_TAIL)
+    assert sum(n == "serving.harvest.absorb" for n, *_ in rec) == \
+        len(harvests)
+    # the absorb is a grandchild: a step's direct children stay the five
+    steps = sorted((t0, t1) for n, t0, t1, _, _ in rec if n == "serving.step")
+    kids = _children_of([(n, t0, t1) for n, t0, t1, _, _ in rec
+                         if n.startswith("serving.") and n != "serving.step"],
+                        steps)
+    assert {n for v in kids.values() for n, _, _ in v} == set(CHILDREN)
 
 
 def test_session_end_makes_spans_quiet(runs):
@@ -225,8 +283,10 @@ def test_schedule_identical_with_a_session_live(runs):
 
 def test_nothing_live_formats_nothing(monkeypatch):
     assert not rt.HostTracer.enabled
+    did = []
 
     def boom(*a, **k):
+        did.append(a)               # a gc callback's raise is swallowed
         raise AssertionError("a quiet span did work")
     monkeypatch.setattr(spans, "format_span_name", boom)
     monkeypatch.setattr(spans, "_Annotation", SimpleNamespace(
@@ -234,3 +294,54 @@ def test_nothing_live_formats_nothing(monkeypatch):
     with spans.span("serving.decode_block", steps=2, active=1) as sp:
         assert sp._ann is None
     spans.instant("serving.request.finish", request=1)
+    # the collector's hook, outside a window, spans nothing either
+    collector.install()
+    gc.collect(1)
+    gc.collect(2)
+    assert did == []
+
+
+def test_offcpu_time_counts_a_stop_and_not_a_wait_or_a_stall(tiny):
+    """``serving.step.offcpu_seconds``: one observation a dispatching
+    step; a sleep in the plan is off the CPU outside any wait, a sleep
+    inside a harvest's fetch is a device wait, an injected stall is
+    neither host nor wait."""
+    net, cfg = tiny
+    inj = FaultInjector()
+    reg, eng = _engine(net, fault_injector=inj)
+    _submit(eng, cfg)
+    eng.run()                       # every program compiled: warm
+    off = reg.get("serving.step.offcpu_seconds")
+    disp = reg.get("serving.step.dispatch_seconds")
+    sleeps, armed = [], {}
+
+    def sleepy(fn, where):
+        def wrapped(*a, **k):
+            if armed.pop(where, False):
+                sleeps.append(where)
+                time.sleep(0.1)
+            return fn(*a, **k)
+        return wrapped
+    eng._build_samp = sleepy(eng._build_samp, "plan")
+    eng._count_experts = sleepy(eng._count_experts, "wait")
+    _submit(eng, cfg)
+    seen = {}
+
+    def step_until(kind):
+        while True:
+            n0, s0 = off.summary()["count"], off.summary()["sum"]
+            eng.step()
+            if kind in sleeps or kind == "stall":
+                sleeps.clear()
+                return off.summary()["count"] - n0, off.summary()["sum"] - s0
+    inj.stall_steps(1, 0.2)
+    seen["stall"] = step_until("stall")
+    armed["plan"] = True
+    seen["plan"] = step_until("plan")
+    armed["wait"] = True
+    seen["wait"] = step_until("wait")
+    eng.run()
+    assert seen["stall"][0] == 1 and seen["stall"][1] < 0.1, seen
+    assert seen["plan"][0] == 1 and seen["plan"][1] >= 0.09, seen
+    assert seen["wait"][0] == 1 and seen["wait"][1] < 0.05, seen
+    assert off.summary()["count"] == disp.summary()["count"] > 0

@@ -132,6 +132,9 @@ REQUIRED_INSTRUMENTS = {
     "serving.async.depth": ("gauge", ()),
     "serving.step.overlap_seconds": ("histogram", ()),
     "serving.fault.stall_seconds": ("histogram", ()),
+    # a step's time off the CPU outside its device waits: where the
+    # host stops (inference/serving.py _ServingInstruments)
+    "serving.step.offcpu_seconds": ("histogram", ()),
     # multi-tenant batched LoRA serving (PR 11, inference/lora.py
     # AdapterStore + inference/serving.py _ServingInstruments):
     # adapter residency across the HBM arena / host-RAM tiers, swap-in
