@@ -171,12 +171,17 @@ planes, ledger) ran SERIALLY with device compute.  This engine splits
   chunk's sampled token stays a device array while the step's further
   chunks are enqueued, and all of a step's first tokens become host
   truth together before the verify and the plan read them (one
-  ``chunk_final`` flush before the first of them, one fetch after the
-  last).  The kill-switch arm lands each behind its own chunk through
+  ``chunk_final`` flush and one fetch, after the last chunk is
+  enqueued).  The kill-switch arm lands each behind its own chunk through
   the same function, so between the arms the events a landing emits
   (a finish at the first token, a handoff) move behind the step's
   later ``prefill_chunk`` events and nothing else moves: every
   request's events, the finished order and the counters are equal.
+- a block whose riders end by their budget, and can end no other way,
+  is the plan's to know: before its sync harvest the slots they leave
+  are admitted into and the next step's first chunks enqueued
+  (``_admit_ahead``), their first tokens landing with the next step's.
+  Both arms take that order, so it moves no request's events.
 - the tiered prefix cache's demote gather rides the same pipeline:
   reclaim ENQUEUES the at-rest-bytes gather during plan and the host
   copies reconcile lazily at the next harvest point (the PR-8
@@ -350,9 +355,9 @@ ASYNC_SYNC_REASONS = (
     "penalty",      # a repetition-penalty presence plane is host-built
     "spec",         # speculative accept/rollback is a host decision
     "chunk_final",  # a prompt's final chunk samples the first token:
-    #                 flushed before a step's first final chunk; the
-    #                 step's first tokens land together after its last
-    #                 chunk is enqueued (``_land_first_tokens``)
+    #                 a step's first tokens land together, the pending
+    #                 block flushed first, after its last chunk is
+    #                 enqueued (``_land_first_tokens``)
     "resume",       # a swap-in rewrites the slot's host carries
     "preempt",      # a swap-out reads the slot's host carries
     "cancel",       # cancel() must know which tokens already exist
@@ -437,6 +442,11 @@ class _ServingInstruments:
             "enqueued (a dispatch-ahead engine), one a prompt on the "
             "lockstep arm; serving.prefills over this is the first "
             "tokens one wait on the device buys")
+        self.admits_ahead = r.counter(
+            "serving.admits_ahead", "admissions into a slot that the "
+            "decode block in flight leaves by its rider's budget, made "
+            "before the block's outputs are fetched (a dispatch-ahead "
+            "engine); serving.prefills is what it is a share of")
         self.prefill_chunks = r.counter(
             "serving.prefill_chunks", "prompt chunks computed (chunked-"
             "prefill dispatches; prefix-cached blocks never become "
@@ -818,7 +828,7 @@ class _ServingInstruments:
             "chosen tenant")
         self._base = {}
         for c in (self.prefills, self.first_token_fetches,
-                  self.prefill_chunks, self.decode_steps,
+                  self.admits_ahead, self.prefill_chunks, self.decode_steps,
                   self.busy_slot_steps, self.block_dispatches,
                   self.requests_finished, self.requests_cancelled,
                   self.prefix_hits, self.prefix_misses,
@@ -1778,9 +1788,13 @@ class ServingEngine:
         self._queue: deque = deque()
         self._prefilling: deque = deque()
         # (request, device token) of the prompts whose final chunk is
-        # enqueued and whose first token has not landed yet: filled and
-        # emptied inside one step (``_land_first_tokens``)
+        # enqueued and whose first token has not landed yet: emptied by
+        # the next landing (``_land_first_tokens``), in the same step or,
+        # for a chunk enqueued ahead of a harvest, the next
         self._first_owed: list = []
+        # the next step's chunk pacing where the step before began it
+        # (``_admit_ahead``), else None
+        self._pacing = None
         self._swapped: List[Request] = []   # preempted, host-RAM KV
         self._swap_out_fn = None            # lazy: engines that never
         self._swap_in_fn = None             # swap compile neither
@@ -2250,8 +2264,17 @@ class ServingEngine:
         it) or at an EOS: one freeze protocol.  It takes exactly the
         tokens up to its finish and retires there; the cells behind
         held a frozen row, so they are pad in the ledger, absent from
-        the KV-sweep model, and no token of the request."""
+        the KV-sweep model, and no token of the request.
+
+        A row whose occupant did not ride ``p`` (admitted since: into a
+        vacant slot, or ahead of this harvest into a slot that one of
+        ``p``'s riders leaves) keeps the carries the host wrote for it."""
         per, active = p.per_iter, p.active
+        riders = dict(zip(active, p.reqs))
+        for i, r in enumerate(self._slots):
+            if r is not None and riders.get(i) is not r:
+                tok[i], lens[i], done[i] = \
+                    self._tok[i], self._lens[i], self._done[i]
         self._tok = tok
         self._lens = lens
         eos = self.cfg.eos_token_id
@@ -2287,21 +2310,22 @@ class ServingEngine:
                               p.step_idx, steps=took, **attrs)
                 req.tokens.extend(row[:took].tolist())
                 req.remaining -= took
-                if hit_eos or req.remaining == 0:
+                if hit_eos or req.remaining == 0 or (
+                        req.sampling is not None and
+                        req.sampling.mask_processor is not None and
+                        self._mask_dead_end(req)):
                     # the finish bitmap observed host-side: EOS in
-                    # this iteration's segment, or the budget ran out
-                    self._slots[i] = None
-                    done[i] = True     # freeze the row until re-use
-                    self._release_blocks(req)
-                    self._finish(req, t, out, lag=lag)
-                elif req.sampling is not None and \
-                        req.sampling.mask_processor is not None and \
-                        self._mask_dead_end(req):
-                    # per == 1 for mask rows (clamped at dispatch), so
-                    # exactly one token was appended; finish THIS
-                    # request — co-resident rows are untouched
-                    self._slots[i] = None
-                    done[i] = True
+                    # this iteration's segment, the budget ran out, or
+                    # (per == 1 for mask rows, clamped at dispatch) the
+                    # grammar has no continuation — co-resident rows
+                    # are untouched
+                    if self._slots[i] is req:
+                        self._slots[i] = None
+                        done[i] = True     # freeze the row until re-use
+                    else:
+                        # the slot was handed over ahead of this harvest:
+                        # the row and its table are the new occupant's
+                        req.slot = None
                     self._release_blocks(req)
                     self._finish(req, t, out, lag=lag)
             for tenant, (u, pad) in gp.items():
@@ -2978,6 +3002,8 @@ class ServingEngine:
                 r.adapter_slot = None
         self._queue.clear()
         self._prefilling.clear()
+        self._first_owed = []
+        self._pacing = None
         self._swapped = []
         self._handoff_ready = []
         self._slots = [None] * self.num_slots
@@ -3066,6 +3092,9 @@ class ServingEngine:
                 phase = req.state
                 if req in self._prefilling:
                     self._prefilling.remove(req)
+                # a first token still owed is dropped with the request
+                self._first_owed = [o for o in self._first_owed
+                                    if o[0] is not req]
                 self._release_blocks(req)   # also trashes the table row
                 self._slots[i] = None
                 self._done[i] = True
@@ -3276,6 +3305,13 @@ class ServingEngine:
         # victim finished on device, and the stale pre-flush truth
         # must not be acted on.
         self._flush_async("preempt", out)
+        if any(r is req for r, _ in self._first_owed):
+            # its prompt is computed and its first token, owed since a
+            # chunk enqueued ahead of a harvest, still on the device:
+            # land it (with the others owed) so that it swaps out as the
+            # decode row it is
+            self._land_first_tokens(
+                out if out is not None else self._flush_finishes)
         slot = req.slot
         if req.state in TERMINAL_STATES:
             return False            # retired by the flush — done
@@ -3641,8 +3677,15 @@ class ServingEngine:
         self._update_deficits()
 
     # graftlint: plan-phase
-    def _admit(self, now: float, out: List[Request]):
-        """Admit the best-class candidates into vacant slots.  The
+    def _admit(self, now: float, out: List[Request],
+               leaving: Optional[dict] = None) -> int:
+        """Admit the best-class candidates into vacant slots; returns how
+        many were admitted.  ``leaving`` ({slot: rider}, ``_admit_ahead``)
+        counts the slots whose riders the block in flight ends by budget
+        as vacant too, in slot order with the others, as the next step
+        would find them; such an admission neither preempts nor resumes
+        (a victim's carries, and a resumed row's, are a block behind
+        until the harvest), and a head candidate that would stops it.  The
         candidate order is priority-then-EDF over the swap list plus
         the arrived queue (swapped requests sort ahead of queued ones
         within a class: they hold host memory and are closest to
@@ -3699,9 +3742,11 @@ class ServingEngine:
             # plain order would have inflicted)
             return self._sched_key(r) + (_state_rank(r),)
 
+        leaving = leaving or {}
+        admitted = 0
         while True:
             slot = next((i for i, r in enumerate(self._slots)
-                         if r is None), None)
+                         if r is None or r is leaving.get(i)), None)
             if slot is None:
                 break
             arrived = [r for r in self._queue if r.arrival_time <= now]
@@ -3709,6 +3754,8 @@ class ServingEngine:
             if not cands:
                 break
             req = cands[0]
+            if leaving and req.state == "swapped":
+                break
             # a "reorder" = the WRR term promoted a different request
             # over the plain priority/EDF/FIFO head (the starvation
             # the old order would have inflicted); only possible — and
@@ -3760,7 +3807,7 @@ class ServingEngine:
                 self._release_queue_pins()
                 n_hbm = 0
                 fresh = self._alloc(total)
-            if fresh is None and \
+            if fresh is None and not leaving and \
                     self._preempt_for(req, total - n_hbm, out):
                 fresh = self._alloc(total - n_hbm)
             if fresh is None:
@@ -3849,8 +3896,10 @@ class ServingEngine:
             self._fr.emit("admit", req.request_id, self._step_idx,
                           slot=slot, matched_blocks=len(mapped),
                           **extra)
+            admitted += 1
         self._m.slot_occupancy.set(
             sum(r is not None for r in self._slots))
+        return admitted
 
     def _build_samp(self, reqs, pos_lag: int = 0):
         """The ``samp`` plane pytree of one dispatch: ``reqs`` is the
@@ -4015,25 +4064,37 @@ class ServingEngine:
         prompts that finished among them land once, after the last
         (``_land_first_tokens``): nothing between two prompts' chunks
         needs the first one's token, so the chip runs chunk after chunk
-        while the host prepares the next."""
-        riders = [i for i, r in enumerate(self._slots)
-                  if r is not None and r.state == "decode"
-                  and r.spec_k is None]
-        n, _ = self._block_steps(riders, sum(p.n for p in self._pend_q))
+        while the host prepares the next.  Where the step before began
+        this pacing ahead of its harvest (``_admit_ahead``), it goes on
+        from there, with the first tokens owed from then landing here."""
+        pacing, self._pacing = self._pacing, None
+        if pacing is None:
+            pacing = self._chunk_pacing(sum(p.n for p in self._pend_q))
         try:
-            for _ in range(n):
-                for _ in range(max(1, -(-len(self._prefilling)
-                                        // self.steps_per_call))):
-                    self._prefill_chunk(out)
-                if not self._prefilling:
-                    break
+            for _ in pacing:
+                self._prefill_chunk(out)
         finally:
             # a chunk that raises leaves the prompts enqueued before it
             # landed, as it did when each landed behind its own chunk
             self._land_first_tokens(out)
 
+    def _chunk_pacing(self, lag: int, leaving=()):
+        """Yield once before each chunk of a step's pacing (see
+        ``_prefill_chunks``), reading the block's length from the plain
+        riders that are there less ``leaving``, ``lag`` steps on."""
+        riders = [i for i, r in enumerate(self._slots)
+                  if r is not None and r.state == "decode"
+                  and r.spec_k is None and i not in leaving]
+        n, _ = self._block_steps(riders, lag)
+        for _ in range(n):
+            for _ in range(max(1, -(-len(self._prefilling)
+                                    // self.steps_per_call))):
+                yield
+            if not self._prefilling:
+                return
+
     # graftlint: plan-phase
-    def _prefill_chunk(self, out: List[Request]):
+    def _prefill_chunk(self, out: List[Request], land: bool = True):
         """Enqueue at most ONE prompt chunk (FIFO over admissions), with
         everything of it the plan knows: counters, the goodput ledger, the
         flight recorder's event, the prefix cache (completed full blocks
@@ -4041,30 +4102,27 @@ class ServingEngine:
         chunk samples the request's first token: it stays on the device,
         owed to the request (``_first_owed``), and the request leaves the
         line so that the next call takes the next prompt.  The lockstep
-        arm reads every token at once, so there the token lands here."""
+        arm reads every token at once, so there the token lands here
+        (``land``; a chunk enqueued ahead of a harvest lands its token
+        with the next step's on both arms)."""
         if not self._prefilling:
             return
         req = self._prefilling[0]
         start, c = req.pf_pos, self.chunk_len
         is_final = start + c >= req.seq_len
-        if is_final:
-            # the final chunk samples the request's first token, which
-            # becomes host truth THIS step (EOS check, decode-mix
-            # entry, the slot's tok/lens carries) — the pipeline syncs.
-            # No block can become pending between two chunks, so of a
-            # step's final chunks only the first finds one to flush.
-            self._flush_async("chunk_final", out)
         with _span("serving.prefill", request=req.request_id,
                    slot=req.slot, start=start):
             flags, samp = self._build_samp([req])
             lora_on, lora_planes = self._build_lora([req])
             lora_args = (lora_planes,) if lora_on else ()
             with self._phase("serving.prefill.dispatch") as ph:
+                # the scalars go as host values, uploaded with the call's
+                # arguments: an eager conversion would queue on the device
+                # behind the work enqueued before it, and wait for it
                 outp = _call_quiet(
                     self._chunk_fn(flags, lora_on), self._pb,
                     jnp.asarray(req.chunk_ids[None, start:start + c]),
-                    jnp.asarray(start, jnp.int32),
-                    jnp.asarray(req.seq_len, jnp.int32),
+                    np.int32(start), np.int32(req.seq_len),
                     jnp.asarray(self._chunk_tables(req.slot)), samp,
                     *lora_args, *self._arenas, *self._slot_state)
                 self._adopt_arenas(outp[1:])
@@ -4105,7 +4163,7 @@ class ServingEngine:
             if is_final:
                 self._prefilling.popleft()
                 self._first_owed.append((req, outp[0]))
-        if not self.async_dispatch:
+        if land and not self.async_dispatch:
             self._land_first_tokens(out)
 
     # graftlint: plan-phase
@@ -4119,20 +4177,29 @@ class ServingEngine:
         order they did when each prompt landed behind its own chunk.
         Called once a step after its last chunk is enqueued (before the
         verify and the plan, which read the carries written here), and
-        after every final chunk on the lockstep arm."""
+        after every final chunk on the lockstep arm, which fetches each
+        token on its own.
+
+        A landed prompt joins the next block's riders, so a block still
+        pending harvests first (``chunk_final``): here, after the step's
+        chunks, so that they are enqueued behind it, not behind its
+        fetch."""
         if not self._first_owed:
             return
+        self._flush_async("chunk_final", out)
         owed, self._first_owed = self._first_owed, []
-        with _span("serving.prefill", landed=len(owed)):
-            with self._wait("serving.prefill.wait") as wait:
-                # sync: chunk_final
-                toks = jax.device_get([tok_d for _, tok_d in owed])
-            # the materialization is part of the chunks' dispatch, as the
-            # sync tail's is of the block's
-            self._disp_s += wait.seconds
-            self._m.first_token_fetches.inc()
-            for (req, _), tok in zip(owed, toks):
-                self._land_first_token(req, int(tok[0]), out)
+        for batch in ([owed] if self.async_dispatch
+                      else [[o] for o in owed]):
+            with _span("serving.prefill", landed=len(batch)):
+                with self._wait("serving.prefill.wait") as wait:
+                    # sync: chunk_final
+                    toks = jax.device_get([tok_d for _, tok_d in batch])
+                # the materialization is part of the chunks' dispatch, as
+                # the sync tail's is of the block's
+                self._disp_s += wait.seconds
+                self._m.first_token_fetches.inc()
+                for (req, _), tok in zip(batch, toks):
+                    self._land_first_token(req, int(tok[0]), out)
 
     def _land_first_token(self, req: Request, tok0: int,
                           out: List[Request]):
@@ -4511,7 +4578,10 @@ class ServingEngine:
         and land their first tokens (``_prefill_chunks``),
         then one speculative verify forward over the spec-mode slots
         and one decode block over the plain-decode mix — the phases
-        coexist in the same iteration.  Returns the requests that
+        coexist in the same iteration.  A block harvested in the same
+        step that ends riders by their budget admits into their slots
+        and enqueues the next step's first chunks before its outputs
+        are fetched (``_admit_ahead``).  Returns the requests that
         reached a terminal state this iteration (finished or
         timeout).
 
@@ -4770,9 +4840,12 @@ class ServingEngine:
                 budget = np.zeros((self.num_slots,), np.int32)
                 for i in active:
                     budget[i] = self._slots[i].remaining
-                tok_in = jnp.asarray(self._tok)
-                lens_in = jnp.asarray(self._lens)
-                done_in = jnp.asarray(self._done)
+                # copies: an admission ahead of the harvest writes the
+                # host carries while the block may still read them (a CPU
+                # ``asarray`` can alias the host buffer)
+                tok_in = jnp.asarray(self._tok.copy())
+                lens_in = jnp.asarray(self._lens.copy())
+                done_in = jnp.asarray(self._done.copy())
                 budget_in = jnp.asarray(budget)
             tables_in = jnp.asarray(self._decode_tables())
         with self._phase("serving.decode_block", steps=n_total,
@@ -4837,22 +4910,79 @@ class ServingEngine:
                 self._harvest_next(finished, reason)
             self._pend_q.pop()
             self._m.async_depth.set(0)
-            with _span("serving.harvest", reason=reason):
-                with self._wait("serving.harvest.wait") as wait:
-                    toks = np.asarray(new_pend.toks_d)      # [B, n]
-                    tok = np.array(new_pend.tok_d)  # writable copies
-                    lens = np.array(new_pend.lens_d)
-                    done = np.array(new_pend.done_d)
-                    self._count_experts(new_pend)
-                # ... and the new dispatch's sync materialization is
-                # part of the dispatch, exactly the lockstep engine's
-                # attribution
-                self._disp_s += wait.seconds
-                with _span("serving.harvest.absorb"):
-                    toks = self._checked_harvest(toks)
-                    self._absorb_block(new_pend, toks, tok, lens, done,
-                                       finished)
+            try:
+                self._admit_ahead(t_now, n_total, active, finished)
+            finally:
+                with _span("serving.harvest", reason=reason):
+                    with self._wait("serving.harvest.wait") as wait:
+                        toks = np.asarray(new_pend.toks_d)      # [B, n]
+                        tok = np.array(new_pend.tok_d)  # writable copies
+                        lens = np.array(new_pend.lens_d)
+                        done = np.array(new_pend.done_d)
+                        self._count_experts(new_pend)
+                    # ... and the new dispatch's sync materialization is
+                    # part of the dispatch, exactly the lockstep engine's
+                    # attribution
+                    self._disp_s += wait.seconds
+                    with _span("serving.harvest.absorb"):
+                        toks = self._checked_harvest(toks)
+                        self._absorb_block(new_pend, toks, tok, lens,
+                                           done, finished)
         return finished
+
+    def _admit_ahead(self, now: float, n: int, active: List[int],
+                     out: List[Request]):
+        """Before the sync harvest of the ``n``-step block just enqueued:
+        where the riders it ends by their budget are all the finishes it
+        can hold (``_leaving``) and a request waits, admit into their
+        slots now and enqueue the next step's first chunks, one an
+        admission, so that the chip runs them while the host fetches and
+        absorbs the block.  A new request rides the block it would have
+        ridden had it been admitted after the harvest: only the slot
+        index is handed over (the retiring rider's blocks release at the
+        harvest, which leaves the row alone), and the chunk program takes
+        the arenas and state rows the block returns, so the device runs
+        it after the block.  Both arms take this order; the lockstep
+        arm's block is not in flight, so its admissions do not count in
+        ``serving.admits_ahead``."""
+        leaving = self._leaving(n, active)
+        if not leaving or not (self._swapped or any(
+                r.arrival_time <= now for r in self._queue)):
+            return
+        with _span("serving.admit", queued=len(self._queue),
+                   leaving=len(leaving)):
+            admitted = self._admit(now, out, leaving)
+        if not admitted:
+            return
+        if self.async_dispatch:
+            self._m.admits_ahead.inc(sum(
+                self._slots[i] is not r for i, r in leaving.items()))
+        self._pacing = self._chunk_pacing(n, leaving)
+        for _ in zip(range(admitted), self._pacing):
+            self._prefill_chunk(out, land=False)
+
+    def _leaving(self, n: int, active: List[int]) -> dict:
+        """{slot: rider} of the riders an ``n``-step block ends by their
+        budget, where nothing else can end one inside it: no EOS token,
+        no rider whose token a mask or a penalty plane reads and no
+        speculative row (their finishes, and their host truth, wait for
+        the harvest); else empty."""
+        if self.cfg.eos_token_id is not None or any(
+                r is not None and r.spec_k is not None
+                and r.state == "decode" for r in self._slots):
+            return {}
+        leaving = {}
+        for i in active:
+            r = self._slots[i]
+            if r is None or r.state != "decode":
+                continue              # retired by a same-step harvest
+            sp = r.sampling
+            if sp is not None and (sp.mask_processor is not None
+                                   or sp.needs_penalty):
+                return {}
+            if r.remaining <= n:
+                leaving[i] = r
+        return leaving
 
     def _stall_diagnosis(self, wall_timeout_s: float) -> str:
         """The state dump an ``EngineStalledError`` carries: enough to
@@ -5023,6 +5153,7 @@ class ServingEngine:
             "prefills": int(self._m.since_init(self._m.prefills)),
             "first_token_fetches": int(
                 self._m.since_init(self._m.first_token_fetches)),
+            "admits_ahead": int(self._m.since_init(self._m.admits_ahead)),
             "prefill_chunks": int(
                 self._m.since_init(self._m.prefill_chunks)),
             "mean_slot_occupancy": occ,

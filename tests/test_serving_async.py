@@ -27,12 +27,13 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import models
+from paddle_tpu import runtime as rt
 from paddle_tpu.inference import FaultInjector
 from paddle_tpu.inference.sampling import DfaTokenMask, SamplingParams
 from paddle_tpu.inference.serving import (ASYNC_SYNC_REASONS,
                                           EngineStalledError,
                                           ServingEngine)
-from paddle_tpu.observability import MetricsRegistry
+from paddle_tpu.observability import MetricsRegistry, spans
 from paddle_tpu.observability.flightrec import FlightRecorder
 
 P, C, BL = 8, 40, 4
@@ -537,3 +538,189 @@ def test_a_chunk_that_raises_leaves_the_prompts_before_it_landed(
                          compute_dtype="float32", registry=reg)
     assert twin.stats()["first_token_fetches"] == 0
     assert reg.get("serving.first_token_fetches").value() == fetched
+
+
+# -- a budget finish admits into its slot before the block is fetched ---------
+
+AHEAD_GEO = {
+    "dense": dict(num_slots=3, prompt_len=P, max_cache_len=C, steps_per_call=8,
+                  block_len=BL, chunk_len=4, compute_dtype="float32"),
+    # the geometry of tests/test_lfm2_moe.py, whose programs are cached
+    "slot_state": dict(num_slots=3, prompt_len=48, max_cache_len=80,
+                       block_len=8, num_blocks=40, chunk_len=16,
+                       steps_per_call=4, compute_dtype="float32",
+                       host_cache_blocks=0),
+}
+AHEAD_MIX = {
+    "dense": [(7, 12), (3, 30), (8, 17), (5, 9), (6, 21), (2, 14), (8, 26),
+              (4, 11), (7, 19)],
+    "slot_state": [(9, 6), (16, 9), (4, 5), (12, 3), (7, 8), (15, 4), (5, 6)],
+}
+AHEAD_KEYS = ("decode_steps", "busy_slot_steps", "block_dispatches",
+              "prefills", "prefill_chunks", "kv_bytes_swept", "useful_tokens",
+              "wasted_tokens", "dispatched_tokens", "finished")
+
+
+def _ahead_arm(net, vocab, variant, mode, eos=None, mix=None):
+    """Requests over three slots whose budgets end inside blocks.  ``mode``:
+    ``ahead`` the dispatch-ahead engine, ``lockstep`` the kill-switch arm,
+    ``after`` the dispatch-ahead engine admitting only after each harvest
+    (``_leaving`` answers nothing: the order before admissions went ahead).
+    Returns the requests, each block's riders, the counters, the
+    ``_admit`` calls made ahead of a harvest, and, for each slot a harvest
+    found handed over to a new request, what the slot held before and after
+    the harvest."""
+    reg, rec = MetricsRegistry(), FlightRecorder()
+    eng = ServingEngine(net, registry=reg, flight_recorder=rec,
+                        eos_token_id=eos, async_dispatch=mode != "lockstep",
+                        **AHEAD_GEO[variant])
+    if mode == "after":
+        eng._leaving = lambda n, active: {}
+    admit, calls = eng._admit, []
+
+    def spy(now, out, leaving=None):
+        calls.append(bool(leaving))
+        return admit(now, out, leaving)
+    eng._admit = spy
+    absorb, handed = eng._absorb_block, []
+
+    def held(i):
+        r = eng._slots[i]
+        return (r, r.state, list(r.blocks), eng._tables[i].tolist(),
+                int(eng._tok[i]), int(eng._lens[i]), bool(eng._done[i]))
+
+    def absorb_spy(p, *args):
+        new = [(i, r) for i, r in zip(p.active, p.reqs)
+               if eng._slots[i] is not r]
+        before = [held(i) for i, _ in new]
+        absorb(p, *args)
+        handed.extend(dict(old=r, before=b, after=held(i))
+                      for (i, r), b in zip(new, before))
+    eng._absorb_block = absorb_spy
+    rng = np.random.default_rng(44)
+    reqs = [eng.submit(rng.integers(0, vocab, (n,)).astype(np.int32),
+                       max_new_tokens=m, arrival_time=0.0)
+            for n, m in (mix or AHEAD_MIX[variant])]
+    while any(r.state != "finished" for r in reqs):
+        eng.step(now=0.0)
+        eng._pool.check()
+        assert eng._step_idx < 400, "did not drain"
+    assert not eng._first_owed and eng._pending is None
+    riders = {}
+    for e in rec.events():
+        if e.kind == "decode_block":
+            riders.setdefault(e.step, set()).add(e.request)
+    return SimpleNamespace(eng=eng, reg=reg, reqs=reqs, stats=eng.stats(),
+                           riders=riders, ahead_calls=sum(calls),
+                           handed=handed)
+
+
+@pytest.fixture(scope="module")
+def ahead_arms(netm, lfm2m):
+    cfg, net = netm
+    nets = {"dense": (net, cfg.vocab_size), "slot_state": (lfm2m, 256)}
+    return {(v, mode): _ahead_arm(*nets[v], v, mode)
+            for v in AHEAD_GEO for mode in ("ahead", "lockstep", "after")}
+
+
+@pytest.mark.parametrize("variant", ["dense", "slot_state"])
+def test_admission_ahead_keeps_every_token_and_every_blocks_riders(
+        ahead_arms, netm, variant):
+    """Admitting into the slots a block leaves by budget before its fetch
+    changes no token and no block's riding set: the dispatch-ahead engine,
+    the lockstep arm and the dispatch-ahead engine admitting after each
+    harvest agree request for request and block for block.  The slot-state
+    family reuses a state row whose old rider is still in flight."""
+    a, s, f = (ahead_arms[variant, m] for m in ("ahead", "lockstep", "after"))
+    for ra, rs, rf in zip(a.reqs, s.reqs, f.reqs):
+        np.testing.assert_array_equal(ra.output, rs.output)
+        np.testing.assert_array_equal(ra.output, rf.output)
+    if variant == "dense":
+        cfg, net = netm
+        r = a.reqs[3]
+        np.testing.assert_array_equal(
+            r.output, _gen_ref(net, r.prompt[:r.seq_len], r.max_new_tokens))
+    assert a.riders == s.riders == f.riders
+    for k in AHEAD_KEYS:
+        assert a.stats[k] == s.stats[k] == f.stats[k], k
+    assert a.stats["admits_ahead"] >= 2 and a.ahead_calls >= 2
+    assert f.ahead_calls == f.stats["admits_ahead"] == 0
+    if variant == "slot_state":
+        assert a.stats["slot_state_bytes"] > 0
+
+
+@pytest.mark.parametrize("variant", ["dense", "slot_state"])
+def test_the_retiring_riders_release_leaves_the_new_riders_slot_alone(
+        ahead_arms, variant):
+    """A rider retired by the harvest after its slot was handed over leaves
+    the new rider's slot, table row and carries as its admission wrote them:
+    the row held the new rider's blocks, done, at length 0, with the token
+    carry untouched; the retired request holds no slot and no block."""
+    for mode in ("ahead", "lockstep"):
+        arm = ahead_arms[variant, mode]
+        assert len(arm.handed) == arm.stats["admits_ahead"] or \
+            mode == "lockstep"
+        assert len(arm.handed) >= 2, mode
+        for h in arm.handed:
+            assert h["after"] == h["before"]
+            _new, state, blocks, row, _tok, lens, done = h["after"]
+            assert state == "prefill" and (lens, done) == (0, True)
+            assert blocks and row[:len(blocks)] == blocks
+            assert set(row[len(blocks):]) <= {arm.eng._pool.trash}
+            old = h["old"]
+            assert old.state == "finished"
+            assert old.slot is None and old.blocks == []
+    # the order before: a slot a block leaves is admitted into next step
+    assert not ahead_arms[variant, "after"].handed
+
+
+def test_a_budget_finish_enqueues_the_new_prompt_before_the_fetch(netm):
+    """In a step whose block ends a rider by its budget with a request
+    queued, the queued request is admitted and its first chunk enqueued
+    between the block's dispatch and the fetch of its outputs, and
+    ``serving.admits_ahead`` counts each such admission."""
+    cfg, net = netm
+    spans.start_recording()
+    try:
+        arm = _ahead_arm(net, cfg.vocab_size, "dense", "ahead")
+        got = spans.recorded()
+    finally:
+        rt.HostTracer.disable()
+        rt.HostTracer.clear()
+    ahead = [(t0, t1) for n, t0, t1, _, a in got
+             if n == "serving.admit" and "leaving" in a]
+    assert len(ahead) == arm.ahead_calls >= 2
+    for a0, a1 in ahead:
+        blk = max(t1 for n, _, t1, _, _ in got
+                  if n == "serving.decode_block" and t1 <= a0)
+        fetch = min(t0 for n, t0, _, _, _ in got
+                    if n == "serving.harvest.wait" and t0 >= a1)
+        assert [1 for n, t0, t1, _, _ in got
+                if n == "serving.prefill.dispatch" and a1 <= t0
+                and t1 <= fetch]
+        assert not [1 for n, t0, _, _, _ in got
+                    if n == "serving.harvest.wait" and blk <= t0 < a1]
+    assert arm.stats["admits_ahead"] == len(arm.handed) == \
+        arm.reg.get("serving.admits_ahead").value()
+
+
+@pytest.mark.parametrize("case", ["eos", "nothing_queued", "lockstep"])
+def test_no_admission_ahead_where_the_block_cannot_know_its_finishes(
+        netm, case):
+    """Blocks that sync for EOS admit after their harvest, an engine with
+    nothing queued has nothing to admit, and the lockstep arm, whose block is
+    not in flight, counts nothing in ``serving.admits_ahead``."""
+    cfg, net = netm
+    mix = AHEAD_MIX["dense"][:3] if case == "nothing_queued" else None
+    arm = _ahead_arm(net, cfg.vocab_size, "dense",
+                     "lockstep" if case == "lockstep" else "ahead",
+                     eos=cfg.vocab_size - 1 if case == "eos" else None,
+                     mix=mix)
+    assert arm.stats["admits_ahead"] == 0
+    assert arm.reg.get("serving.admits_ahead").value() == 0
+    if case == "lockstep":
+        assert arm.ahead_calls >= 2       # the same order, uncounted
+    else:
+        assert arm.ahead_calls == 0
+    if case == "eos":
+        assert arm.stats["async_syncs_by_reason"]["eos"] > 0

@@ -429,9 +429,10 @@ def test_busy_slot_steps_count_live_cells_and_the_ledger_pads_the_frozen(
 
 def test_a_slot_vacated_inside_a_block_rides_the_next(netm):
     """Two riders end inside one block at different offsets while a long
-    rider keeps the block whole and two requests wait: the next step admits
-    both, runs both prompts' chunks (a chunk for each decode step of the
-    block it paces, until no slot waits) and both ride its block."""
+    rider keeps the block whole and two requests wait: the step of that
+    block admits both into the slots it leaves and enqueues both prompts'
+    chunks (a chunk for each decode step of the block they pace, until no
+    slot waits) before its harvest, and both ride the next block."""
     cfg, net = netm
     rng = np.random.default_rng(7)
     rec = FlightRecorder()
@@ -445,12 +446,16 @@ def test_a_slot_vacated_inside_a_block_rides_the_next(netm):
     long_, a, b, c, d = reqs
     while a.state != "finished" or b.state != "finished":
         eng.step()
-    assert c.state == d.state == "queued" and long_.state == "decode"
+    assert c.state == d.state == "prefill" and long_.state == "decode"
+    assert eng.stats()["admits_ahead"] == 2
     ended = {e.request: e.step for e in rec.events() if e.kind == "finish"}
     assert ended[a.request_id] == ended[b.request_id]    # one block, both
+    chunked = [e.step for e in rec.events() if e.kind == "prefill_chunk"
+               and e.request in (c.request_id, d.request_id)]
+    assert chunked == 2 * [ended[a.request_id]]
     chunks = eng.stats()["prefill_chunks"]
     eng.step()
-    assert eng.stats()["prefill_chunks"] == chunks + 2
+    assert eng.stats()["prefill_chunks"] == chunks
     # c's budget ends with the block, so its harvest is not deferred
     assert (c.state, d.state) == ("finished", "decode")
     assert len(c.tokens) == len(d.tokens) == 1 + 8

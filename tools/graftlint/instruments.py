@@ -135,6 +135,10 @@ REQUIRED_INSTRUMENTS = {
     # a step's time off the CPU outside its device waits: where the
     # host stops (inference/serving.py _ServingInstruments)
     "serving.step.offcpu_seconds": ("histogram", ()),
+    # admissions into a slot the block in flight leaves by budget, made
+    # before the block's outputs are fetched (inference/serving.py
+    # _ServingInstruments); the benchmark reads it over serving.prefills
+    "serving.admits_ahead": ("counter", ()),
     # multi-tenant batched LoRA serving (PR 11, inference/lora.py
     # AdapterStore + inference/serving.py _ServingInstruments):
     # adapter residency across the HBM arena / host-RAM tiers, swap-in
