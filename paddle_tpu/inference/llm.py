@@ -462,13 +462,14 @@ def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
         flat_kv, state = _split_slot_state(model, flat_arenas)
         kvs = _pack_paged_kvs(_constrain_arenas(flat_kv, shard),
                               tables, kv_int8, _kv_latent(model))
-        if state:
+        if state or counts is not None:
             # the second kind of state rides the scan carry as one more
             # entry of ``kvs``; the scan body never looks inside.
             # ``stale``: the rows that were done when the block began,
             # whose state rows hold nothing to read (vacant, prefilling,
             # or poisoned below by the block they finished in).  A model
-            # that counts (its experts' load) adds its counters to it
+            # that counts (its experts' load) adds its counters to it,
+            # beside its state or, where it keeps none, alone
             kvs.append({"state": state, "stale": done})
             if counts is not None:
                 kvs[-1]["counters"] = counts()
@@ -481,7 +482,7 @@ def _build_paged_decode_block(model, cfg: GenerationConfig, steps_per_call,
                     (tok, lens, kvs, pos0, pres0, done, budget),
                     None, length=steps_per_call)
         tail = ()
-        if state:
+        if state or counts is not None:
             slot_state = kvs_f.pop()
             tail = tuple(_poison_state(model, slot_state["state"],
                                        done_f & ~done))

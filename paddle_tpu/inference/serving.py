@@ -472,6 +472,14 @@ class _ServingInstruments:
         self.moe_experts_touched = r.counter(
             "moe.experts_touched", "experts that got at least one live "
             "row, summed over the (expert layer, decode step) pairs")
+        self.moe_zero_expert_picks = r.counter(
+            "moe.zero_expert_picks", "live decode rows' top-k picks that "
+            "fell on zero-compute (identity) experts, summed over decode "
+            "steps and expert layers (a router that has such experts)")
+        self.moe_expert_picks = r.counter(
+            "moe.expert_picks", "live decode rows' top-k picks, real and "
+            "zero-compute experts alike, summed as moe.zero_expert_picks "
+            "is")
         self.tokens_emitted = r.counter(
             "serving.tokens_emitted", "tokens emitted to requests "
             "(prefill first-tokens + the cells each rider of a decode "
@@ -4388,14 +4396,20 @@ class ServingEngine:
 
     def _count_experts(self, p: _PendingBlock):
         """Fetch a harvested decode block's expert-load array, where the
-        model counts one (rows routed to each expert, then the (layer,
-        step) pairs, then the experts that got a row summed over the
-        pairs), and feed it to the registry."""
+        model counts one (rows routed to each expert; where the model's
+        router has zero-compute experts, ``counts_picks``, the picks that
+        fell on them and all picks; then the (layer, step) pairs, then the
+        experts that got a row summed over the pairs), and feed it to the
+        registry."""
         if p.counters_d is None:
             return
         counters = np.asarray(p.counters_d)
-        for e in np.flatnonzero(counters[:-2]):
+        tail = 4 if getattr(self._model, "counts_picks", False) else 2
+        for e in np.flatnonzero(counters[:-tail]):
             self._m.moe_expert_tokens.inc(int(counters[e]), expert=str(e))
+        if tail == 4:
+            self._m.moe_zero_expert_picks.inc(int(counters[-4]))
+            self._m.moe_expert_picks.inc(int(counters[-3]))
         self._m.moe_layer_steps.inc(int(counters[-2]))
         self._m.moe_experts_touched.inc(int(counters[-1]))
 

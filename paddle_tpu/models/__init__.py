@@ -21,6 +21,8 @@ from .kimi_linear import (KimiLinearConfig, KimiLinearForCausalLM,
                           KimiLinearModel, tiny_kimi_linear_config)
 from .falcon_h1 import (FalconH1Config, FalconH1ForCausalLM, FalconH1Model,
                         tiny_falcon_h1_config)
+from .longcat_flash import (LongCatFlashConfig, LongCatFlashForCausalLM,
+                            LongCatFlashModel, tiny_longcat_flash_config)
 from .ocr import (DBNet, DBNetConfig, DBLoss, DBFPN, DBHead, db_postprocess,
                   CRNN, CRNNConfig, CTCHeadLoss, ctc_greedy_decode,
                   PPOCRSystem)
@@ -44,6 +46,8 @@ __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM",
            "tiny_kimi_linear_config",
            "FalconH1Config", "FalconH1Model", "FalconH1ForCausalLM",
            "tiny_falcon_h1_config",
+           "LongCatFlashConfig", "LongCatFlashModel", "LongCatFlashForCausalLM",
+           "tiny_longcat_flash_config",
            "DBNet", "DBNetConfig", "DBLoss", "DBFPN", "DBHead",
            "db_postprocess", "CRNN", "CRNNConfig", "CTCHeadLoss",
            "ctc_greedy_decode", "PPOCRSystem"]

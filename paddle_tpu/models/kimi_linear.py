@@ -1,6 +1,7 @@
 """Kimi Linear sparse hybrid decoder (``model_type`` ``kimi_linear``): gated
 delta-rule linear attention (KDA) whose matrix state is per-slot state,
-latent attention (MLA, no rotary) whose paged cache is one row a token, and
+latent attention (MLA, ``models/mla.py``: no rotary part and a full-rank
+query, as published) whose paged cache is one row a token, and
 routed experts of which this chip may hold a share, beside a shared one.
 
 Layer ``i`` (1-based, as the published lists count) is ``h = h +
@@ -48,8 +49,8 @@ from ..incubate.nn.functional import swiglu
 from ..nn.initializer import Constant, Normal
 from ..ops.pallas.kda import kda_chunk, kda_decode_step
 from .generation import (LatentCacheSpec, SlotStateError, StateRows,
-                         generate_by_forward, paged_verify_scatter,
-                         poison_first_rows)
+                         generate_by_forward, poison_first_rows)
+from .mla import LatentAttention
 
 
 @dataclass
@@ -103,10 +104,8 @@ class KimiLinearConfig:
 
     def __post_init__(self):
         refused = {
-            "q_lora_rank": self.q_lora_rank is not None,
             "rope_scaling": self.rope_scaling is not None,
             "num_nextn_predict_layers": self.num_nextn_predict_layers > 0,
-            "mla_use_nope": not self.mla_use_nope,
             "moe_router_activation_func":
                 self.moe_router_activation_func != "sigmoid",
             "hidden_act": self.hidden_act != "silu",
@@ -247,104 +246,20 @@ class KimiDeltaAttention(nn.Layer):
             return self.o_proj(Tensor(y)), (new_tail, state)
 
 
-class KimiMLAttention(nn.Layer):
-    """MLA without rotary: ``q = W_q x`` as heads of ``[q_nope, q_pe]``;
-    ``[c~, k_pe] = W_kva x``, ``c = rms(c~)``; ``[k_nope, v]_h = W_kvb,h c``;
-    ``k_h = [k_nope_h, k_pe]`` with ``k_pe`` shared by the heads; causal
-    softmax of ``q_h . k_h / sqrt(nope + rope)``.  What a token caches is
-    ``[c, k_pe]``."""
+# the MLA layer by the name the Kimi fault tool patches
+KimiMLAttention = LatentAttention
 
-    def __init__(self, config: KimiLinearConfig):
-        super().__init__()
-        h = config.hidden_size
-        self.num_heads = config.num_attention_heads
-        self.nope, self.rope = config.qk_nope_head_dim, \
-            config.qk_rope_head_dim
-        self.v_dim, self.rank = config.v_head_dim, config.kv_lora_rank
-        self.scale = (self.nope + self.rope) ** -0.5
-        self.q_proj = _linear(h, self.num_heads * (self.nope + self.rope))
-        self.kv_a_proj_with_mqa = _linear(h, self.rank + self.rope)
-        self.kv_a_layernorm = nn.RMSNorm(self.rank, config.rms_norm_eps)
-        self.kv_b_proj = _linear(self.rank,
-                                 self.num_heads * (self.nope + self.v_dim))
-        self.o_proj = _linear(self.num_heads * self.v_dim, h)
 
-    def _kv_b(self):
-        """W_kvb as ([rank, heads, nope], [rank, heads, v])."""
-        w = self.kv_b_proj.weight._value.reshape(
-            self.rank, self.num_heads, self.nope + self.v_dim)
-        return w[..., :self.nope], w[..., self.nope:]
-
-    def _expanded(self, q_nope, q_pe, rows, q_pos):
-        """Plain attention of the queries at positions ``q_pos`` [B, C] over
-        the latent rows ``rows`` [B, S, >= rank + rope], row ``s`` at
-        position ``s``: keys and values expanded through W_kvb."""
-        with jax.named_scope("mla_expand"):
-            w_k, w_v = self._kv_b()
-            c, k_pe = rows[..., :self.rank], \
-                rows[..., self.rank:self.rank + self.rope]
-            k_nope = jnp.einsum("bsr,rhn->bshn", c, w_k)
-            v = jnp.einsum("bsr,rhv->bshv", c, w_v)
-            logits = (jnp.einsum("bchn,bshn->bhcs", q_nope, k_nope,
-                                 preferred_element_type=jnp.float32)
-                      + jnp.einsum("bchr,bsr->bhcs", q_pe, k_pe,
-                                   preferred_element_type=jnp.float32)) \
-                * self.scale
-            keep = jnp.arange(rows.shape[1])[None, None, :] \
-                <= q_pos[:, :, None]
-            logits = jnp.where(keep[:, None], logits, -jnp.inf)
-            probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-            return jnp.einsum("bhcs,bshv->bchv", probs, v)
-
-    def _absorbed(self, q_nope, q_pe, arena, tables, lens):
-        """One token a row over the latent arena, the up-projections folded
-        into the query (``q_lat = W_kvb^K^T q_nope``) and the output (``o =
-        W_kvb^V o_lat``): every head reads the one shared row a token."""
-        from ..ops.pallas.decode_attention import decode_attention_latent
-        with jax.named_scope("mla_absorb"):
-            w_k, w_v = self._kv_b()
-            q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, w_k)
-            q = jnp.concatenate([q_lat, q_pe], axis=-1)
-            q = jnp.pad(q, ((0, 0), (0, 0),
-                            (0, arena.shape[-1] - q.shape[-1])))
-            o_lat = decode_attention_latent(
-                q.astype(arena.dtype), arena, tables, lens, self.rank,
-                self.scale)
-            return jnp.einsum("bhr,rhv->bhv", o_lat.astype(w_v.dtype), w_v)
-
-    def step(self, u, pos0, n_valid, kv):
-        """u: Tensor [B, C, H] at positions ``pos0[b] + c``.  ``kv`` None:
-        causal attention inside the rows.  ``kv = (arena, tables)``: the
-        valid rows' latent rows are written through the tables and the
-        queries attend over everything written."""
-        from ..ops.pallas.decode_attention import paged_gather_view
-        with jax.named_scope("mla_attention"):
-            b, c, _ = u.shape
-            q = self.q_proj(u)._value.reshape(
-                b, c, self.num_heads, self.nope + self.rope)
-            q_nope, q_pe = q[..., :self.nope], q[..., self.nope:]
-            ckv = self.kv_a_proj_with_mqa(u)
-            latent = self.kv_a_layernorm(Tensor(ckv._value[..., :self.rank]))
-            rows = jnp.concatenate(
-                [latent._value, ckv._value[..., self.rank:]], axis=-1)
-            pos = pos0[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-            if kv is None:
-                out = self._expanded(q_nope, q_pe, rows, pos - pos0[:, None])
-            else:
-                arena, tables = kv
-                rows = jnp.pad(rows, ((0, 0), (0, 0),
-                                      (0, arena.shape[-1] - rows.shape[-1])))
-                arena = paged_verify_scatter(arena, tables, pos0, n_valid,
-                                             rows[:, :, None, :])
-                if c == 1:
-                    out = self._absorbed(q_nope[:, 0], q_pe[:, 0], arena,
-                                         tables, pos0)[:, None]
-                else:
-                    out = self._expanded(
-                        q_nope, q_pe, paged_gather_view(arena, tables), pos)
-                kv = (arena, tables)
-            out = out.reshape(b, c, -1).astype(u._value.dtype)
-            return self.o_proj(Tensor(out)), kv
+def _mla(config: KimiLinearConfig):
+    """The MLA layer of a Kimi Linear config: NoPE (``mla_use_nope``, as
+    published) or rotary at ``rope_theta``, a full-rank query or a low-rank
+    one (``q_lora_rank``), no LoRA scales."""
+    return LatentAttention(
+        config.hidden_size, config.num_attention_heads,
+        config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim,
+        config.kv_lora_rank, config.rms_norm_eps,
+        q_lora_rank=config.q_lora_rank,
+        rope_theta=None if config.mla_use_nope else config.rope_theta)
 
 
 class KimiMLP(nn.Layer):
@@ -395,7 +310,7 @@ class KimiDecoderLayer(nn.Layer):
         self.input_layernorm = nn.RMSNorm(config.hidden_size,
                                           config.rms_norm_eps)
         self.self_attn = KimiDeltaAttention(config) if self.kind == "kda" \
-            else KimiMLAttention(config)
+            else _mla(config)
         self.post_attention_layernorm = nn.RMSNorm(config.hidden_size,
                                                    config.rms_norm_eps)
         self.sparse = layer_idx >= config.first_k_dense_replace

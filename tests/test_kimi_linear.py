@@ -169,11 +169,15 @@ def test_forward_matches_the_reference(model, weights):
 
 
 def test_the_configuration_refuses_what_it_has_no_answer_for():
-    for key, value in [("q_lora_rank", 64), ("rope_scaling", {"factor": 2}),
-                       ("num_nextn_predict_layers", 1),
-                       ("mla_use_nope", False)]:
+    for key, value in [("rope_scaling", {"factor": 2}),
+                       ("num_nextn_predict_layers", 1)]:
         with pytest.raises(ValueError, match=key):
             models.tiny_kimi_linear_config(**{key: value})
+    # the shared latent attention layer has a low-rank query and a rotary
+    # part: a config that asks for them is built, not refused
+    mla = models.KimiLinearForCausalLM(models.tiny_kimi_linear_config(
+        q_lora_rank=24, mla_use_nope=False)).model.layers[2].self_attn
+    assert mla.q_lora_rank == 24 and mla.inv_freq is not None
     with pytest.raises(ValueError, match="once each"):
         models.tiny_kimi_linear_config(linear_attn_config={
             "kda_layers": [1, 2], "full_attn_layers": [2, 3], "head_dim": 16,
