@@ -15,10 +15,13 @@ What a token caches is ``[c, k_pe]``, ``kv_lora_rank + qk_rope_head_dim``
 values: the scaled latent and the rotated positional key.  Decode runs
 absorbed (the up-projections folded into the query and the output, every head
 over the one shared row: ``ops/pallas/decode_attention.
-decode_attention_latent``); a chunk runs expanded over the slot's gathered
-rows.  The query's scale is applied before ``q`` is split, so the absorbed
-query ``q_lat = W_kvb^K^T q_nope`` carries it, and the latent's scale is in
-the cached row: the kernel sees neither.
+decode_attention_latent``), and so does a prompt chunk where the kernel is
+taken, its query tiles each reading the slot's rows only up to their causal
+frontier (``latent_chunk_attention``, route counter
+``pallas.latent_chunk.route``); elsewhere a chunk runs expanded over the
+slot's gathered rows.  The query's scale is applied before ``q`` is split,
+so the absorbed query ``q_lat = W_kvb^K^T q_nope`` carries it, and the
+latent's scale is in the cached row: the kernel sees neither.
 """
 
 from __future__ import annotations
@@ -123,28 +126,48 @@ class LatentAttention(nn.Layer):
             probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
             return jnp.einsum("bhcs,bshv->bchv", probs, v)
 
+    def _absorb_query(self, q_nope, q_pe, arena):
+        """``[q_lat, q_pe]`` with ``q_lat = W_kvb^K^T q_nope``, zero-padded
+        to the arena's row and in its dtype, and ``W_kvb^V`` for the
+        output: the up-projections folded into the query and the output."""
+        w_k, w_v = self._kv_b()
+        q_lat = jnp.einsum("...hn,rhn->...hr", q_nope, w_k)
+        q = jnp.concatenate([q_lat, q_pe], axis=-1)
+        pad = [(0, 0)] * (q.ndim - 1) + [(0, arena.shape[-1] - q.shape[-1])]
+        return jnp.pad(q, pad).astype(arena.dtype), w_v
+
     def _absorbed(self, q_nope, q_pe, arena, tables, lens):
-        """One token a row over the latent arena, the up-projections folded
-        into the query (``q_lat = W_kvb^K^T q_nope``) and the output (``o =
-        W_kvb^V o_lat``): every head reads the one shared row a token."""
+        """One token a row over the latent arena, absorbed
+        (``_absorb_query``): every head reads the one shared row a
+        token."""
         from ..ops.pallas.decode_attention import decode_attention_latent
         with jax.named_scope("mla_absorb"):
-            w_k, w_v = self._kv_b()
-            q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, w_k)
-            q = jnp.concatenate([q_lat, q_pe], axis=-1)
-            q = jnp.pad(q, ((0, 0), (0, 0),
-                            (0, arena.shape[-1] - q.shape[-1])))
-            o_lat = decode_attention_latent(
-                q.astype(arena.dtype), arena, tables, lens, self.rank,
-                self.scale)
+            q, w_v = self._absorb_query(q_nope, q_pe, arena)
+            o_lat = decode_attention_latent(q, arena, tables, lens,
+                                            self.rank, self.scale)
             return jnp.einsum("bhr,rhv->bhv", o_lat.astype(w_v.dtype), w_v)
+
+    def _absorbed_chunk(self, q_nope, q_pe, arena, tables, pos0, n_valid,
+                        tq):
+        """A chunk's queries over the latent arena, absorbed: each query
+        tile of ``tq`` positions reads the slot's rows through its table
+        only up to its causal frontier
+        (``ops/pallas/decode_attention.latent_chunk_attention``)."""
+        from ..ops.pallas.decode_attention import latent_chunk_attention
+        with jax.named_scope("mla_chunk"):
+            q, w_v = self._absorb_query(q_nope, q_pe, arena)
+            o_lat = latent_chunk_attention(q, arena, tables, pos0, n_valid,
+                                           self.rank, self.scale, tq)
+            return jnp.einsum("bchr,rhv->bchv", o_lat.astype(w_v.dtype),
+                              w_v)
 
     def step(self, u, pos0, n_valid, kv):
         """u: Tensor [B, C, H] at positions ``pos0[b] + c``.  ``kv`` None:
         causal attention inside the rows.  ``kv = (arena, tables)``: the
         valid rows' latent rows are written through the tables and the
         queries attend over everything written."""
-        from ..ops.pallas.decode_attention import paged_gather_view
+        from ..ops.pallas.decode_attention import (latent_chunk_tile,
+                                                   paged_gather_view)
         with jax.named_scope("mla_attention"):
             b, c, _ = u.shape
             q = self._query(u).reshape(
@@ -175,6 +198,10 @@ class LatentAttention(nn.Layer):
                 if c == 1:
                     out = self._absorbed(q_nope[:, 0], q_pe[:, 0], arena,
                                          tables, pos0)[:, None]
+                elif tq := latent_chunk_tile(c, self.num_heads, arena,
+                                             tables, self.rank):
+                    out = self._absorbed_chunk(q_nope, q_pe, arena, tables,
+                                               pos0, n_valid, tq)
                 else:
                     out = self._expanded(
                         q_nope, q_pe, paged_gather_view(arena, tables), pos)

@@ -40,13 +40,14 @@ in VMEM and then computes on all of it: the dense ``_kernel``
 (``LLMPredictor``'s contiguous cache); its landing buffers grow with
 the cache (``_VMEM_BUDGET``, reason ``vmem_budget``).  The STREAMING
 one (``_paged_stream_kernel``: the float paged cache, decode and the
-K-wide verify alike) walks the prefix in double-buffered groups of
-blocks with an online softmax and prefetches across slots; what it
-stages does not grow with the table, and the budget does not bind it
-(PR 29: 80% of the HBM roofline at the serving cell's geometry where
-a staged body read 44%, kernel alone on the v5e).  The int8 paged
-cache has no kernel: it reads through ``paged_dequant_view`` on every
-platform (reason ``int8_scale_lanes``).
+K-wide verify alike, and the latent arena's decode and prompt chunk)
+walks the prefix in double-buffered groups of blocks with an online
+softmax and prefetches across slots; what it stages does not grow with
+the table, and the budget does not bind it (80% of the HBM roofline
+at the serving cell's geometry where a staged body read 44%, kernel
+alone on the v5e).  The int8 paged cache has no kernel: it reads
+through ``paged_dequant_view`` on every platform (reason
+``int8_scale_lanes``).
 """
 
 from __future__ import annotations
@@ -590,9 +591,11 @@ def _paged_stream_kernel(lens_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
     no padding rows between heads, the softmax statistics are [P, 1],
     and one dot gives PV [P, W], of which row r's own head's D lanes
     are the answer (the rest, other heads' V under this head's weights,
-    is dropped at the end).  ``cq`` is 1 for decode and K+1 for the
-    speculative verifier; query c sees cache rows ``<= lens[b] + c``,
-    the causal frontier the sequential decode loop would have given it.
+    is dropped at the end).  ``cq`` is 1 for decode, K+1 for the
+    speculative verifier and a query tile of a prompt chunk
+    (``latent_chunk_attention``); query c sees cache rows ``<= lens[b] +
+    c``, the causal frontier the sequential decode loop would have given
+    it.
 
     Stale-buffer invariant: a stage is refreshed only as far as the
     group's valid blocks reach, so rows past the frontier of the last
@@ -608,10 +611,10 @@ def _paged_stream_kernel(lens_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref,
     (``dimension_semantics=("arbitrary",)``): a 'parallel' batch
     dimension would race programs on the shared stages.
 
-    A LATENT arena (``_latent_stream_kernel``) is this body with
-    ``v_hbm``, ``vbuf`` and ``vsem`` None: one row a token, shared by
-    every query head (``hkv`` 1), whose first ``d`` lanes are also its
-    value.  Only K is staged, PV reads those lanes of the K stage, and
+    A LATENT arena (``_latent_stream_kernel``: decode, and a prompt
+    chunk's query tiles) is this body with ``v_hbm``, ``vbuf`` and
+    ``vsem`` None: one row a token, shared by every query head (``hkv``
+    1), whose first ``d`` lanes are also its value.  Only K is staged, PV reads those lanes of the K stage, and
     the K stages are what is zeroed once."""
     bi = pl.program_id(0)
     last_prog = pl.num_programs(0) - 1
@@ -871,13 +874,14 @@ def _latent_stream_kernel(lens_ref, tbl_ref, q_ref, c_hbm, o_ref, cbuf,
                          **static)
 
 
-def _route_decision_latent(q, arena, tables, dv):
-    """(use_pallas, reason) for the latent decode gate.  ``q`` [B, G, W]:
-    every query head of a slot against the slot's one row a token, so the
-    kernel's q block is the ``G`` rows as they are and ``group_too_wide``
+def _route_decision_latent(q, arena, tables, dv, cq=1):
+    """(use_pallas, reason) for the latent gate.  ``q`` [B, G, W]: every
+    query head of a slot against the slot's one row a token, so the
+    kernel's q block is the ``G`` rows of each of ``cq`` positions as they
+    are (1 for decode, a query tile for a chunk) and ``group_too_wide``
     (the block-diagonal q of the KV kernels) does not arise; what is
-    staged is two stages of rows ``W`` wide and an accumulator ``dv``
-    wide."""
+    staged is two stages of rows ``W`` wide, the ``cq * G`` rows' logits
+    over a stage, their accumulator ``dv`` wide and their q block."""
     from ...core.flags import flag
     if not flag("use_decode_attention_kernel"):
         return False, "flag_disabled"
@@ -894,29 +898,40 @@ def _route_decision_latent(q, arena, tables, dv):
     ok, reason = _paged_table_rule(arena)
     if not ok:
         return False, reason
-    rows = _stage_blocks(arena, tables) * arena.shape[1]
-    p = _stream_rows(1, 1, q.shape[1])
-    vmem = (2 * rows * w * jnp.dtype(arena.dtype).itemsize + p * rows * 4
-            + p * (dv * 4 + 2 * w * jnp.dtype(q.dtype).itemsize))
-    if vmem > _VMEM_BUDGET:
+    if _latent_vmem(q.shape[1], cq, arena, tables, dv) > _VMEM_BUDGET:
         return False, "vmem_budget"
     return True, "latent_ok"
 
 
-def _latent_stream(q, arena, tables, lens, dv, scale):
-    """Dispatch ``_latent_stream_kernel``.  q [B, G, W]; arena
-    [NB+1, L, W] (last row = trash block); returns [B, G, dv]."""
+def _latent_vmem(g, cq, arena, tables, dv):
+    """Bytes the latent gate counts for a program of ``cq`` positions of
+    ``g`` heads: both row stages, the rows' float32 logits over a stage,
+    their float32 accumulator and their double-buffered q block."""
+    rows = _stage_blocks(arena, tables) * arena.shape[1]
+    p = _stream_rows(1, cq, g)
+    item = jnp.dtype(arena.dtype).itemsize
+    w = arena.shape[2]
+    return 2 * rows * w * item + p * rows * 4 + p * (dv * 4 + 2 * w * item)
+
+
+def _latent_stream(q, arena, tables, lens, dv, scale, cq=1,
+                   name="decode_attention_latent"):
+    """Dispatch ``_latent_stream_kernel``, one program a row of ``q``.
+    q [N, cq * G, W], row ``c * G + g`` position c of head g; arena
+    [NB+1, L, W] (last row = trash block); tables [N, max_blocks]; lens
+    [N] the first position's global slot, position c seeing slots
+    ``<= lens + c``.  Returns [N, cq * G, dv]."""
     _guard_replicated_tables(tables)
-    b, g, w = q.shape
+    b, r, w = q.shape
     blk_len = arena.shape[1]
     bpg = _stage_blocks(arena, tables)
     rows = bpg * blk_len
-    n_rows = _stream_rows(1, 1, g)
-    qall = jnp.pad(q, ((0, 0), (0, n_rows - g), (0, 0)))
+    n_rows = _stream_rows(1, 1, r)
+    qall = jnp.pad(q, ((0, 0), (0, n_rows - r), (0, 0)))
     kernel = functools.partial(
         _latent_stream_kernel, block_len=blk_len, bpg=bpg,
-        n_blocks_max=tables.shape[1], cq=1, g=g, hkv=1, d=dv, scale=scale,
-        out_dtype=q.dtype)
+        n_blocks_max=tables.shape[1], cq=cq, g=r // cq, hkv=1, d=dv,
+        scale=scale, out_dtype=q.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
@@ -939,10 +954,10 @@ def _latent_stream(q, arena, tables, lens, dv, scale):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_rows, dv), q.dtype),
         compiler_params=_STREAM_COMPILER_PARAMS,
-        name="decode_attention_latent",
+        name=name,
         interpret=not on_tpu(),
     )(lens.astype(jnp.int32), tables.astype(jnp.int32), qall, arena)
-    return out[:, :g]
+    return out[:, :r]
 
 
 def decode_attention_latent(q, arena, tables, lens, dv, scale):
@@ -968,6 +983,84 @@ def decode_attention_latent(q, arena, tables, lens, dv, scale):
     logits = jnp.where(valid[:, None, :], logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bgs,bsv->bgv", probs, rows[..., :dv])
+
+
+# The closed vocabulary of the ``pallas.latent_chunk.route`` counter's
+# ``reason`` axis: the latent gate's refusals (``_route_decision_latent``)
+# and the chunk kernel's accept.
+LATENT_CHUNK_ROUTE_REASONS = (
+    "latent_chunk_ok", "flag_disabled", "pallas_unavailable",
+    "gspmd_partitioned", "dtype_mismatch", "geometry", "paged_block_len",
+    "vmem_budget",
+)
+
+_latent_chunk_counter_inst = None
+
+
+def _latent_chunk_counter():
+    # the process-default registry, resolved once (as ``_route_counter``)
+    global _latent_chunk_counter_inst
+    if _latent_chunk_counter_inst is None:
+        from ...observability import metrics as _obs
+        _latent_chunk_counter_inst = _obs.get_registry().counter(
+            "pallas.latent_chunk.route",
+            "latent chunk-attention dispatch decisions (the streaming "
+            "kernel over query tiles vs the gathered and expanded XLA "
+            "path, with the gating reason)",
+            labels=("decision", "reason"))
+    return _latent_chunk_counter_inst
+
+
+def _latent_chunk_decision(c, g, arena, tables, dv):
+    """(tq, reason) for a chunk of ``c`` positions of ``g`` heads: the
+    latent gate at ``tq`` positions a program, ``tq`` the largest power
+    of two dividing ``c`` whose program the VMEM estimate admits, or 0
+    where the gate refuses (``reason`` its refusal)."""
+    q = jax.ShapeDtypeStruct((1, g, arena.shape[-1]), arena.dtype)
+    tq = c & -c
+    while True:
+        use, reason = _route_decision_latent(q, arena, tables, dv, cq=tq)
+        if use:
+            return tq, "latent_chunk_ok"
+        if reason != "vmem_budget" or tq == 1:
+            return 0, reason
+        tq //= 2
+
+
+def latent_chunk_tile(c, g, arena, tables, dv):
+    """The query tile of ``latent_chunk_attention`` for a chunk of ``c``
+    positions of ``g`` heads over ``arena`` (0: the gate refused, and the
+    caller attends over the gathered rows), counted once a call on
+    ``pallas.latent_chunk.route``."""
+    tq, reason = _latent_chunk_decision(c, g, arena, tables, dv)
+    if reason not in LATENT_CHUNK_ROUTE_REASONS:
+        raise ValueError(f"unknown latent-chunk route reason {reason!r}")
+    _latent_chunk_counter().inc(decision="pallas" if tq else "xla",
+                                reason=reason)
+    return tq
+
+
+@functools.partial(jax.jit, static_argnames=("dv", "scale", "tq"))
+def latent_chunk_attention(q, arena, tables, start, n_valid, dv, scale, tq):
+    """A prompt chunk's attention of ``G`` query heads over a LATENT paged
+    cache, the up-projections absorbed as in ``decode_attention_latent``:
+    q [B, C, G, W], position c of row b at global slot ``start[b] + c``,
+    the first ``n_valid[b]`` of them valid; its rows are in the arena
+    already.  The chunk runs as ``C / tq`` query tiles, one program each
+    (``tq`` from ``latent_chunk_tile``); a tile streams the slot's blocks
+    through its table only to its last position's causal frontier, and a
+    tile wholly past ``n_valid`` walks the slot's first blocks alone (its
+    rows are garbage the caller drops, as it drops every row at or past
+    ``n_valid``).  Returns [B, C, G, dv] in q.dtype.  Jitted, so that a
+    program's sublayers of one shape trace and lower the kernel once."""
+    b, c, g, w = q.shape
+    t = c // tq
+    first = jnp.arange(t, dtype=jnp.int32)[None, :] * tq          # [1, T]
+    lens = jnp.where(first < n_valid[:, None], start[:, None] + first, 0)
+    out = _latent_stream(
+        q.reshape(b * t, tq * g, w), arena, jnp.repeat(tables, t, axis=0),
+        lens.reshape(b * t), dv, scale, cq=tq, name="latent_chunk_attention")
+    return out.reshape(b, c, g, dv)
 
 
 def _decode_attention_xla(q4, k_cache, v_cache, lens):

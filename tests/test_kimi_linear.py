@@ -448,3 +448,30 @@ def test_a_dense_models_arenas_and_programs_are_unchanged():
     eng.submit(np.arange(1, 12, dtype=np.int32), max_new_tokens=6)
     eng.run()
     assert eng.stats()["slot_state_bytes"] == 0
+
+
+def test_the_chunk_kernel_serves_the_xla_routes_tokens(model, monkeypatch):
+    """The engine with the latent chunk kernel forced in the MLA layer
+    (interpret mode, query tiles of 4 positions) serves the tokens the
+    gathered and expanded XLA route serves, over prompts of one to three
+    chunks."""
+    from paddle_tpu.observability.metrics import get_registry
+    from paddle_tpu.ops.pallas import decode_attention as da
+    rng = np.random.default_rng(46)
+    prompts = [rng.integers(0, 256, (n,)).astype(np.int32)
+               for n in (40, 5, 17, 33)]
+
+    def served():
+        eng = engine(model)
+        sent = [eng.submit(ids, max_new_tokens=6) for ids in prompts]
+        eng.run()
+        return [list(r.output) for r in sent]
+
+    xla = served()
+    route = get_registry().counter("pallas.latent_chunk.route",
+                                   labels=("decision", "reason"))
+    before = route.value(decision="pallas", reason="latent_chunk_ok")
+    monkeypatch.setattr(da, "_latent_chunk_decision",
+                        lambda *shape: (4, "latent_chunk_ok"))
+    assert served() == xla
+    assert route.value(decision="pallas", reason="latent_chunk_ok") > before

@@ -332,3 +332,37 @@ def test_engine_serves_reused_slots_and_a_shared_prefix(model, weights):
     assert grew("moe.expert_picks") == \
         grew("moe.zero_expert_picks") + grew("moe.expert_tokens")
     assert 0 < grew("moe.zero_expert_picks") < grew("moe.expert_picks")
+
+
+def test_the_chunk_kernel_serves_the_xla_routes_tokens(model, monkeypatch):
+    """The engine with the latent chunk kernel forced (interpret mode, query
+    tiles of 4 positions) serves the tokens the gathered and expanded XLA
+    route serves: prompts of one to three chunks, and two that share a
+    prefix of three blocks, the second's chunk starting at the hit."""
+    from paddle_tpu.observability.metrics import get_registry
+    from paddle_tpu.ops.pallas import decode_attention as da
+    rng = np.random.default_rng(46)
+    prefix = rng.integers(0, 256, (24,))
+    prompts = [np.concatenate([prefix, rng.integers(0, 256, (9,))]),
+               rng.integers(0, 256, (40,)), rng.integers(0, 256, (5,)),
+               np.concatenate([prefix, rng.integers(0, 256, (13,))])]
+
+    def served():
+        eng = engine(model)
+        sent = []
+        for ids in prompts:
+            sent.append(eng.submit(ids.astype(np.int32), max_new_tokens=6))
+            if len(sent) == 1:
+                eng.run()       # the prefix is cached before the last comes
+        eng.run()
+        assert eng.stats()["prefix_hit_tokens"] >= 24
+        return [list(r.output) for r in sent]
+
+    xla = served()
+    route = get_registry().counter("pallas.latent_chunk.route",
+                                   labels=("decision", "reason"))
+    before = route.value(decision="pallas", reason="latent_chunk_ok")
+    monkeypatch.setattr(da, "_latent_chunk_decision",
+                        lambda *shape: (4, "latent_chunk_ok"))
+    assert served() == xla
+    assert route.value(decision="pallas", reason="latent_chunk_ok") > before

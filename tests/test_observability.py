@@ -678,6 +678,7 @@ def test_metrics_name_lint_clean():
              "pallas.decode_attention.route",
              "pallas.moe_experts.route",
              "pallas.kda_decode.route",
+             "pallas.latent_chunk.route",
              "serving.tpot_seconds", "serving.admits_ahead")), n
         assert n in names, n
     kinds = {r[3]: r[2] for r in regs}

@@ -1535,6 +1535,124 @@ def test_latent_gate_and_arena_shape():
     assert not use and reason == "pallas_unavailable"       # the CPU
 
 
+# -- the latent chunk: query tiles that stop at their causal frontier ---------
+
+def _latent_chunk_case(heads, start, seed=46, c=16, blk_len=8, mb=12):
+    """A latent layer of ``heads`` heads (rank 128 and a rotary part of 64:
+    rows of 192 resting as 256), one slot's table of ``mb`` blocks dealt
+    from a larger arena, and a chunk of ``c`` queries at ``start``."""
+    from paddle_tpu.models.mla import LatentAttention
+    layer = LatentAttention(64, heads, 16, 64, 16, 128, 1e-5)
+    rng = np.random.default_rng(seed)
+    layer.kv_b_proj.weight.set_value(jnp.asarray(
+        rng.standard_normal((128, heads * 32)) / np.sqrt(128), jnp.float32))
+    nb = 2 * mb + 3
+    arena = jnp.asarray(rng.standard_normal((nb + 1, blk_len, 256)),
+                        jnp.float32)
+    tables = jnp.asarray(rng.permutation(nb)[:mb][None], jnp.int32)
+    q_nope = jnp.asarray(rng.standard_normal((1, c, heads, 16)), jnp.float32)
+    q_pe = jnp.asarray(rng.standard_normal((1, c, heads, 64)), jnp.float32)
+    return layer, q_nope, q_pe, arena, tables, jnp.asarray([start], jnp.int32)
+
+
+_LATENT_CHUNKS = {    # name: (start, valid queries of 16, positions a tile)
+    "first_chunk_short": (0, 11, 4),
+    "continuation": (16, 16, 8),
+    "after_a_prefix_hit_of_three_blocks": (24, 13, 2),
+}
+
+
+@pytest.mark.parametrize("heads", [32, 64])
+@pytest.mark.parametrize("which", sorted(_LATENT_CHUNKS))
+def test_latent_chunk_kernel_is_the_expanded_attention(monkeypatch, which,
+                                                       heads):
+    """A chunk's absorbed attention through the streaming kernel over query
+    tiles (interpret mode) against the expanded attention over the slot's
+    gathered rows: every valid query, with stages of two blocks so that the
+    tiles walk one to three groups and the last tile of a short chunk lies
+    wholly past its valid queries."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "_STAGE_BYTES", 16 << 10)
+    start, n_valid, tq = _LATENT_CHUNKS[which]
+    layer, q_nope, q_pe, arena, tables, pos0 = _latent_chunk_case(heads,
+                                                                  start)
+    assert da._stage_blocks(arena, tables) == 2
+    pos = pos0[:, None] + jnp.arange(16)[None, :]
+    want = layer._expanded(q_nope, q_pe, da.paged_gather_view(arena, tables),
+                           pos)
+    got = layer._absorbed_chunk(q_nope, q_pe, arena, tables, pos0,
+                                jnp.asarray([n_valid], jnp.int32), tq)
+    np.testing.assert_allclose(np.asarray(got)[:, :n_valid],
+                               np.asarray(want)[:, :n_valid], atol=1e-5)
+
+
+def test_latent_chunk_kernel_ignores_stale_rows(monkeypatch):
+    """Rows past the last valid query's frontier, the trash block and the
+    blocks of other slots hold huge values: masked before ``exp`` (or never
+    staged), they change no valid query's output."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "_STAGE_BYTES", 16 << 10)
+    start, n_valid = 16, 11
+    layer, q_nope, q_pe, arena, tables, pos0 = _latent_chunk_case(32, start)
+    valid = jnp.asarray([n_valid], jnp.int32)
+    clean = layer._absorbed_chunk(q_nope, q_pe, arena, tables, pos0, valid, 4)
+    last = start + n_valid - 1                      # the last valid row
+    own = np.asarray(tables)[0]
+    dirty = arena.at[own[last // 8], last % 8 + 1:].set(1e6)
+    dirty = dirty.at[own[last // 8 + 1:]].set(-1e6).at[-1].set(1e6)
+    others = np.setdiff1d(np.arange(arena.shape[0] - 1), own)
+    dirty = dirty.at[others].set(1e6)
+    out = layer._absorbed_chunk(q_nope, q_pe, dirty, tables, pos0, valid, 4)
+    np.testing.assert_allclose(np.asarray(out)[:, :n_valid],
+                               np.asarray(clean)[:, :n_valid], atol=1e-5)
+
+
+def test_latent_chunk_gate_reasons(monkeypatch):
+    """The chunk's route: the XLA path on the CPU and in a partitioned
+    program, each under its reason on ``pallas.latent_chunk.route``; on
+    the chip the largest query tile the VMEM estimate admits, 16
+    positions of LongCat's 64 heads and 32 of Kimi's 32 (7,380,992 B of
+    12 MiB; twice the tile would count 13,737,984 B), and the gate's
+    refusals where the row's lanes or the budget rule the kernel out."""
+    from paddle_tpu.observability.metrics import get_registry
+    from paddle_tpu.ops.pallas import _common
+    from paddle_tpu.ops.pallas import decode_attention as da
+    shape = jax.ShapeDtypeStruct
+    arena = shape((16385, 16, 640), jnp.bfloat16)
+    tables = shape((1, 128), jnp.int32)
+
+    route = get_registry().counter("pallas.latent_chunk.route",
+                                   labels=("decision", "reason"))
+    before = route.value(decision="xla", reason="pallas_unavailable")
+    assert da.latent_chunk_tile(512, 64, arena, tables, 512) == 0
+    assert route.value(decision="xla",
+                       reason="pallas_unavailable") == before + 1
+    monkeypatch.setattr(_common, "on_tpu", lambda: True)
+    with _common.partitioned_scope(True):
+        assert da._latent_chunk_decision(512, 64, arena, tables, 512) == \
+            (0, "gspmd_partitioned")
+    monkeypatch.setattr(da, "pallas_enabled", lambda: True)
+    assert da._latent_chunk_decision(512, 64, arena, tables, 512) == \
+        (16, "latent_chunk_ok")
+    assert da._latent_chunk_decision(512, 32, arena, tables, 512) == \
+        (32, "latent_chunk_ok")
+    assert da._latent_vmem(64, 16, arena, tables, 512) == 7_380_992
+    assert da._latent_vmem(64, 32, arena, tables, 512) == 13_737_984
+    assert da._latent_chunk_decision(48, 64, arena, tables, 512)[0] == 16
+    assert da._latent_chunk_decision(512, 64, arena, tables, 96) == \
+        (0, "geometry")
+    assert da._latent_chunk_decision(512, 8192, arena, tables, 512) == \
+        (0, "vmem_budget")
+    before = route.value(decision="pallas", reason="latent_chunk_ok")
+    assert da.latent_chunk_tile(512, 64, arena, tables, 512) == 16
+    assert route.value(decision="pallas",
+                       reason="latent_chunk_ok") == before + 1
+    # decode's gate is the same estimate at one position
+    assert da._route_decision_latent(
+        shape((128, 64, 640), jnp.bfloat16), arena,
+        shape((128, 128), jnp.int32), 512) == (True, "latent_ok")
+
+
 def _kda_step_case(seed=0, b=5, h=8, d=128, slots=6, layers=2):
     from paddle_tpu.ops.pallas import kda
     ks = jax.random.split(jax.random.PRNGKey(seed), 7)

@@ -210,6 +210,12 @@ REQUIRED_INSTRUMENTS = {
     # reason (KDA_ROUTE_REASONS); the serving harness's route check keys
     # on it
     "pallas.kda_decode.route": ("counter", ("decision", "reason")),
+    # a prompt chunk's latent attention
+    # (ops/pallas/decode_attention.py): the streaming kernel over query
+    # tiles vs the gathered and expanded XLA path, with the gating reason
+    # (LATENT_CHUNK_ROUTE_REASONS); the serving harness's route line
+    # prints it
+    "pallas.latent_chunk.route": ("counter", ("decision", "reason")),
     # wire transport (PR 19, inference/transport.py
     # _TransportInstruments): frames moved per kind (the determinism
     # surface the bench multiproc arm gates on), encoded byte totals

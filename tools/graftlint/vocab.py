@@ -99,6 +99,11 @@ VOCABS: Tuple[VocabSpec, ...] = (
     # ops/pallas/kda.py): every label the pallas.kda_decode.route counter
     # can carry is a literal return of the _kda_route_reason producer
     VocabSpec("KDA_ROUTE_REASONS", producers=("_kda_route_reason",)),
+    # routing reasons of a prompt chunk's latent attention
+    # (ops/pallas/decode_attention.py): the latent gate's refusals reach
+    # the pallas.latent_chunk.route counter through non-literal locals
+    # (dead=False); latent_chunk_tile guards the set at runtime
+    VocabSpec("LATENT_CHUNK_ROUTE_REASONS", dead=False),
     # wire-transport frame kinds (PR 19, inference/transport.py):
     # every request kind has a literal transport.rpc("<kind>", ...)
     # site (RemoteReplica and friends), every reply kind a literal
